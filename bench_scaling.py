@@ -17,7 +17,8 @@ measure that, so this harness defends the claim three ways:
                         per-step coordination at all (XLA's schedule is
                         static), which is the structural argument.
   --mode project        the analytic v5e-256 projection: measured
-                        single-chip step times (BENCH_MODELS/BENCH_r03)
+                        single-chip step times (a deleted record of a
+                        machine that is gone; see MEASURED below)
                         + gradient bytes vs ICI ring bandwidth with an
                         overlap budget, every assumption stated in the
                         output.
@@ -35,9 +36,12 @@ import json
 import time
 
 # ---------------------------------------------------------------------------
-# measured single-chip inputs (BENCH_r03.json / BENCH_MODELS.json) and
-# public hardware constants — every number the projection uses, in one
-# visible table.
+# single-chip inputs and public hardware constants — every number the
+# projection uses, in one visible table.  The img/s column was recorded
+# by the driver (resnet50) and by earlier builders (the rest) on a v5e
+# behind a remote-chip plug-in that no longer exists; the record files
+# were deleted with it and the figures have NOT been re-measured on the
+# current machine, so the projection is exactly that (ROADMAP S2).
 # ---------------------------------------------------------------------------
 
 MEASURED = {
@@ -130,29 +134,32 @@ def _coordination_body(iters):
     return res
 
 
-def coordination(iters=30, ps=(1, 2, 4, 8)):
+def coordination(iters=30, ps=(1, 2, 4, 8), cpu_devices=1):
     """Mean per-op latency vs P: the coordination floor of the eager
-    path (KV rendezvous + gloo collective + dispatch).  The jit path
-    carries none of this — coordination there is compile-time."""
+    path (KV rendezvous + collective + dispatch).  The jit path
+    carries none of this — coordination there is compile-time.
+    ``cpu_devices=None`` runs on the machine's accelerator, one chip
+    per process (the launcher refuses a P the host has no chips for)."""
     from horovod_tpu.runner import run
 
     rows = []
     for p in ps:
         results = run(_coordination_body, args=(iters,), np=p,
-                      cpu_devices=1, timeout=900.0)
+                      cpu_devices=cpu_devices, timeout=900.0)
         agg = {k: round(max(r[k] for r in results), 3)
                for k in results[0]}
         rows.append({"processes": p, **agg})
     # stall-watchdog cost isolated at P=4: the default rows above run
     # the amortized mode; compare against the round-4 strict per-op
     # rendezvous and against checking disabled (the amortized target:
-    # within noise of disabled — VERDICT r4 #1)
+    # within noise of disabled)
     for label, env in (
             ("amortized", {}),
             ("strict", {"HVTPU_STALL_CHECK_MODE": "strict"}),
             ("disabled", {"HVTPU_STALL_CHECK_DISABLE": "1"})):
         results = run(_coordination_body, args=(iters,), np=4,
-                      cpu_devices=1, env=env or None, timeout=900.0)
+                      cpu_devices=cpu_devices, env=env or None,
+                      timeout=900.0)
         rows.append({
             "processes": 4, "stall_check": label,
             **{k: round(max(r[k] for r in results), 3)
@@ -281,12 +288,16 @@ def main():
                    help="output file for --mode all")
     args = p.parse_args()
 
+    import horovod_tpu as hvt
+
+    hvt.enable_compile_cache()
+    cpu_devices = 1 if args.platform == "cpu" else None
     if args.mode == "sweep":
         for r in sweep(args):
             print(json.dumps(r))
         return
     if args.mode == "coordination":
-        for r in coordination(iters=args.iters):
+        for r in coordination(iters=args.iters, cpu_devices=cpu_devices):
             print(json.dumps(r))
         return
     if args.mode == "project":
@@ -302,7 +313,8 @@ def main():
         "verdict": None,  # filled below
         "projection_assumptions": ASSUMPTIONS,
         "projection": project(),
-        "coordination_vs_P": coordination(iters=args.iters),
+        "coordination_vs_P": coordination(iters=args.iters,
+                                          cpu_devices=cpu_devices),
     }
     if not args.skip_sweep:
         doc["virtual_mesh_sweep_note"] = (

@@ -12,8 +12,12 @@ Run (2 processes × 4 virtual CPU devices = an 8-device global mesh):
 
     hvtpurun -np 2 --cpu-devices 4 python examples/pod_train.py
 
-On real TPU hosts, drop ``--cpu-devices`` — each process picks up its
-host's chips and the mesh spans the slice.
+On a TPU host, drop ``--cpu-devices``.  ``hvtpurun -np 4`` on a
+four-chip host is the Horovod shape — four ranks, one chip each (the
+launcher hands local rank r chip r through the environment libtpu
+reads) — and ``-np 1`` is one process driving all four.  Either way
+the mesh spans every chip, and the eager plane (``hvt.allreduce``)
+spans the ranks.
 """
 
 import argparse
@@ -36,10 +40,18 @@ def main():
     hvt.init()
     mesh = hvt.world_mesh()
     n_dev = mesh.devices.size
+    print(f"rank {hvt.rank()}/{hvt.size()} owns "
+          f"{[str(d) for d in jax.local_devices()]}", flush=True)
     if hvt.rank() == 0:
         print(f"pod: {hvt.size()} processes x "
               f"{jax.local_device_count()} local devices = "
               f"{n_dev}-device world mesh", flush=True)
+
+    # The eager plane is process-granularity: one tensor per rank.
+    total = hvt.allreduce(
+        jnp.full((1024,), hvt.rank() + 1.0), op=hvt.Sum, name="pod.hello")
+    want = hvt.size() * (hvt.size() + 1) / 2
+    assert np.asarray(total).tolist() == [want] * 1024, (total[:4], want)
 
     # Deterministic synthetic data; every process generates the full
     # array and contributes only the shards it owns.
@@ -87,7 +99,8 @@ def main():
         last = val
     assert last < first, (first, last)
     if hvt.rank() == 0:
-        print(f"loss {first:.4f} -> {last:.4f} over {args.steps} steps "
+        print(f"eager allreduce across {hvt.size()} ranks = {want:g}; "
+              f"loss {first:.4f} -> {last:.4f} over {args.steps} steps "
               f"on {n_dev} devices; ranks consistent "
               f"({hvt.size()} ranks)", flush=True)
 

@@ -27,27 +27,6 @@ from typing import Optional
 
 import jax
 
-if not hasattr(jax, "shard_map"):
-    # jax < 0.5 only ships shard_map under experimental, with the
-    # replication check spelled check_rep instead of check_vma; alias
-    # the modern surface so every call site (and user code written
-    # against it) runs on both.
-    from jax.experimental.shard_map import shard_map as _exp_shard_map
-
-    def _shard_map_compat(f=None, /, **kw):
-        if "check_vma" in kw:
-            kw["check_rep"] = kw.pop("check_vma")
-        if f is None:
-            return lambda g: _exp_shard_map(g, **kw)
-        return _exp_shard_map(f, **kw)
-
-    jax.shard_map = _shard_map_compat
-
-if not hasattr(jax.lax, "axis_size"):
-    # jax < 0.5 has no lax.axis_size; core.axis_frame(name) resolves
-    # the bound size of a mesh axis at trace time there.
-    jax.lax.axis_size = lambda axis_name: jax.core.axis_frame(axis_name)
-
 from . import comm, core
 from . import data  # noqa: F401  (elastic-aware input pipeline)
 from . import elastic  # noqa: F401  (hvt.elastic.State/run parity surface)
@@ -71,6 +50,7 @@ from .core import (
     remove_process_set,
 )
 from .core import state as _state
+from .core.compile_cache import enable_compile_cache
 from .version import __version__
 
 # ---------------------------------------------------------------------------
@@ -526,7 +506,7 @@ from .api.sharded_checkpoint import ShardedCheckpointer  # noqa: E402
 
 __all__ = [
     "__version__",
-    "init", "shutdown", "is_initialized",
+    "init", "shutdown", "is_initialized", "enable_compile_cache",
     "rank", "size", "local_rank", "local_size", "cross_rank", "cross_size",
     "num_devices", "local_devices", "world_mesh", "hierarchical_mesh", "mesh",
     "allreduce", "grouped_allreduce", "allgather", "broadcast", "alltoall",

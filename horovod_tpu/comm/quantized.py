@@ -22,9 +22,13 @@ matching the paper's error model.
 
 from __future__ import annotations
 
+import os
+
 import jax
 import jax.numpy as jnp
 from jax import lax
+
+from ..ops import ring as _ring
 
 BLOCK = 512
 
@@ -78,27 +82,17 @@ def quantized_allreduce(tensor, *, axis_name: str, average: bool = False,
     ``HVTPU_QUANTIZED_RING=1`` routes through the Pallas per-hop
     requantizing ring kernel instead (ops/ring.py — the EQuARX
     algorithm proper, requantizing on every hop rather than once per
-    phase); only takes effect where the kernel can run (TPU, or the
-    interpreter in tests).  The ring kernel rounds deterministically,
-    so ``stochastic=True`` keeps the XLA path — the documented
+    phase).  The kernel runs on a TPU (or the interpreter in tests)
+    and raises anywhere else — asking for the ring never silently runs
+    this XLA path.  The ring kernel rounds deterministically, so
+    ``stochastic=True`` keeps the XLA path — the documented
     unbiased-dither semantics win over the ring opt-in.
     """
-    import os
-
     n_ranks = lax.axis_size(axis_name)
     if (os.environ.get("HVTPU_QUANTIZED_RING", "0") == "1"
             and n_ranks > 1 and not stochastic):
-        try:
-            # soft import: ring.py needs pallas importable; fall
-            # through to the XLA path anywhere it isn't
-            from ..ops.ring import _interpret_arg, ring_allreduce
-        except Exception:
-            ring_allreduce = None
-        if ring_allreduce is not None and _interpret_arg() is not None:
-            return ring_allreduce(
-                tensor, axis_name=axis_name, average=average,
-                quantized=True,
-            )
+        return _ring.ring_allreduce(
+            tensor, axis_name=axis_name, average=average, quantized=True)
     orig_shape = tensor.shape
     orig_dtype = tensor.dtype
     flat = tensor.reshape(-1).astype(jnp.float32)

@@ -10,18 +10,32 @@ CMake into the framework .so; see SURVEY.md §2.1):
 
 ``make_controller`` picks the native implementation when a toolchain is
 available, else the fallback — both speak the same wire format, so
-mixed fleets coordinate fine.
+mixed fleets coordinate fine.  ``controller_kind()`` says which one a
+process gets, and why.
 """
 
 from __future__ import annotations
 
+import logging
 import os
 
 from . import core, fallback, wire
 
+logger = logging.getLogger("horovod_tpu")
+
 
 def native_available() -> bool:
     return core.available()
+
+
+def controller_kind() -> str:
+    """Which eager controller ``make_controller`` builds here:
+    ``"native"`` or ``"python (<why not native>)"``."""
+    if os.environ.get("HVTPU_FORCE_PY_CONTROLLER"):
+        return "python (HVTPU_FORCE_PY_CONTROLLER is set)"
+    if core.available():
+        return "native"
+    return f"python ({core.unavailable_reason})"
 
 
 def make_controller(rank: int, size: int, fusion_threshold: int,
@@ -38,12 +52,16 @@ def make_controller(rank: int, size: int, fusion_threshold: int,
     distribution channel."""
     if resync_every is None:
         resync_every = int(os.environ.get("HVTPU_CACHE_RESYNC_EVERY", "64"))
-    if (not os.environ.get("HVTPU_FORCE_PY_CONTROLLER")
-            and core.available()):
+    kind = controller_kind()
+    if kind == "native":
         return core.NativeController(
             rank, size, fusion_threshold, cache_capacity,
             stall_warn_s, stall_abort_s, resync_every=resync_every,
         )
+    if not os.environ.get("HVTPU_FORCE_PY_CONTROLLER"):
+        logger.warning(
+            "eager controller: native core unavailable, using the "
+            "Python twin — %s", core.unavailable_reason)
     return fallback.PyController(
         rank, size, fusion_threshold, cache_capacity,
         stall_warn_s, stall_abort_s, resync_every=resync_every,
@@ -51,5 +69,6 @@ def make_controller(rank: int, size: int, fusion_threshold: int,
 
 
 __all__ = [
-    "core", "fallback", "wire", "native_available", "make_controller",
+    "core", "fallback", "wire", "native_available", "controller_kind",
+    "make_controller",
 ]

@@ -24,13 +24,22 @@ _LIB_PATH = os.path.join(_HERE, "libhvt_core.so")
 _build_lock = threading.Lock()
 _lib: Optional[ctypes.CDLL] = None
 _lib_tried = False
+# Why the native library is not in use (None while it is, or before
+# the first load attempt) — callers that fall back say so.
+unavailable_reason: Optional[str] = None
 
 
 def build(force: bool = False) -> Optional[str]:
-    """Compile libhvt_core.so with make/g++; returns its path or None."""
+    """Compile libhvt_core.so with make/g++; returns its path or None
+    (the reason is left in ``unavailable_reason``)."""
+    global unavailable_reason
     with _build_lock:
         if os.environ.get("HVTPU_SKIP_NATIVE_BUILD"):
-            return _LIB_PATH if os.path.exists(_LIB_PATH) else None
+            if os.path.exists(_LIB_PATH):
+                return _LIB_PATH
+            unavailable_reason = ("HVTPU_SKIP_NATIVE_BUILD is set and "
+                                  f"{_LIB_PATH} does not exist")
+            return None
         # Always invoke make: its dependency tracking makes this a no-op
         # when the .so is current, and picks up edits to src/*.cc that a
         # bare existence check would silently ignore.
@@ -44,9 +53,17 @@ def build(force: bool = False) -> Optional[str]:
                 capture_output=True,
                 timeout=300,
             )
-        except (subprocess.SubprocessError, FileNotFoundError, OSError):
-            return _LIB_PATH if os.path.exists(_LIB_PATH) else None
-        return _LIB_PATH if os.path.exists(_LIB_PATH) else None
+        except subprocess.CalledProcessError as e:
+            err = e.stderr.decode(errors="replace").strip()
+            unavailable_reason = (
+                f"make failed (exit {e.returncode}): {err[-400:]}")
+        except (subprocess.SubprocessError, OSError) as e:
+            unavailable_reason = f"make could not run: {e!r}"
+        if os.path.exists(_LIB_PATH):
+            return _LIB_PATH
+        unavailable_reason = unavailable_reason or (
+            f"make left no {_LIB_PATH}")
+        return None
 
 
 def _configure(lib: ctypes.CDLL) -> ctypes.CDLL:
@@ -144,7 +161,7 @@ def _configure(lib: ctypes.CDLL) -> ctypes.CDLL:
 
 def load() -> Optional[ctypes.CDLL]:
     """Load (building if needed) the native library; None if unavailable."""
-    global _lib, _lib_tried
+    global _lib, _lib_tried, unavailable_reason
     if _lib is not None:
         return _lib
     if _lib_tried:
@@ -158,10 +175,15 @@ def load() -> Optional[ctypes.CDLL]:
         # ABI check below would reject it too, but only if _configure
         # survives) — fall back to the Python twin either way.
         _lib = _configure(ctypes.CDLL(path))
-    except (OSError, AttributeError):
+    except (OSError, AttributeError) as e:
+        unavailable_reason = f"cannot load {path}: {e!r}"
         return None
     if _lib.hvt_abi_version() != 5:
+        unavailable_reason = (
+            f"{path} has ABI {_lib.hvt_abi_version()}, need 5")
         _lib = None
+    else:
+        unavailable_reason = None
     return _lib
 
 

@@ -68,18 +68,39 @@ Interval = Tuple[float, float]
 ACTIVE = os.environ.get("HVTPU_STEPPROF", "1").lower() not in (
     "0", "false", "off")
 
-# HVTPU_STEPPROF_PEAK_TFLOPS: per-chip peak for the MFU denominator
-# (default: v5e bf16 197 TFLOP/s).
-PEAK_TFLOPS = float(os.environ.get("HVTPU_STEPPROF_PEAK_TFLOPS", "197"))
+# Per-chip bf16 peak TFLOP/s by ``jax.Device.device_kind`` — the MFU
+# denominator.  Source: Google Cloud documentation, "TPU v5e" system
+# architecture page (197 TFLOP/s bf16 per chip).
+PEAK_TFLOPS_BY_DEVICE_KIND = {
+    "TPU v5 lite": 197.0,
+}
 
 # HVTPU_STEPPROF_WINDOW: max collective/data windows retained between
 # step boundaries (bounds collector memory on pathological loops).
 _WINDOW = int(os.environ.get("HVTPU_STEPPROF_WINDOW", "4096"))
 
 
-def peak_flops() -> float:
-    """Per-chip peak FLOP/s used as the MFU denominator."""
-    return PEAK_TFLOPS * 1e12
+def peak_flops(device_kind: Optional[str] = None) -> float:
+    """Per-chip peak FLOP/s used as the MFU denominator: the explicit
+    ``HVTPU_STEPPROF_PEAK_TFLOPS`` override if set, else the table
+    entry for ``device_kind`` (default: this process's first device).
+    A device that is not in the table raises — an MFU against a
+    guessed peak is worse than none."""
+    override = os.environ.get("HVTPU_STEPPROF_PEAK_TFLOPS")
+    if override:
+        return float(override) * 1e12
+    if device_kind is None:
+        import jax
+
+        device_kind = jax.devices()[0].device_kind
+    try:
+        return PEAK_TFLOPS_BY_DEVICE_KIND[device_kind] * 1e12
+    except KeyError:
+        raise LookupError(
+            f"no peak FLOP/s recorded for device kind {device_kind!r} "
+            f"(known: {sorted(PEAK_TFLOPS_BY_DEVICE_KIND)}); add it to "
+            "obs/stepprof.PEAK_TFLOPS_BY_DEVICE_KIND with its source, or "
+            "set HVTPU_STEPPROF_PEAK_TFLOPS for this run") from None
 
 
 # ---------------------------------------------------------------------------
@@ -214,7 +235,7 @@ OVERLAP_FRACTION = obs_metrics.REGISTRY.gauge(
 MFU = obs_metrics.REGISTRY.gauge(
     "hvtpu_mfu",
     "Measured model FLOPs utilization: cost_analysis() FLOPs per step "
-    "/ (step wall time x HVTPU_STEPPROF_PEAK_TFLOPS peak). 0 until "
+    "/ (step wall time x the device's peak, obs/stepprof.peak_flops). 0 until "
     "the host loop provides step FLOPs (stepprof.set_step_flops).")
 
 
@@ -329,7 +350,6 @@ class _Collector:
                 "active": ACTIVE,
                 "steps": self._steps,
                 "flops_per_step": self._flops_per_step,
-                "peak_tflops": PEAK_TFLOPS,
                 "overlap_fraction": OVERLAP_FRACTION.value(),
                 "mfu": MFU.value(),
                 "last_step": dict(self._last),

@@ -19,26 +19,26 @@ places where an explicit kernel still wins:
   the on-core PRNG (cuda_kernels.cu's scale kernels have no TPU analog
   in XLA's standard fusion set for the rounding path).
 
-Every entry point falls back to a numerically-identical XLA lowering
-when not running on TPU (CPU tests, interpret-unfriendly shapes), so
-callers never need to branch.
+Which implementation runs is decided by what the process can observe:
+on a TPU backend the Pallas kernel (compiled by Mosaic), on any other
+backend the numerically-identical XLA twin, so callers never need to
+branch.  ``HVTPU_PALLAS=0`` selects the twin on a TPU as well; float16
+buffers always take it (Mosaic has no f16 vector type on v5e).
 """
 
 from __future__ import annotations
 
 import functools
-from typing import Optional, Tuple
+import logging
+import os
+from typing import Tuple
 
 import jax
 import jax.numpy as jnp
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-try:  # pallas is part of jax, but keep the import soft for safety
-    from jax.experimental import pallas as pl
-    from jax.experimental.pallas import tpu as pltpu
-
-    _HAS_PALLAS = True
-except Exception:  # pragma: no cover - pallas ships with jax
-    _HAS_PALLAS = False
+logger = logging.getLogger("horovod_tpu")
 
 # Lane width of the VPU / MXU; last-dim tiles are always 128 wide.
 _LANES = 128
@@ -49,23 +49,24 @@ _TILE_ROWS = 256
 
 
 def _on_tpu() -> bool:
-    try:
-        return jax.default_backend() == "tpu"
-    except Exception:  # pragma: no cover
-        return False
+    return jax.default_backend() == "tpu"
 
 
 def _pallas_mode() -> Tuple[bool, bool]:
     """(use_pallas, interpret).  HVTPU_PALLAS=0 disables the kernels
     entirely; HVTPU_PALLAS_INTERPRET=1 forces the Pallas path in
     interpreter mode so CPU tests execute the real kernel bodies."""
-    import os
-
-    if not _HAS_PALLAS or os.environ.get("HVTPU_PALLAS", "1") == "0":
+    if os.environ.get("HVTPU_PALLAS", "1") == "0":
         return False, False
     if os.environ.get("HVTPU_PALLAS_INTERPRET", "0") == "1":
         return True, True
     return _on_tpu(), False
+
+
+def _mosaic_dtype(dtype) -> bool:
+    """False for element types the Mosaic compiler cannot load or
+    store on the chip (v5e has no float16 vector type)."""
+    return jnp.dtype(dtype) != jnp.float16
 
 
 def _pad_to_grid(flat, rows_mult: int) -> Tuple[jax.Array, int, int]:
@@ -126,7 +127,8 @@ def fused_scale_cast(flat, scale, out_dtype=None):
     """
     out_dtype = jnp.dtype(out_dtype or flat.dtype)
     use, interp = _pallas_mode()
-    if not use or flat.ndim != 1:
+    if (not use or flat.ndim != 1
+            or not (_mosaic_dtype(flat.dtype) and _mosaic_dtype(out_dtype))):
         return _scale_cast_xla(jnp.asarray(flat), float(scale), out_dtype)
 
     x2, rows, n = _pad_to_grid(jnp.asarray(flat), _QROWS)
@@ -168,12 +170,13 @@ _QROWS = 8
 QBLOCK = _QROWS * _LANES
 
 
-def block_scale_inv(xg):
+def block_scale_inv(xg, axis: int = 1):
     """Shared absmax-block quantisation formula: (scale, inv) for
-    blocks ``xg (g, B) f32``.  THE single definition — the Pallas
-    kernel, the XLA twin, and the ring kernel's per-hop requantization
-    (ops/ring.py) must stay bit-identical, so they all call this."""
-    absmax = jnp.max(jnp.abs(xg), axis=1, keepdims=True)
+    blocks laid along ``axis`` of f32 ``xg``.  THE single definition —
+    the Pallas kernel, the XLA twin, and the ring kernel's per-hop
+    requantization (ops/ring.py) must stay bit-identical, so they all
+    call this."""
+    absmax = jnp.max(jnp.abs(xg), axis=axis, keepdims=True)
     # single multiply (not /127): a division invites per-fusion
     # strength-reduction ulp drift between lowerings
     scale = absmax * jnp.float32(1.0 / 127.0)
@@ -241,8 +244,12 @@ def quantize_int8_blocks(flat, *, stochastic: bool = False,
     """
     flat = jnp.asarray(flat)
     use, interp = _pallas_mode()
-    if stochastic and interp:
-        # the on-core PRNG has no interpreter implementation
+    if stochastic and (interp or not use):
+        # the on-core PRNG exists only in the Mosaic-compiled kernel:
+        # neither the test interpreter nor the XLA twin has one
+        logger.warning(
+            "quantize_int8_blocks: stochastic rounding needs the on-core "
+            "PRNG of the compiled TPU kernel; rounding to nearest here")
         stochastic = False
     if not use or flat.ndim != 1:
         q, scale, n = _quantize_xla(flat)
@@ -251,8 +258,8 @@ def quantize_int8_blocks(flat, *, stochastic: bool = False,
     # keep the native width into the kernel (the in-register cast in
     # the body handles f32 accumulation) — a host-side astype would
     # materialize a full f32 copy of the buffer in HBM first; only
-    # exotic dtypes (f64 etc.) pre-cast
-    if flat.dtype not in (jnp.float32, jnp.bfloat16, jnp.float16):
+    # dtypes Mosaic cannot load (f16, f64, ...) pre-cast
+    if flat.dtype not in (jnp.float32, jnp.bfloat16):
         flat = flat.astype(jnp.float32)
     x2, rows, n = _pad_to_grid(flat, _QROWS)
 

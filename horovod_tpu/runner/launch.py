@@ -25,6 +25,7 @@ import sys
 import threading
 from typing import Dict, List, Optional, Sequence
 
+from ..core.compile_cache import compile_cache_dir
 from . import hosts as hosts_mod
 from . import safe_shell_exec
 from .hosts import SlotInfo
@@ -292,6 +293,84 @@ def parse_hostfile(path: str) -> str:
     return ",".join(specs)
 
 
+# One chip per local rank on a TPU host: how libtpu wants the host's
+# processes laid out (x,y,z), by ranks per host == chips per host.
+# Only layouts that have run are listed: the four-chip v5e host (2x2).
+# Two ranks on that host (bounds 2,1,1 over chips 0,1) died inside
+# libtpu start-up, so a partial layout is refused, not attempted.
+_TPU_PROCESS_BOUNDS = {4: "2,2,1"}
+# libtpu's default inter-process port; local rank r listens on base + r.
+_TPU_PROCESS_PORT_BASE = 8476
+
+
+def tpu_chip_env(slot: SlotInfo) -> Dict[str, str]:
+    """What libtpu reads, before the worker imports JAX, to give local
+    rank r chip r of its host and wire the host's ranks into one
+    slice: the visible chip, per-process and process-grid bounds, every
+    local rank's address, this rank's port and its task id.  Empty for
+    one rank per host — that process owns all of the host's chips —
+    and for a layout with no known grid (refused before any spawn on a
+    host that has chips, see check_chip_assignment)."""
+    if slot.local_size not in _TPU_PROCESS_BOUNDS:
+        return {}
+    ports = [_TPU_PROCESS_PORT_BASE + r for r in range(slot.local_size)]
+    return {
+        "TPU_VISIBLE_CHIPS": str(slot.local_rank),
+        "TPU_CHIPS_PER_PROCESS_BOUNDS": "1,1,1",
+        "TPU_PROCESS_BOUNDS": _TPU_PROCESS_BOUNDS[slot.local_size],
+        "TPU_PROCESS_ADDRESSES": ",".join(
+            f"localhost:{p}" for p in ports),
+        "TPU_PROCESS_PORT": str(ports[slot.local_rank]),
+        "CLOUD_TPU_TASK_ID": str(slot.local_rank),
+    }
+
+
+def local_tpu_chips() -> int:
+    """TPU chips this host gives us, counted from the device nodes
+    libtpu opens (``/dev/vfio/<n>`` on v5e and later, ``/dev/accel<n>``
+    before) — the PCI bus can list chips a VM was not handed.  The
+    launcher must not initialize a JAX backend to find out, or its
+    children could not have the chips."""
+    import glob
+
+    return len(glob.glob("/dev/vfio/[0-9]*") + glob.glob("/dev/accel[0-9]*"))
+
+
+def _cpu_mode(env: Dict[str, str]) -> bool:
+    v = env.get("HVTPU_CPU_DEVICES") or env.get("HOROVOD_CPU_DEVICES")
+    return bool(v) and v != "0"
+
+
+def check_chip_assignment(slots: List[SlotInfo], cpu_mode: bool,
+                          chips: Optional[int] = None) -> Optional[str]:
+    """Why this layout cannot get one chip per rank, or None.  Checked
+    before any spawn: ranks left to fight over the chips fail late and
+    unreadably."""
+    if cpu_mode:
+        return None
+    if chips is None:
+        chips = local_tpu_chips()
+    if chips == 0:
+        return None  # no TPU here: the chip variables are inert
+    local = [s for s in slots if hosts_mod.is_local_host(s.hostname)]
+    if not local or local[0].local_size == 1:
+        return None
+    n = local[0].local_size
+    if n != chips or n not in _TPU_PROCESS_BOUNDS:
+        return (f"{n} ranks on a host with {chips} TPU chip(s): one chip "
+                "per rank is wired up only for a whole host of "
+                f"{sorted(_TPU_PROCESS_BOUNDS)} chips (-np equal to the "
+                "chip count); otherwise run one rank per host driving "
+                "all its chips through world_mesh(), or pass "
+                "--cpu-devices for a CPU run")
+    if any(s.cross_size > 1 for s in slots):
+        return ("one chip per process across several hosts is not "
+                "wired up (TPU_PROCESS_BOUNDS would have to span the "
+                "hosts); run one rank per host, each driving all of "
+                "its chips through world_mesh()")
+    return None
+
+
 def uniform_local_size(slots: List[SlotInfo]) -> int:
     """The common per-host slot count when the layout is uniform (every
     host has the same local_size), else 0.  Hierarchical collectives
@@ -325,6 +404,9 @@ def build_worker_env(
     )
     if uniform_local is not None:
         env["HVTPU_UNIFORM_LOCAL_SIZE"] = str(uniform_local)
+    # workers compile into the same persistent cache as the launching
+    # script (core/compile_cache.py); an outside setting wins
+    env.setdefault("JAX_COMPILATION_CACHE_DIR", compile_cache_dir())
     # Source-checkout robustness: make the horovod_tpu package the
     # launcher itself is running from importable in workers even when
     # it is not pip-installed and the script lives elsewhere (the
@@ -400,6 +482,11 @@ def build_worker_env(
                 print(f"hvtpurun: warning: -x {spec}: variable not "
                       "found in the launcher environment",
                       file=sys.stderr)
+    # One chip per local rank, unless this is a CPU run.  After the
+    # flags and -x so that either way of asking for the CPU platform
+    # counts.
+    if not _cpu_mode(env):
+        env.update(tpu_chip_env(slot))
     return env
 
 
@@ -437,7 +524,7 @@ def build_ssh_command(
         f"{k}={shlex.quote(v)}"
         for k, v in sorted(env.items())
         if (k.startswith(("HVTPU_", "HOROVOD_", "JAX_", "XLA_", "TPU_",
-                          "PYTHONPATH")) or k in extra)
+                          "CLOUD_TPU_", "PYTHONPATH")) or k in extra)
         # never serialize the HMAC key itself into argv — it would be
         # world-readable via /proc/*/cmdline on both ends; the key
         # rides a 0600 file (HVTPU_SECRET_FILE) instead
@@ -483,6 +570,12 @@ def launch_workers(
     (HVTPU_START_TIMEOUT -> jax.distributed initialization_timeout).
     """
     base_env = dict(base_env if base_env is not None else os.environ)
+    refusal = check_chip_assignment(
+        slots, _cpu_mode(base_env)
+        or (args is not None and args.cpu_devices is not None))
+    if refusal:
+        print(f"hvtpurun: refusing to launch: {refusal}", file=sys.stderr)
+        return 2
     stdout_lock = threading.Lock()
     uniform = uniform_local_size(slots)
     ssh_opts = ssh_options_from_args(args)

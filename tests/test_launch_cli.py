@@ -1,6 +1,6 @@
 """End-to-end CLI launches: ``hvtpurun -np N python examples/...`` as a
 real subprocess invocation — the reference's `horovodrun -np 2 python
-train.py` acceptance path (VERDICT round-1 task 1 'done when')."""
+train.py` acceptance path."""
 
 import os
 import subprocess

@@ -858,9 +858,8 @@ class TestNativeGaussianProcess:
 
 class TestWheelBuild:
     """pip install compiles the native core into the wheel (parity:
-    the reference's setup.py/CMake build — SURVEY.md §2.3; VERDICT
-    round-2 task 8: 'pip install . on a clean box yields the C++
-    path')."""
+    the reference's setup.py/CMake build — SURVEY.md §2.3: 'pip
+    install . on a clean box yields the C++ path')."""
 
     def test_wheel_contains_loadable_native_core(self, tmp_path):
         import ctypes

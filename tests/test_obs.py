@@ -345,7 +345,7 @@ class TestAutotunerWiring:
 
 
 class TestAutotunerControllerWiring:
-    """VERDICT round-1 task 6(b): the autotuner's cycle-time AND fusion
+    """The autotuner's cycle-time AND fusion
     threshold must reach the LIVE controller, not just the jit path."""
 
     def test_autotuner_applies_to_controller(self, hvt):

@@ -9,11 +9,10 @@ fallback uses — the same executable-spec pattern as test_native.py's
 C++/Python cross-check.
 """
 
-import os
-
 import numpy as np
 import pytest
 
+import jax
 import jax.numpy as jnp
 
 from horovod_tpu.ops import (
@@ -96,6 +95,64 @@ class TestQuantizeInt8:
         assert not np.asarray(q).any()
         out = dequantize_int8_blocks(q, scale, n)
         assert not np.asarray(out).any()
+
+
+class TestLowersForTpu:
+    """Lower every ``pallas_call`` site for the chip, interpreter off:
+    the interpreter accepts what the chip's toolchain refuses, and a
+    refusal should fail here, not on a chip run."""
+
+    # ResNet-50's gradient count: a main grid plus a remainder tile
+    # that is not a multiple of the int8 tile height
+    N = 25_557_032
+
+    @pytest.fixture(autouse=True)
+    def compiled_mode(self, monkeypatch):
+        monkeypatch.delenv("HVTPU_PALLAS_INTERPRET", raising=False)
+        monkeypatch.setattr(pallas_ops, "_on_tpu", lambda: True)
+
+    def _lower(self, f, *shapes):
+        return jax.jit(f).trace(*shapes).lower(
+            lowering_platforms=("tpu",)).as_text()
+
+    @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+    @pytest.mark.parametrize("stochastic", [False, True])
+    def test_quantize(self, dtype, stochastic):
+        text = self._lower(
+            lambda x, seed: quantize_int8_blocks(
+                x, stochastic=stochastic, seed=seed)[:2],
+            jax.ShapeDtypeStruct((self.N,), dtype),
+            jax.ShapeDtypeStruct((), jnp.int32))
+        assert text.count("tpu_custom_call") >= 2  # main + remainder
+
+    def test_dequantize(self):
+        rows = -(-self.N // QBLOCK) * 8
+        text = self._lower(
+            lambda q, s: dequantize_int8_blocks(q, s, self.N),
+            jax.ShapeDtypeStruct((rows, 128), jnp.int8),
+            jax.ShapeDtypeStruct((rows // 8, 1), jnp.float32))
+        assert text.count("tpu_custom_call") >= 2
+
+    @pytest.mark.parametrize("out_dtype", [jnp.float32, jnp.bfloat16])
+    def test_fused_scale_cast(self, out_dtype):
+        text = self._lower(
+            lambda x: fused_scale_cast(x, 0.125, out_dtype),
+            jax.ShapeDtypeStruct((self.N,), jnp.float32))
+        assert text.count("tpu_custom_call") >= 2
+
+    def test_float16_takes_the_xla_twin(self):
+        """Mosaic has no f16 vector type on v5e (the chip's compiler
+        said so): f16 buffers must not reach a kernel."""
+        x16 = jax.ShapeDtypeStruct((70000,), jnp.float16)
+        x32 = jax.ShapeDtypeStruct((70000,), jnp.float32)
+        for text in (
+                self._lower(lambda x: fused_scale_cast(x, 2.0), x16),
+                self._lower(
+                    lambda x: fused_scale_cast(x, 2.0, jnp.float16), x32)):
+            assert "tpu_custom_call" not in text
+        # quantize pre-casts f16 in XLA; the kernel sees f32
+        text = self._lower(lambda x: quantize_int8_blocks(x)[:2], x16)
+        assert "tpu_custom_call" in text and "f16" in text
 
 
 class TestInt8CompressorIntegration:

@@ -1,4 +1,4 @@
-"""RayExecutor's actor path (VERDICT r4 #5), driven by a MOCKED ray
+"""RayExecutor's actor path, driven by a MOCKED ray
 module — the same pattern the reference uses to unit-test its launcher
 with mocked ssh (SURVEY §4.3).  Asserts actors are created with the
 requested resources, each rank's env carries the launcher-equivalent

@@ -4,8 +4,11 @@ ICI remote DMA.
 
 On this CPU test platform the REAL kernel bodies run under the Pallas
 TPU interpreter, which simulates the remote DMAs + semaphores across
-the 8 shard_map devices — so the double-buffer protocol, the per-slot
-semaphore accounting, and the ACK backpressure all actually execute.
+the 8 shard_map devices — so the entry barrier, the double-buffer
+protocol, the per-slot semaphore accounting, and the ACK backpressure
+all actually execute.  The interpreter accepts things the chip's
+toolchain refuses, so every kernel is also lowered for TPU with the
+interpreter off (TestLowersForTpu).
 """
 
 import numpy as np
@@ -66,26 +69,28 @@ class TestRingAllreduce:
             np.asarray(out), np.asarray(x).mean(0), rtol=1e-5, atol=1e-6
         )
 
-    def test_integer_dtype_consistent_across_backends(self, monkeypatch):
-        # ints must take the exact psum path with the SAME dtype no
-        # matter which backend flag is set (regression: pallas path
-        # returned f32 for ints)
+    def test_spans_several_kernel_calls(self, monkeypatch):
+        """A buffer over one call's VMEM share is reduced slice by
+        slice; shrink the share so a small tensor takes the sliced
+        path, ragged tail included."""
+        from horovod_tpu.ops import ring as ring_mod
+
+        # (every interpreter buffer stays under the CPU client's 100 KiB
+        # inline-copy limit: larger ones need a free pool thread, and
+        # 8 blocked device callbacks on 8 cores leave none)
+        monkeypatch.setattr(ring_mod, "_MAX_CHUNK_ROWS", 8)
+        per_rank = 8 * 2 * 8 * 128 + 77   # 3 slices of 8 rows/rank
         x = jnp.asarray(
-            np.arange(8 * 64, dtype=np.int32).reshape(8, 64)
+            np.random.RandomState(9).randint(-100, 100, (8, per_rank))
+            .astype(np.float32)
         )
-        out_pallas = _run(
+        out = _run(
             lambda xs: ring_allreduce(xs[0], axis_name=AXIS),
             x, out_specs=P(),
         )
-        monkeypatch.setenv("HVTPU_PALLAS", "0")
-        out_psum = _run(
-            lambda xs: ring_allreduce(xs[0], axis_name=AXIS),
-            x, out_specs=P(),
-        )
-        assert out_pallas.dtype == out_psum.dtype == jnp.int32
+        # integer-valued f32: sums are exact in any order
         np.testing.assert_array_equal(
-            np.asarray(out_pallas), np.asarray(out_psum)
-        )
+            np.asarray(out), np.asarray(x).sum(0))
 
     def test_nd_shape_and_dtype_restore(self):
         x = jnp.asarray(
@@ -156,34 +161,89 @@ class TestRingAllgather:
         np.testing.assert_array_equal(np.asarray(out), np.asarray(x))
 
 
-class TestFallbacks:
-    def test_no_pallas_falls_back_to_psum(self, monkeypatch):
-        monkeypatch.setenv("HVTPU_PALLAS", "0")
-        x = jnp.asarray(
-            np.random.RandomState(5).randn(8, 100).astype(np.float32)
-        )
-        out = _run(
-            lambda xs: ring_allreduce(xs[0], axis_name=AXIS),
+class TestRefusals:
+    """Asked for what the kernels cannot do, the entry points raise;
+    they never run an XLA collective in the kernel's name."""
+
+    def _allreduce(self, x, **kw):
+        return _run(
+            lambda xs: ring_allreduce(xs[0], axis_name=AXIS, **kw),
             x, out_specs=P(),
-        )
-        np.testing.assert_allclose(
-            np.asarray(out), np.asarray(x).sum(0), rtol=1e-6
         )
 
-    def test_no_pallas_quantized_falls_back_to_xla_path(self, monkeypatch):
+    def test_pallas_switched_off_raises(self, monkeypatch):
         monkeypatch.setenv("HVTPU_PALLAS", "0")
-        x = jnp.asarray(
-            np.random.RandomState(6).randn(8, 2048).astype(np.float32)
-        )
-        out = _run(
+        x = jnp.ones((8, 100), jnp.float32)
+        with pytest.raises(RuntimeError, match="HVTPU_PALLAS='0'"):
+            self._allreduce(x)
+        with pytest.raises(RuntimeError, match="ring kernels run on a TPU"):
+            self._allreduce(x, quantized=True)
+
+    def test_cpu_without_the_interpreter_raises(self, monkeypatch):
+        monkeypatch.delenv("HVTPU_PALLAS_INTERPRET")
+        with pytest.raises(RuntimeError, match="platform is 'cpu'"):
+            self._allreduce(jnp.ones((8, 100), jnp.float32))
+        with pytest.raises(RuntimeError, match="platform is 'cpu'"):
+            _run(lambda xs: ring_allgather_2d(xs, axis_name=AXIS),
+                 jnp.ones((8 * 8, 128), jnp.float32), out_specs=P())
+
+    def test_quantized_ring_knob_never_runs_the_xla_path(self, monkeypatch):
+        from horovod_tpu.comm.quantized import quantized_allreduce
+
+        monkeypatch.delenv("HVTPU_PALLAS_INTERPRET")
+        monkeypatch.setenv("HVTPU_QUANTIZED_RING", "1")
+        with pytest.raises(RuntimeError, match="ring kernels run on a TPU"):
+            _run(lambda xs: quantized_allreduce(xs[0], axis_name=AXIS),
+                 jnp.ones((8, 2048), jnp.float32), out_specs=P())
+
+    def test_integers_raise(self):
+        x = jnp.arange(8 * 64, dtype=jnp.int32).reshape(8, 64)
+        with pytest.raises(TypeError, match="use lax.psum"):
+            self._allreduce(x)
+
+    def test_allgather_block_shape_is_checked(self):
+        with pytest.raises(ValueError, match="multiple of 8"):
+            _run(lambda xs: ring_allgather_2d(xs, axis_name=AXIS),
+                 jnp.ones((8 * 4, 128), jnp.float32), out_specs=P())
+
+
+class TestLowersForTpu:
+    """Lower every ring ``pallas_call`` for the chip, interpreter off.
+    The interpreter hid a lowering-time refusal for twenty PRs
+    (``collective_id`` without a barrier semaphore); this is where the
+    next one fails — on the CPU, not on a chip run."""
+
+    @pytest.fixture(autouse=True)
+    def compiled_mode(self, monkeypatch):
+        from horovod_tpu.ops import pallas_ops
+
+        monkeypatch.delenv("HVTPU_PALLAS_INTERPRET")
+        monkeypatch.setattr(pallas_ops, "_on_tpu", lambda: True)
+
+    def _lower(self, body, x, out_specs):
+        text = jax.jit(
+            jax.shard_map(body, mesh=mesh8(), in_specs=(P(AXIS),),
+                          out_specs=out_specs, check_vma=False)
+        ).trace(x).lower(lowering_platforms=("tpu",)).as_text()
+        assert "tpu_custom_call" in text
+        return text
+
+    @pytest.mark.parametrize("quantized", [False, True])
+    @pytest.mark.parametrize("per_rank", [4096, 3_000_001])
+    def test_allreduce(self, quantized, per_rank):
+        x = jax.ShapeDtypeStruct((8, per_rank), jnp.float32)
+        text = self._lower(
             lambda xs: ring_allreduce(
-                xs[0], axis_name=AXIS, quantized=True
-            ),
-            x, out_specs=P(),
-        )
-        want = np.asarray(x).sum(0)
-        amax = np.abs(np.asarray(x)).max()
-        assert np.abs(np.asarray(out) - want).max() <= 8 * 3 * amax / 127
+                xs[0], axis_name=AXIS, quantized=quantized),
+            x, P())
+        # the entry barrier and an explicit VMEM budget are in the call
+        assert "collective_id" in text
+        assert "scoped_memory_configs" in text
+
+    def test_allgather(self):
+        x = jax.ShapeDtypeStruct((8 * 64, 128), jnp.float32)
+        self._lower(lambda xs: ring_allgather_2d(xs, axis_name=AXIS),
+                    x, P())
 
 
 class TestEngineIntegration:
@@ -195,11 +255,6 @@ class TestEngineIntegration:
         from horovod_tpu.comm.compression import Compression
         from horovod_tpu.comm.reduce_ops import ReduceOp
         from horovod_tpu.ops import ring as ring_mod
-
-        if ring_mod._interpret_arg() is None:
-            pytest.skip("Pallas interpreter cannot run the ring kernels "
-                        "on this jax (no remote-DMA simulation); the "
-                        "engine correctly falls back to the XLA path")
 
         # the XLA two-phase path would also satisfy the numeric bound,
         # so additionally prove the ring kernel actually ran

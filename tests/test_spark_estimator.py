@@ -166,7 +166,7 @@ class TestTorchEstimator:
 
     @pytest.mark.slow  # tier-1 runtime diet: heaviest in the --durations audit; full matrix via -m slow
     def test_resume_from_checkpoint_2proc(self, tmp_path):
-        """VERDICT r4 #8: refit with the same run_id and
+        """Refit with the same run_id and
         resume_from_checkpoint=True continues from the Store
         checkpoint — the second fit's first-epoch loss picks up near
         the first fit's last-epoch loss, not the fresh-weights loss."""

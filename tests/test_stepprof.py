@@ -218,13 +218,18 @@ class TestCollector:
         # union [t-10ms, t-2ms] = 8 ms, not 6+4
         assert 0.004 < after["sum"] - before["sum"] < 0.5
 
-    def test_mfu_gauge_from_step_flops(self):
+    def test_mfu_gauge_from_step_flops(self, monkeypatch):
+        # the CPU test device has no table entry: name the peak
+        monkeypatch.setenv("HVTPU_STEPPROF_PEAK_TFLOPS", "197")
         c = stepprof.get_collector()
         c.set_step_flops(stepprof.peak_flops() * 0.01)  # 1% of peak/s
-        c.note_step_boundary()
-        import time as _time
-        _time.sleep(0.01)
-        c.note_step_boundary()
+        try:
+            c.note_step_boundary()
+            import time as _time
+            _time.sleep(0.01)
+            c.note_step_boundary()
+        finally:
+            c.set_step_flops(None)
         v = stepprof.MFU.value()
         assert v > 0
 
@@ -235,7 +240,7 @@ class TestCollector:
             dbg = debug_snapshot()
             assert "stepprof" in dbg
             st = dbg["stepprof"]
-            for key in ("active", "steps", "peak_tflops", "mfu",
+            for key in ("active", "steps", "mfu",
                         "overlap_fraction", "last_step"):
                 assert key in st
         finally:
@@ -290,8 +295,10 @@ class TestMeasuredFlops:
             pytest.skip("backend exposes no cost analysis")
         # 2*M*N*K with some tolerance for backend accounting
         assert 64 ** 3 < flops < 8 * 64 ** 3
-        assert stepprof.mfu(flops, 1.0) == pytest.approx(
-            flops / stepprof.peak_flops())
+        v5e = stepprof.peak_flops("TPU v5 lite")
+        assert v5e == 197e12
+        assert stepprof.mfu(flops, 1.0, peak=v5e) == pytest.approx(
+            flops / v5e)
 
     def test_measured_flops_tolerates_junk(self):
         class NoCA:

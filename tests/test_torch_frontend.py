@@ -392,7 +392,7 @@ class TestSyncBatchNorm:
 
 
 class TestZeroCopyAdapter:
-    """The DLPack adapter boundary (VERDICT round-1 task 5): contiguous
+    """The DLPack adapter boundary : contiguous
     fp32 tensors must cross torch->jax and jax->torch with NO host
     copy, asserted by buffer pointer identity."""
 
@@ -509,7 +509,7 @@ class TestFusedBroadcastParameters:
 
 
 class TestEagerBenchRegression:
-    """CI-side anchors for BENCH_EAGER.json (VERDICT round-2 task 3):
+    """CI-side anchors for BENCH_EAGER.json:
     the eager path's tracked properties fail a test here rather than
     only drifting in the recorded tables."""
 
