@@ -43,10 +43,17 @@ Observability: ``hvtpu_data_*`` metrics (docs/observability.md), a
 ``DATA_WAIT`` trace phase so ``hvtputrace report`` attributes
 stragglers to input vs compute vs comms, loader state in ``/debug``,
 and the ``data.next`` fault site (delay/error/drop) for chaos runs.
+The prefetch thread times the three stages of every batch it queues —
+fetch, transform, blocked on the full queue — into
+``hvtpu_data_{fetch,transform,backpressure}_seconds``, and writes the
+same stages as ``hvtpu:loader.*`` spans into a ``jax.profiler`` trace
+when one is being taken: the producer is either making a batch or
+parked, whichever call the consumer happens to block in.
 """
 
 from __future__ import annotations
 
+import collections
 import logging
 import os
 import queue
@@ -70,6 +77,21 @@ _M_WAIT = obs_metrics.histogram(
     "Time the training loop blocked waiting on the input pipeline per "
     "batch (the data-stall half of the straggler decomposition).",
     buckets=obs_metrics.DEFAULT_TIME_BUCKETS)
+_M_FETCH = obs_metrics.histogram(
+    "hvtpu_data_fetch_seconds",
+    "Time the prefetch thread spent planning and fetching one batch "
+    "from the source.",
+    buckets=obs_metrics.DEFAULT_TIME_BUCKETS)
+_M_TRANSFORM = obs_metrics.histogram(
+    "hvtpu_data_transform_seconds",
+    "Time the prefetch thread spent in the user transform and the "
+    "device_put of one batch, whichever are set.",
+    buckets=obs_metrics.DEFAULT_TIME_BUCKETS)
+_M_BACKPRESSURE = obs_metrics.histogram(
+    "hvtpu_data_backpressure_seconds",
+    "Time the prefetch thread was parked on a full queue before one "
+    "batch went in (near zero throughout: the loader paces the job).",
+    buckets=obs_metrics.DEFAULT_TIME_BUCKETS)
 _M_QDEPTH = obs_metrics.gauge(
     "hvtpu_data_queue_depth",
     "Prefetch queue depth sampled at each batch delivery (0 means the "
@@ -84,6 +106,33 @@ _M_RESHARDS = obs_metrics.counter(
     "hvtpu_data_reshards_total",
     "Iterator-state restores applied (elastic resync / rollback): each "
     "re-partitions the unconsumed epoch remainder across the world.")
+
+# The stage boundaries of the last batches queued, oldest first: what
+# the three producer histograms sum, batch by batch, so that a reader
+# can take the batches of a window of its own (``recent_stages``).
+_STAGES = collections.deque(maxlen=4096)
+
+
+def _note_stages(t0: float, t1: float, t2: float, t3: float) -> None:
+    """One queued batch, from the four readings the prefetch thread
+    took of ``time.perf_counter()``: before the plan, after the fetch,
+    after transform and device_put, after the ``queue.put`` that took
+    the batch."""
+    _M_FETCH.observe(t1 - t0)
+    _M_TRANSFORM.observe(t2 - t1)
+    _M_BACKPRESSURE.observe(t3 - t2)
+    _STAGES.append((t0, t1, t2, t3))
+
+
+def recent_stages() -> list:
+    """``(t0, t1, t2, t3)`` in ``time.perf_counter()`` seconds for each
+    of the last 4096 batches the loaders of this process queued, oldest
+    first: fetch is ``t1 - t0``, transform ``t2 - t1``, backpressure
+    ``t3 - t2``.  The histograms hold the process's sums; this is for
+    whoever needs a window's (the benchmark's ``input_*`` metrics take
+    the batches of its untraced window)."""
+    return list(_STAGES)
+
 
 # live loaders for the /debug endpoint and the pre-exit quiesce hook
 _LIVE: Dict[str, "ElasticDataLoader"] = {}
@@ -296,6 +345,7 @@ class ElasticDataLoader:
     def _prefetch_loop(self) -> None:
         n = self._n
         sharder = self._sharder
+        clock = time.perf_counter
         while not self._stop.is_set():
             with self._lock:
                 if self._plan_version != self.state.version:
@@ -314,26 +364,40 @@ class ElasticDataLoader:
                 version = self._plan_version
                 epoch = self._plan_epoch
                 cursor = self._plan_cursor
+            # One clock reading at each of a batch's four boundaries;
+            # each stage's span opens and closes where its two are
+            # taken, and _note_stages turns the four into the counters.
+            # A stage that raises, or a put cut short by quiesce(),
+            # notes nothing.
             try:
-                indices, new_cursor = sharder.next_indices(
-                    epoch, cursor, self._rank, self._size)
-                batch = self.source.fetch(indices)
-                if self.transform is not None:
-                    batch = self.transform(batch)
-                if self._device_put:
-                    batch = self._to_device(batch)
+                with tracing.span("loader.fetch"):
+                    t0 = clock()
+                    indices, new_cursor = sharder.next_indices(
+                        epoch, cursor, self._rank, self._size)
+                    batch = self.source.fetch(indices)
+                    t1 = clock()
+                with tracing.span("loader.transform"):
+                    if self.transform is not None:
+                        batch = self.transform(batch)
+                    if self._device_put:
+                        batch = self._to_device(batch)
+                    t2 = clock()
             except BaseException as e:  # noqa: BLE001 - forwarded to consumer
                 with self._lock:
                     self._pending_error = e
                 return
             item = _Item(version, epoch, cursor, new_cursor, indices,
                          batch)
-            while not self._stop.is_set():
-                try:
-                    self._queue.put(item, timeout=0.2)
-                    break
-                except queue.Full:
-                    continue
+            t3 = None
+            with tracing.span("loader.put"):
+                while t3 is None and not self._stop.is_set():
+                    try:
+                        self._queue.put(item, timeout=0.2)
+                        t3 = clock()
+                    except queue.Full:
+                        continue
+            if t3 is not None:
+                _note_stages(t0, t1, t2, t3)
             with self._lock:
                 if self._plan_version == version:
                     self._plan_cursor = new_cursor
@@ -376,38 +440,42 @@ class ElasticDataLoader:
             return item
 
     def _deliver(self) -> Tuple[np.ndarray, Any]:
-        t0 = time.perf_counter()
-        t_wall0 = time.time()
-        if tracing.ACTIVE:
-            tracing.op_begin(f"data/{self.name}", kind="data",
-                             phase=tracing.DATA_WAIT,
-                             epoch=self.state.epoch,
-                             cursor=self.state.cursor)
-        try:
-            dropped = False
-            if faults.ACTIVE:
-                # delay stalls inside the DATA_WAIT span (an injected
-                # input straggler); error raises; drop loses one batch
-                dropped = faults.inject(
-                    "data.next",
-                    detail=f"{self.name}@{self.state.epoch}:"
-                           f"{self.state.cursor}")
-            item = self._next_item()
-            if dropped:
-                logger.warning(
-                    "data loader %r: injected drop lost batch "
-                    "epoch=%d cursor=%d (%d samples)", self.name,
-                    item.epoch, item.cursor_before, len(item.indices))
-                self.state.cursor = item.cursor_after
-                item = self._next_item()
-        finally:
+        # the span opens and closes where the counter's two readings of
+        # the clock are taken, as the producer's three do
+        with tracing.span("loader.wait"):
+            t0 = time.perf_counter()
+            t_wall0 = time.time()
             if tracing.ACTIVE:
-                tracing.op_done(f"data/{self.name}")
-            if stepprof.ACTIVE:
-                # Wall-clock window for the overlap profiler's
-                # per-step data-wait bucket (obs/stepprof).
-                stepprof.note_data_wait(t_wall0, time.time())
-        _M_WAIT.observe(time.perf_counter() - t0)
+                tracing.op_begin(f"data/{self.name}", kind="data",
+                                 phase=tracing.DATA_WAIT,
+                                 epoch=self.state.epoch,
+                                 cursor=self.state.cursor)
+            try:
+                dropped = False
+                if faults.ACTIVE:
+                    # delay stalls inside the DATA_WAIT span (an injected
+                    # input straggler); error raises; drop loses one batch
+                    dropped = faults.inject(
+                        "data.next",
+                        detail=f"{self.name}@{self.state.epoch}:"
+                               f"{self.state.cursor}")
+                item = self._next_item()
+                if dropped:
+                    logger.warning(
+                        "data loader %r: injected drop lost batch "
+                        "epoch=%d cursor=%d (%d samples)", self.name,
+                        item.epoch, item.cursor_before, len(item.indices))
+                    self.state.cursor = item.cursor_after
+                    item = self._next_item()
+            finally:
+                if tracing.ACTIVE:
+                    tracing.op_done(f"data/{self.name}")
+                if stepprof.ACTIVE:
+                    # Wall-clock window for the overlap profiler's
+                    # per-step data-wait bucket (obs/stepprof).
+                    stepprof.note_data_wait(t_wall0, time.time())
+            waited = time.perf_counter() - t0
+        _M_WAIT.observe(waited)
         if item.cursor_before != self.state.cursor \
                 or item.epoch != self.state.epoch:
             raise RuntimeError(

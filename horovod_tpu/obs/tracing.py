@@ -299,6 +299,29 @@ def step_boundary(wall_us: float, steps: float = 1.0, **args):
         t.instant(STEP_BOUNDARY, wall_us=wall_us, steps=steps, **args)
 
 
+# ---- spans on the profiler's clock ----------------------------------------
+# Unlike the spans above these need no tracer and no ACTIVE guard: a
+# ``jax.profiler`` session is the switch.  Inside one the span lands in
+# the trace's host plane, on the line of the thread that wrote it and on
+# the clock the device planes share, so a device idle gap can be laid
+# against what the host was doing; outside one it costs under a
+# microsecond and writes nothing.
+
+_TraceAnnotation = None
+
+
+def span(name: str):
+    """Context manager: the span ``"hvtpu:" + name`` in whatever
+    ``jax.profiler`` trace is being taken.  The only place the program
+    makes a ``TraceAnnotation``; docs/observability.md lists the names."""
+    global _TraceAnnotation
+    if _TraceAnnotation is None:
+        from jax.profiler import TraceAnnotation
+
+        _TraceAnnotation = TraceAnnotation
+    return _TraceAnnotation("hvtpu:" + name)
+
+
 def install(trace_dir: str, rank: int = 0, size: int = 1, client=None,
             pings: int = 8) -> Tracer:
     """Create the process tracer and flip the ACTIVE fast-path flag.

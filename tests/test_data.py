@@ -301,6 +301,219 @@ class TestLoader:
 
 
 # ---------------------------------------------------------------------------
+# the producer's side: three counters and four spans at one set of boundaries
+# ---------------------------------------------------------------------------
+
+_STAGE_COUNTERS = ("hvtpu_data_fetch_seconds",
+                   "hvtpu_data_transform_seconds",
+                   "hvtpu_data_backpressure_seconds")
+
+
+def _stage_totals():
+    """``{counter: (sum, count)}`` of the loader's four histograms."""
+    from horovod_tpu.obs import metrics as obs_metrics
+
+    snap = obs_metrics.snapshot()
+    out = {}
+    for name in _STAGE_COUNTERS + ("hvtpu_data_wait_seconds",):
+        cells = snap[name]["values"].values()
+        out[name] = (sum(c["sum"] for c in cells),
+                     sum(c["count"] for c in cells))
+    return out
+
+
+def _stage_deltas(before):
+    after = _stage_totals()
+    return {name: (after[name][0] - before[name][0],
+                   after[name][1] - before[name][1]) for name in after}
+
+
+def _stages_since(t):
+    from horovod_tpu.data import loader as loader_mod
+
+    return [s for s in loader_mod.recent_stages() if s[0] >= t]
+
+
+def _busy_share(deltas):
+    fetch, transform, parked = (deltas[name][0] for name in _STAGE_COUNTERS)
+    return (fetch + transform) / (fetch + transform + parked)
+
+
+class _SleepySource(ArraySource):
+    def __init__(self, n, seconds):
+        super().__init__({"y": np.arange(n)})
+        self.seconds = seconds
+
+    def fetch(self, indices):
+        time.sleep(self.seconds)
+        return super().fetch(indices)
+
+
+class _FailingSource(ArraySource):
+    """Raises on the ``fail_on``-th fetch."""
+
+    def __init__(self, n, fail_on):
+        super().__init__({"y": np.arange(n)})
+        self.fail_on, self.fetches = fail_on, 0
+
+    def fetch(self, indices):
+        self.fetches += 1
+        if self.fetches == self.fail_on:
+            raise OSError("shard unreadable")
+        return super().fetch(indices)
+
+
+class TestProducerStages:
+    def test_each_histogram_gains_one_observation_a_batch(self):
+        before = _stage_totals()
+        ld = _make_loader(n=40, batch=4)
+        try:
+            assert len(list(ld)) == 10
+        finally:
+            ld.close()      # the thread is joined: the counts stand still
+        deltas = _stage_deltas(before)
+        counts = {deltas[name][1] for name in _STAGE_COUNTERS}
+        assert len(counts) == 1, deltas
+        # every batch delivered was queued, and the producer runs at
+        # most the queue's depth ahead of the consumer
+        assert 10 <= counts.pop() <= 10 + ld.prefetch_depth
+        assert deltas["hvtpu_data_wait_seconds"][1] == 10
+
+    def test_slow_source_keeps_the_producer_busy(self):
+        before = _stage_totals()
+        ld = ElasticDataLoader(_SleepySource(40, 0.02), batch_size=4,
+                               device_put=False)
+        try:
+            list(ld)
+        finally:
+            ld.close()
+        deltas = _stage_deltas(before)
+        assert _busy_share(deltas) > 0.9, deltas
+        fetch_s, batches = deltas["hvtpu_data_fetch_seconds"]
+        assert fetch_s / batches >= 0.02
+
+    def test_slow_consumer_parks_the_producer(self):
+        before = _stage_totals()
+        ld = _make_loader(n=48, batch=4)
+        slept = 0.0
+        try:
+            for _ in ld:
+                t0 = time.perf_counter()
+                time.sleep(0.03)
+                slept += time.perf_counter() - t0
+        finally:
+            ld.close()
+        deltas = _stage_deltas(before)
+        assert _busy_share(deltas) < 0.2, deltas
+        # the queue holds two and a third is parked when the consumer
+        # first sleeps; each later sleep is one put's wait
+        parked_s = deltas["hvtpu_data_backpressure_seconds"][0]
+        assert 0.6 * slept <= parked_s <= slept + 0.05, (parked_s, slept)
+
+    def test_a_fetch_that_raises_reaches_the_consumer_and_notes_nothing(self):
+        before = _stage_totals()
+        ld = ElasticDataLoader(_FailingSource(24, fail_on=3), batch_size=4,
+                               device_put=False)
+        try:
+            it = iter(ld)
+            next(it), next(it)
+            with pytest.raises(RuntimeError, match="prefetch failed") as ei:
+                next(it)
+            assert isinstance(ei.value.__cause__, OSError)
+        finally:
+            ld.close()
+        deltas = _stage_deltas(before)
+        assert {deltas[name][1] for name in _STAGE_COUNTERS} == {2}
+
+    def test_recent_stages_holds_what_the_counters_summed(self):
+        t_before = time.perf_counter()
+        before = _stage_totals()
+        ld = _make_loader(n=24, batch=4)
+        try:
+            list(ld)
+        finally:
+            ld.close()
+        deltas = _stage_deltas(before)
+        stages = _stages_since(t_before)
+        assert len(stages) == deltas["hvtpu_data_fetch_seconds"][1]
+        assert all(t0 <= t1 <= t2 <= t3 <= time.perf_counter()
+                   for t0, t1, t2, t3 in stages)
+        for k, name in enumerate(_STAGE_COUNTERS):
+            assert sum(s[k + 1] - s[k] for s in stages) == pytest.approx(
+                deltas[name][0], abs=1e-9)
+
+    def test_a_profile_holds_the_four_spans_and_they_match_the_counters(
+            self, tmp_path):
+        import glob
+
+        import jax
+        from jax.profiler import ProfileData
+
+        options = jax.profiler.ProfileOptions()
+        options.python_tracer_level = 0     # the benchmark's options
+        options.host_tracer_level = 1
+        t_before = time.perf_counter()
+        before = _stage_totals()
+        jax.profiler.start_trace(str(tmp_path), profiler_options=options)
+        try:
+            ld = ElasticDataLoader(
+                _SleepySource(24, 0.004), batch_size=4, device_put=False,
+                transform=lambda b: (time.sleep(0.002), b)[1])
+            try:
+                for _ in ld:
+                    time.sleep(0.01)
+            finally:
+                ld.close()
+        finally:
+            jax.profiler.stop_trace()
+        deltas = _stage_deltas(before)
+        stages = _stages_since(t_before)
+        (path,) = glob.glob(str(tmp_path / "plugins/profile/*/*.xplane.pb"))
+        lines = {}   # one line of the host plane per thread
+        for plane in ProfileData.from_file(path).planes:
+            if not plane.name.startswith("/host:"):
+                continue
+            for k, line in enumerate(plane.lines):
+                for e in line.events:
+                    if e.name.startswith("hvtpu:loader."):
+                        lines.setdefault(k, {}).setdefault(
+                            e.name[len("hvtpu:loader."):], []).append(
+                                (e.start_ns, e.duration_ns / 1e9))
+        assert sorted(map(sorted, lines.values())) == [
+            ["fetch", "put", "transform"], ["wait"]]
+        producer, consumer = sorted(lines.values(), key=len, reverse=True)
+        # a batch parked when the loader closed has spans and no
+        # observation; every other span is one observation's length
+        for k, stage in enumerate(("fetch", "transform", "put")):
+            spans = [s for _, s in sorted(producer[stage])]
+            assert len(stages) <= len(spans) <= len(stages) + 1
+            for span_s, s in zip(spans, stages):
+                assert span_s == pytest.approx(s[k + 1] - s[k], abs=1e-3)
+        waits = [s for _, s in consumer["wait"]]
+        wait_s, wait_n = deltas["hvtpu_data_wait_seconds"]
+        assert len(waits) == wait_n == 6
+        assert sum(waits) == pytest.approx(wait_s, abs=wait_n * 1e-3)
+
+    def test_outside_a_profiler_session_a_span_writes_nothing(self, tmp_path):
+        from horovod_tpu.obs import tracing
+
+        assert tracing.ACTIVE is False and tracing.get_tracer() is None
+        with tracing.span("loader.fetch"):
+            pass
+        assert tracing.ACTIVE is False and tracing.get_tracer() is None
+        # nor into the program's own trace, which HVTPU_TRACE switches
+        tracing.install(str(tmp_path))
+        try:
+            with tracing.span("loader.fetch"):
+                assert tracing.ACTIVE is True
+        finally:
+            tracing.uninstall()
+        (trace,) = tmp_path.iterdir()
+        assert "loader.fetch" not in trace.read_text()
+        assert not list(tmp_path.glob("plugins"))
+
+
+# ---------------------------------------------------------------------------
 # fault site
 # ---------------------------------------------------------------------------
 
