@@ -39,6 +39,7 @@ _HERE = os.path.dirname(os.path.abspath(__file__))
 sys.path.insert(0, _HERE)
 
 STEPS = 4  # optimizer steps after the compiling one
+INPUT_BATCHES = 300  # batches phase_input reads back
 _PREFIX = ""  # "REHEARSAL " under --rehearse-on-cpu
 
 
@@ -166,11 +167,13 @@ class TrainJob:
             return {k: jax.device_put(v, self.batch_sharding)
                     for k, v in batch.items()}
 
+        self.pool = {"x": images, "y": labels}
+        self.source = hvt.data.ArraySource(self.pool)
+        self.place = place
         # device_put=False + an explicit placing transform: a failed
         # transfer fails the prefetch, it is not retried on the host
         self.loader = hvt.data.ElasticDataLoader(
-            hvt.data.ArraySource({"x": images, "y": labels}),
-            batch_size=self.global_batch, shuffle=True, seed=0,
+            self.source, batch_size=self.global_batch, shuffle=True, seed=0,
             device_put=False, transform=place, name="chip_smoke")
         self.batches = self.loader.stream()
         self.opt_state = None
@@ -228,6 +231,96 @@ class TrainJob:
         if not math.isfinite(loss):
             raise AssertionError(f"non-finite loss {loss}")
         return loss, dt, batch
+
+
+def phase_input(job: TrainJob, rehearse: bool):
+    """The loader's batches are the pool's rows, bit for bit, on the
+    device.  ``ArraySource.fetch`` gathers into blocks of memory it used
+    before, as soon as nothing refers to a block any more, while
+    ``device_put`` returns long before its copy to the chips has read
+    the block: a batch written over in flight would train on as if
+    nothing had happened (random images, random labels), so this is the
+    check that can see one.  A second loader over the job's source and
+    placing transform, with the indices; every batch read back after
+    ``block_until_ready``."""
+    import jax
+    import numpy as np
+
+    import horovod_tpu as hvt
+    from horovod_tpu.obs import metrics as program_metrics
+
+    counter = program_metrics.REGISTRY.counter(
+        "hvtpu_data_fetch_blocks_total")
+    reused0, fresh0 = (counter.value(block="reused"),
+                       counter.value(block="fresh"))
+    in_flight = []  # per batch: (copy not landed yet, references it holds)
+
+    def place_and_count(batch):
+        # whoever holds the leaf holds the pool's block it is a view of
+        # (``base``: None where the result was a fresh one of numpy's)
+        leaf, block = batch["x"], batch["x"].base
+        before = sys.getrefcount(leaf) + sys.getrefcount(block)
+        placed = job.place(batch)
+        added = sys.getrefcount(leaf) + sys.getrefcount(block) - before
+        # read after the count: a copy still under way now was under way
+        # when its references were counted
+        in_flight.append((not placed["x"].is_ready(), added,
+                          block is not None))
+        return placed
+
+    loader = hvt.data.ElasticDataLoader(
+        job.source, batch_size=job.global_batch, shuffle=True, seed=1,
+        device_put=False, transform=place_and_count, with_indices=True,
+        name="chip_smoke_input")
+    # read back as rows of bits: copied out in the layout the chip keeps
+    # a batch of images in, 77 MB took 0.55 s (PERF.md section 6, PR 26)
+    as_rows = jax.jit(lambda a: a.reshape(a.shape[0], -1))
+    bits = {k: v.view(f"u{v.dtype.itemsize}").reshape(len(v), -1)
+            for k, v in job.pool.items()}
+    want = {k: np.empty((job.global_batch, v.shape[1]), v.dtype)
+            for k, v in bits.items()}
+    t0 = time.perf_counter()
+    try:
+        batches = loader.stream()
+        for n in range(INPUT_BATCHES):
+            indices, batch = next(batches)
+            jax.block_until_ready(batch)
+            for k, rows in bits.items():
+                got = np.asarray(as_rows(batch[k]))
+                np.take(rows, indices, axis=0, out=want[k], mode="clip")
+                if (got.dtype != job.pool[k].dtype
+                        or not np.array_equal(
+                            got.view(rows.dtype), want[k])):
+                    raise AssertionError(
+                        f"input: batch {n}, leaf {k!r}: the device holds "
+                        "other bits than the pool's rows at its indices")
+    finally:
+        loader.close()
+    reused = counter.value(block="reused") - reused0
+    fresh = counter.value(block="fresh") - fresh0
+    held = [h for flying, h, is_block in in_flight if flying and is_block]
+    say(f"input: {INPUT_BATCHES} batches of {job.global_batch} rows equal "
+        f"to pool[indices] bit for bit on the device(s), in "
+        f"{time.perf_counter() - t0:.1f} s; blocks gathered into: "
+        f"{reused:.0f} reused, {fresh:.0f} fresh; of {len(in_flight)} "
+        f"batches placed, {len(held)} had their copy still under way "
+        "when device_put returned, and it then held "
+        f"{sorted(set(held))} reference(s) to the leaf or its block")
+    if any(h < 1 for h in held):
+        raise AssertionError(
+            "input: a copy to the device was under way and held no "
+            "reference to the block it was reading: the pool would hand "
+            "that block out again")
+    if rehearse:
+        return  # toy batches are under the pooled size, CPU copies instant
+    if not held:
+        raise AssertionError(
+            "input: no copy was seen under way, so nothing was shown "
+            "about who holds a block meanwhile")
+    if reused < 0.9 * INPUT_BATCHES:
+        raise AssertionError(
+            f"input: only {reused:.0f} of {INPUT_BATCHES} batches reused "
+            "a block; something holds on to the host batches")
 
 
 def phase_train(job: TrainJob, watch: CompileWatch):
@@ -597,6 +690,7 @@ def main() -> int:
     watch = CompileWatch()
     job = TrainJob(toy=rehearse)
     try:
+        phase_input(job, rehearse)
         batch = phase_train(job, watch)
         phase_width(job, batch)
         phase_reference()

@@ -90,7 +90,8 @@ _M_TRANSFORM = obs_metrics.histogram(
 _M_BACKPRESSURE = obs_metrics.histogram(
     "hvtpu_data_backpressure_seconds",
     "Time the prefetch thread was parked on a full queue before one "
-    "batch went in (near zero throughout: the loader paces the job).",
+    "batch went in: the producer's slack (where it stays near zero the "
+    "loader paces the job).",
     buckets=obs_metrics.DEFAULT_TIME_BUCKETS)
 _M_QDEPTH = obs_metrics.gauge(
     "hvtpu_data_queue_depth",

@@ -162,6 +162,215 @@ class TestSources:
 
 
 # ---------------------------------------------------------------------------
+# ArraySource's block pool: where a gather's memory comes from
+# ---------------------------------------------------------------------------
+
+_ROW_BYTES = 1 << 16    # 16 rows make the pooled size of 1 MiB
+
+
+def _pool_data(dtype, structure, n):
+    """``n`` rows of 64 KiB of random bits (NaN patterns among them:
+    compare bits, not values) as one big leaf, alone or beside a small
+    label leaf that never reaches the pooled size."""
+    raw = np.random.default_rng(7).integers(
+        0, 256, (n, _ROW_BYTES), dtype=np.uint8)
+    big = raw.view(np.dtype(dtype)).reshape(n, -1, 64)
+    small = np.arange(n, dtype=np.int32)
+    return {"array": big, "dict": {"x": big, "y": small},
+            "tuple": (big, small)}[structure]
+
+
+def _leaves(struct):
+    out = []
+    hvt_data.map_structure(out.append, struct)
+    return out
+
+
+def _same_bits(got, want):
+    got, want = _leaves(got), _leaves(want)
+    return len(got) == len(want) and all(
+        g.dtype == w.dtype and g.shape == w.shape
+        and np.array_equal(np.asarray(g).view(np.uint8), w.view(np.uint8))
+        for g, w in zip(got, want))
+
+
+def _rows(data, indices):
+    return hvt_data.map_structure(lambda a: a[indices], data)
+
+
+def _address(batch):
+    return _leaves(batch)[0].ctypes.data
+
+
+def _block_counts():
+    from horovod_tpu.obs import metrics as obs_metrics
+
+    fam = obs_metrics.REGISTRY.counter("hvtpu_data_fetch_blocks_total")
+    return fam.value(block="reused"), fam.value(block="fresh")
+
+
+def _bfloat16():
+    import ml_dtypes
+
+    return ml_dtypes.bfloat16
+
+
+@pytest.mark.parametrize("structure", ["array", "dict", "tuple"])
+@pytest.mark.parametrize("dtype", [_bfloat16(), np.float32, np.int32],
+                         ids=["bfloat16", "float32", "int32"])
+class TestArraySourceBlocks:
+    def test_every_batch_of_three_epochs_is_the_pools_rows(
+            self, dtype, structure):
+        data = _pool_data(dtype, structure, 48)
+        reused0, _ = _block_counts()
+        ld = ElasticDataLoader(ArraySource(data), batch_size=16, seed=11,
+                               device_put=False, with_indices=True)
+        seen = 0
+        try:
+            for _ in range(3):
+                for indices, batch in ld:
+                    assert _same_bits(batch, _rows(data, indices))
+                    seen += 1
+                    del batch
+        finally:
+            ld.close()
+        assert seen == 9
+        assert _block_counts()[0] > reused0     # and blocks were reused
+
+    def test_a_consumer_that_keeps_its_batches_finds_them_unchanged(
+            self, dtype, structure):
+        data = _pool_data(dtype, structure, 160)
+        reused0, fresh0 = _block_counts()
+        ld = ElasticDataLoader(ArraySource(data), batch_size=16, seed=5,
+                               device_put=False, with_indices=True)
+        try:
+            kept = list(ld)
+        finally:
+            ld.close()
+        assert len(kept) == 10          # more than the pool's cap a leaf
+        for indices, batch in kept:
+            assert _same_bits(batch, _rows(data, indices))
+        assert len({_address(b) for _, b in kept}) == 10
+        reused, fresh = _block_counts()
+        # nothing was free while every batch was held (the prefetcher
+        # ran a few fetches past the epoch's end besides)
+        assert reused == reused0 and fresh - fresh0 >= 10
+
+    def test_a_consumer_that_drops_its_batches_sees_a_block_again(
+            self, dtype, structure):
+        data = _pool_data(dtype, structure, 48)
+        src = ArraySource(data)
+        rng = np.random.default_rng(3)
+        reused0, fresh0 = _block_counts()
+        batch = src.fetch(rng.permutation(48)[:16])
+        first = _address(batch)
+        del batch
+        for k in range(5):
+            indices = rng.permutation(48)[:16]
+            batch = src.fetch(indices)
+            assert _address(batch) == first
+            assert _same_bits(batch, _rows(data, indices))
+            del batch
+        assert _block_counts() == (reused0 + 5, fresh0 + 1)
+        # a batch of another size has a block of its own
+        batch = src.fetch(np.arange(20))
+        assert _address(batch) != first
+        assert _same_bits(batch, _rows(data, np.arange(20)))
+
+    def test_device_batches_outlive_later_fetches(self, dtype, structure):
+        data = _pool_data(dtype, structure, 48)
+        ld = ElasticDataLoader(ArraySource(data), batch_size=16, seed=2,
+                               device_put=True, with_indices=True)
+        try:
+            kept = list(ld) + list(ld)
+        finally:
+            ld.close()
+        import jax
+
+        assert len(kept) == 6
+        assert all(isinstance(leaf, jax.Array)
+                   for _, b in kept for leaf in _leaves(b))
+        for indices, batch in kept:
+            assert _same_bits(batch, _rows(data, indices))
+
+    def test_odd_indices_give_what_indexing_gives(self, dtype, structure):
+        data = _pool_data(dtype, structure, 48)
+        src = ArraySource(data)
+        with pytest.raises(IndexError):
+            src.fetch(np.r_[np.arange(15), 48])
+        with pytest.raises(IndexError):
+            src.fetch(np.r_[np.arange(15), -49])
+        negative = np.r_[np.arange(15), -1]
+        assert _same_bits(src.fetch(negative), _rows(data, negative))
+        empty = np.empty((0,), dtype=np.int64)
+        assert _same_bits(src.fetch(empty), _rows(data, empty))
+        as_list = list(range(16))
+        assert _same_bits(src.fetch(as_list), _rows(data, as_list))
+
+    def test_two_threads_never_receive_the_same_block(
+            self, dtype, structure):
+        data = _pool_data(dtype, structure, 48)
+        src = ArraySource(data)
+        held, lock, errors = set(), threading.Lock(), []
+
+        def work(seed):
+            rng = np.random.default_rng(seed)
+            try:
+                for _ in range(25):
+                    indices = rng.permutation(48)[:16]
+                    batch = src.fetch(indices)
+                    address = _address(batch)
+                    with lock:
+                        assert address not in held
+                        held.add(address)
+                    time.sleep(0.001)   # the other thread fetches now
+                    assert _same_bits(batch, _rows(data, indices))
+                    with lock:
+                        held.discard(address)
+                    del batch
+            except BaseException as e:  # noqa: BLE001 - reported below
+                errors.append(e)
+
+        threads = [threading.Thread(target=work, args=(k,))
+                   for k in range(2)]
+        interval = sys.getswitchinterval()
+        sys.setswitchinterval(1e-5)     # switch inside the pool's check
+        try:
+            for t in threads:
+                t.start()
+            for t in threads:
+                t.join(timeout=60)
+        finally:
+            sys.setswitchinterval(interval)
+        assert not any(t.is_alive() for t in threads)
+        assert not errors, errors
+
+
+@pytest.mark.parametrize("how", ["strided", "memmap", "unsigned_indices"])
+def test_what_take_would_copy_whole_keeps_plain_indexing(how, tmp_path):
+    """np.take first copies a source that is not C-contiguous; such a
+    leaf, an ndarray subclass and indices that are not signed integers
+    get ``a[indices]`` as ever, and the pool is not touched."""
+    rows = np.random.default_rng(1).integers(
+        0, 256, (48, 2, _ROW_BYTES), dtype=np.uint8)
+    indices = np.random.default_rng(2).permutation(48)[:16]
+    if how == "strided":
+        data = rows[:, 0, :]
+        assert not data.flags.c_contiguous
+    elif how == "memmap":
+        data = np.memmap(tmp_path / "rows", dtype=np.uint8, mode="w+",
+                         shape=(48, _ROW_BYTES))
+        data[:] = rows[:, 1, :]
+    else:
+        data, indices = rows[:, 0, :].copy(), indices.astype(np.uint32)
+    before = _block_counts()
+    src = ArraySource(data)
+    for _ in range(3):
+        assert _same_bits(src.fetch(indices), np.asarray(data)[indices])
+    assert _block_counts() == before
+
+
+# ---------------------------------------------------------------------------
 # loader units
 # ---------------------------------------------------------------------------
 
