@@ -673,3 +673,34 @@ def aggregate(process_set=None, timeout_s: float = 60.0,
             pass
     return {"per_rank": per_rank, "merged": merge_snapshots(
         [per_rank[r] for r in sorted(per_rank)])}
+
+
+# ---------------------------------------------------------------------------
+# expert layers
+# ---------------------------------------------------------------------------
+
+def note_moe_routing(rows_per_expert) -> None:
+    """Record what a step's expert layers saw: ``rows_per_expert`` is
+    the ``[layers, experts_held]`` (or ``[experts_held]``) count that
+    ``parallel.moe.dropless_topk_moe`` returns and a model hands back in
+    its ``model_state``.  Call it from the host loop at logging cadence,
+    on a state the loop has already fetched: it reads the array (a
+    device-to-host copy if it still lives on the chip) and is never
+    called from inside the step."""
+    import numpy as np
+
+    rows = np.asarray(rows_per_expert, np.float64)
+    rows = rows.reshape(-1, rows.shape[-1])
+    mean = rows.mean(axis=-1)
+    worst = np.max(np.where(mean > 0, rows.max(axis=-1)
+                            / np.maximum(mean, 1.0), 0.0))
+    REGISTRY.gauge(
+        "hvtpu_moe_rows_per_expert",
+        "Rows the busiest expert held here got over the mean of the "
+        "experts held, the worst layer of the last step noted: 1.0 is an "
+        "even routing; the layer's tiles, and with an exchange its "
+        "slowest peer, grow with it.").set(float(worst))
+    REGISTRY.counter(
+        "hvtpu_moe_local_rows_total",
+        "Rows (token, expert) the experts held here multiplied, summed "
+        "over layers and over the steps noted.").inc(float(rows.sum()))

@@ -9,7 +9,7 @@ lowers onto the ICI torus.
 """
 
 from .mesh import LOGICAL_AXES, MeshLayout, auto_layout, make_layout
-from .moe import expert_parallel_moe, switch_route
+from .moe import dropless_topk_moe, expert_parallel_moe, switch_route
 from .pipeline import bubble_fraction, pipeline_apply
 from .ring import ring_attention
 from .tp import column_parallel, row_parallel, tp_shard_dim
@@ -30,5 +30,6 @@ __all__ = [
     "pipeline_apply",
     "bubble_fraction",
     "expert_parallel_moe",
+    "dropless_topk_moe",
     "switch_route",
 ]

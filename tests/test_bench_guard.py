@@ -136,16 +136,26 @@ class TestMetricsEmbedding:
         assert m["hvtpu_ckpt_verify_failures_total"] == 0
 
     def test_report_embeds_data_stall_row(self, bench):
+        from horovod_tpu.obs import metrics as obs_metrics
+
+        # the histogram is the process's: whatever loaders ran in this
+        # worker before have added to it, so the row is checked against
+        # the one snapshot its report embeds, at a sum that is not zero
+        obs_metrics.histogram("hvtpu_data_wait_seconds").observe(0.1234567)
         report = bench.build_report(metric="m", value=1.0, unit="u",
                                     elapsed_seconds=10.0)
         stall = report["data_stall"]
         assert set(stall) == {"batches", "wait_seconds",
                               "stall_fraction"}
-        assert stall["batches"] == report["metrics"][
-            "hvtpu_data_wait_seconds"]["count"]
-        # derived against the caller's wall time; null without it
+        wait = report["metrics"]["hvtpu_data_wait_seconds"]
+        assert stall["batches"] == wait["count"] >= 1
+        assert stall["wait_seconds"] == round(wait["sum"], 6)
+        # derived against the caller's wall time (both figures are
+        # rounded to six places, each from the unrounded sum); null
+        # without it
+        assert stall["stall_fraction"] == round(wait["sum"] / 10.0, 6)
         assert stall["stall_fraction"] == pytest.approx(
-            stall["wait_seconds"] / 10.0)
+            stall["wait_seconds"] / 10.0, abs=1e-6)
         no_elapsed = bench.build_report(metric="m", value=1.0, unit="u")
         assert no_elapsed["data_stall"]["stall_fraction"] is None
         json.dumps(report)
