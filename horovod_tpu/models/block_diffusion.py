@@ -38,6 +38,7 @@ from __future__ import annotations
 
 import dataclasses
 import functools
+import logging
 import math
 from typing import Any, Dict
 
@@ -46,7 +47,11 @@ import jax.numpy as jnp
 import numpy as np
 from jax import lax
 
+from ..obs import metrics
+from ..ops import flash_attention, pallas_ops
 from ..parallel.moe import dropless_topk_moe
+
+logger = logging.getLogger("horovod_tpu")
 
 Params = Dict[str, Any]
 
@@ -57,6 +62,12 @@ _MASKED = -1e30
 
 # queries and keys of a tile: 2,048 x 512 f32 scores a key/value head
 _ATTENTION_TILE = 512
+
+# queries and keys of a block of the Pallas kernels
+# (``ops/flash_attention.py``), chosen on the chip: PERF.md, findings
+# of PR 28, has the sizes tried and what each measured
+_FLASH_BLOCK_Q = 512
+_FLASH_BLOCK_KV = 512
 
 
 @dataclasses.dataclass(frozen=True)
@@ -166,20 +177,41 @@ def allowed(q_pos, k_pos, seq_len: int, block_length: int):
             | (q_clean & k_clean & (k_blk <= q_blk)))
 
 
+def tile_work(seq_len: int, block_length: int, tile_q: int, tile_k: int):
+    """What each (query tile, key tile) pair holds under ``allowed``,
+    int8 ``[2 nq, 2 nk]``: 0 nothing, 1 some pairs (the mask has to be
+    applied), 2 every query of the tile sees every key.  Each half is
+    cut into ``ceil(seq_len / tile)`` tiles of its own (the noised
+    half's first), so no tile straddles the halves.  In closed form
+    from the first and last block a tile touches: ``allowed`` compares
+    blocks, so a tile stands for the range of its blocks."""
+    def blocks(tile):
+        first = np.arange(-(-seq_len // tile)) * tile
+        last = np.minimum(first + tile, seq_len) - 1
+        return (first // block_length, last // block_length,
+                first + tile <= seq_len)
+
+    (q_lo, q_hi, q_whole), (lo, hi, k_whole) = blocks(tile_q), blocks(tile_k)
+    q_lo, q_hi, nq, nk = q_lo[:, None], q_hi[:, None], len(q_lo), len(lo)
+    some = np.zeros((2 * nq, 2 * nk), bool)
+    every = np.zeros((2 * nq, 2 * nk), bool)
+    some[:nq, :nk] = (lo <= q_hi) & (q_lo <= hi)   # some block in common
+    every[:nq, :nk] = (q_lo == q_hi) & (lo == hi) & (q_lo == lo)
+    some[:nq, nk:] = lo < q_hi                     # an earlier clean block
+    every[:nq, nk:] = hi < q_lo
+    some[nq:, nk:] = lo <= q_hi
+    every[nq:, nk:] = hi <= q_lo
+    # a tile with padding is never seen whole
+    every &= np.tile(q_whole, 2)[:, None] & np.tile(k_whole, 2)
+    return some.astype(np.int8) + (some & every)
+
+
 def tile_runs(seq_len: int, block_length: int, tile: int):
     """The tile pairs that hold work, as runs ``(first query tile, one
-    past the last, first key tile)`` of pairs that lie on one diagonal.
-    Each half is cut into ``ceil(seq_len / tile)`` tiles of its own
-    (tiles ``0..n-1`` noised, ``n..2n-1`` clean), so no tile straddles
-    the halves."""
+    past the last, first key tile)`` of pairs that lie on one diagonal
+    (tiles ``0..n-1`` noised, ``n..2n-1`` clean)."""
     n = -(-seq_len // tile)
-    lo = (np.arange(n) * tile) // block_length
-    hi = (np.minimum((np.arange(n) + 1) * tile, seq_len) - 1) // block_length
-    work = np.zeros((2 * n, 2 * n), bool)
-    q_hi, q_lo = hi[:, None], lo[:, None]
-    work[:n, :n] = (lo <= q_hi) & (q_lo <= hi)   # some block in common
-    work[:n, n:] = lo < q_hi                     # an earlier clean block
-    work[n:, n:] = lo <= q_hi
+    work = tile_work(seq_len, block_length, tile, tile) > 0
     runs = []
     for off in range(-(2 * n - 1), 2 * n):       # key tile = query tile - off
         start = None
@@ -284,15 +316,95 @@ def _tiled_bwd(runs, spec, res, d_out):
 _tiled.defvjp(_tiled_fwd, _tiled_bwd)
 
 
+@functools.lru_cache(maxsize=None)
+def _flash_schedule(seq_len: int, block_length: int, block_q: int,
+                    block_kv: int) -> flash_attention.PairSchedule:
+    """The kernels' walk over ``xt ++ x``: a half is a whole number of
+    blocks, so tile ``i`` is positions ``i * block`` on."""
+    work = tile_work(seq_len, block_length, block_q, block_kv)
+    logger.info(
+        "block-diffusion attention runs in the Pallas kernels: blocks of "
+        "%d queries x %d keys over 2 x %d positions, block length %d; %d "
+        "of %d block pairs hold work, %d partial and %d full, at most %d "
+        "key blocks a query block", block_q, block_kv, seq_len,
+        block_length, np.count_nonzero(work), work.size,
+        np.count_nonzero(work == flash_attention.PARTIAL),
+        np.count_nonzero(work == flash_attention.FULL),
+        np.count_nonzero(work, axis=1).max())
+    return flash_attention.pair_schedule(work, block_q, block_kv)
+
+
+@functools.lru_cache(maxsize=None)
+def _note_xla_path(seq_len, block_length, tile, why):
+    logger.info(
+        "block-diffusion attention runs in XLA tiles of %d over 2 x %d "
+        "positions, block length %d (%s)", tile, seq_len, block_length, why)
+
+
+def _in_kernels(kernels, spec, *operands):
+    """``flash_attention.forward`` or ``.backward`` on ``operands``
+    (``q`` first) with this mask's schedule and ``allowed`` itself."""
+    seq_len, block_length, block_q, block_kv, interpret = spec
+    return kernels(
+        *operands, _flash_schedule(seq_len, block_length, block_q, block_kv),
+        functools.partial(allowed, seq_len=seq_len,
+                          block_length=block_length),
+        scale=1.0 / math.sqrt(operands[0].shape[-1]), mask_value=_MASKED,
+        interpret=interpret)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
+def _flash(q, k, v, spec):
+    return _flash_fwd(q, k, v, spec)[0]
+
+
+@jax.named_scope("hvtpu:attention")
+def _flash_fwd(q, k, v, spec):
+    out, lse, exact = _in_kernels(flash_attention.forward, spec, q, k, v)
+    return out, (q, k, v, exact, lse)
+
+
+@jax.named_scope("hvtpu:attention")
+def _flash_bwd(spec, res, d_out):
+    return _in_kernels(flash_attention.backward, spec, *res, d_out)
+
+
+_flash.defvjp(_flash_fwd, _flash_bwd)
+
+
 def tiled_attention(q, k, v, *, block_length: int, tile: int):
     """Attention of ``q`` ``[B, 2T, H, hd]`` over ``k``, ``v`` ``[B, 2T,
     G, hd]`` under the block-diffusion mask (``allowed``), every query
     head reading the key/value head of its group.  Scores and softmax in
     f32; the products take ``q``'s type.  Everything in it runs under
-    the scope ``hvtpu:attention``."""
+    the scope ``hvtpu:attention``.
+
+    One algorithm, an online softmax over the tile pairs that hold
+    work, in two implementations; which one runs is observed, not set.
+    Where ``ops.pallas_ops`` compiles kernels (a TPU backend, or the
+    interpreter in tests) and the shapes are ones they take, the pairs
+    run in the Pallas kernels of ``ops/flash_attention.py`` in blocks of
+    ``_FLASH_BLOCK_Q`` x ``_FLASH_BLOCK_KV`` and a pair's scores stay in
+    VMEM; elsewhere in XLA over diagonal runs of ``tile`` x ``tile``.
+    ``hvtpu_attention_calls_total{path=}`` counts, when a program is
+    traced, which it was."""
     b, two_t, heads, hd = q.shape
     seq_len, groups = two_t // 2, k.shape[2]
     tile = min(tile, seq_len)
+    use, interpret = pallas_ops._pallas_mode()
+    blocks = min(_FLASH_BLOCK_Q, seq_len), min(_FLASH_BLOCK_KV, seq_len)
+    if not use:
+        why = "no Pallas on this backend"
+    elif not (q.dtype == k.dtype == v.dtype and flash_attention.supports(
+            hd, q.dtype, seq_len, *blocks)):
+        why = (f"the kernels take heads of whole 128 lanes and halves of "
+               f"whole blocks, not head_dim {hd}, {q.dtype}, blocks of "
+               f"{blocks}")
+    else:
+        metrics.note_attention_path("pallas")
+        return _flash(q, k, v, (seq_len, block_length, *blocks, interpret))
+    metrics.note_attention_path("xla")
+    _note_xla_path(seq_len, block_length, tile, why)
     n = -(-seq_len // tile)
     pad = n * tile - seq_len
 

@@ -676,8 +676,22 @@ def aggregate(process_set=None, timeout_s: float = 60.0,
 
 
 # ---------------------------------------------------------------------------
-# expert layers
+# attention and expert layers
 # ---------------------------------------------------------------------------
+
+def note_attention_path(path: str) -> None:
+    """Count one call of ``models.block_diffusion.tiled_attention`` by
+    the implementation it took: ``"pallas"`` (the kernels of
+    ``ops/flash_attention.py``) or ``"xla"``.  Called while a program is
+    traced, once a call site and a trace, never from inside the step: a
+    step that scans its layers counts one call however many layers run
+    it."""
+    REGISTRY.counter(
+        "hvtpu_attention_calls_total",
+        "Calls of the block-diffusion attention, counted when a program "
+        "is traced, by the implementation that was built in: the Pallas "
+        "kernels or XLA tiles.").inc(path=path)
+
 
 def note_moe_routing(rows_per_expert) -> None:
     """Record what a step's expert layers saw: ``rows_per_expert`` is

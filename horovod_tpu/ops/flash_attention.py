@@ -1,0 +1,357 @@
+"""Flash attention over the tile pairs a mask leaves work in: three
+Pallas TPU kernels (forward, ``dq``, ``dk``/``dv``) that keep a tile
+pair's scores in VMEM between the two products.
+
+What a caller brings: ``q`` ``[B, P, H, hd]`` and ``k``, ``v`` ``[B, P,
+G, hd]`` as the projections leave them (head-minor, the positions not
+split into tiles: the kernels' blocks are cut by their index maps, so
+nothing is transposed on the way in or out), a ``PairSchedule`` that
+lists the (query tile, key tile) pairs that hold work and says of each
+whether every query sees every key, and ``seen(q_pos, k_pos)``, the
+mask itself, which a kernel evaluates on iotas in the pairs that are
+not full.  Nothing here knows which mask it is
+(``models/block_diffusion.py`` brings ``allowed``);
+``jax.experimental.pallas.ops.tpu.splash_attention`` is the model for
+the schedule and the oracle in the tests, and does not ship: it hands
+``pallas_call`` a ``metadata=``, which XLA prints over three lines of
+the compiled text, and ``benchmark/scopes.py`` reads an instruction as
+one line (PERF.md, section 7).
+
+*The walk.*  A kernel's grid is ``(B, G, pairs)``: the pairs in the
+order of the tile that accumulates (the query tile for the forward and
+``dq`` kernels, the key tile for ``dk``/``dv``), handed over by scalar
+prefetch, so that a block's index map reads both tiles' indices from
+SMEM and no step is spent on a pair without work.  The accumulators
+live in VMEM scratch, are cleared at a tile's first pair and written
+out at its last.  The query heads of a group are walked inside a step
+against the one key/value tile the step fetched, so a partial pair's
+mask is computed once for all of them.
+
+*The arithmetic* is ``models.block_diffusion._tiled``'s: f32 scores
+scaled after the product, f32 softmax, the products in the operands'
+type with f32 accumulation, a masked score ``mask_value``.
+"""
+
+from __future__ import annotations
+
+import functools
+from typing import Callable, NamedTuple
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+from jax import lax
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+_LANES = 128
+# a pair's kind in the tables
+PARTIAL, FULL = 1, 2
+
+_NT = (((1,), (1,)), ((), ()))      # a @ b.T
+# blocks of 512 x 512 fit the 16 MiB of VMEM a v5e kernel gets unasked;
+# 1,024 x 1,024 and 512 x 2,048 do not (Mosaic: out of memory in vmem).
+# Asked for always, so that the sizes tried on the chip all compiled
+# and a larger block is a constant's change
+_VMEM_LIMIT = 64 * 1024 * 1024
+
+
+class PairSchedule(NamedTuple):
+    """The tile pairs with work, twice: in query-tile order and in
+    key-tile order.  Each table is int32 ``[3, pairs]``: the query
+    tile, the key tile, the pair's kind."""
+    block_q: int
+    block_kv: int
+    by_query: np.ndarray
+    by_key: np.ndarray
+
+    @property
+    def pairs(self) -> int:
+        return self.by_query.shape[1]
+
+
+def pair_schedule(work: np.ndarray, block_q: int, block_kv: int):
+    """``work`` ``[query tiles, key tiles]``: 0 where no query of the
+    one sees a key of the other, else ``PARTIAL`` or ``FULL``."""
+    qi, kj = np.nonzero(work)                    # sorted by query tile
+    by_query = np.stack([qi, kj, work[qi, kj]]).astype(np.int32)
+    return PairSchedule(
+        block_q, block_kv, by_query,
+        by_query[:, np.lexsort((qi, kj))])       # ... and by key tile
+
+
+def supports(head_dim: int, dtype, positions: int, block_q: int,
+             block_kv: int) -> bool:
+    """Shapes the kernels take: whole 128-lane heads, blocks of whole
+    vector tiles that divide the positions, operands the MXU takes."""
+    return (head_dim % _LANES == 0
+            and jnp.dtype(dtype) in (jnp.bfloat16, jnp.float32)
+            and block_q % _LANES == 0 and block_kv % _LANES == 0
+            and positions % block_q == 0 and positions % block_kv == 0)
+
+
+def _lanes(x, width):
+    """``[rows, 128]`` of equal lanes -> ``[rows, width]``."""
+    return x if width == _LANES else jnp.tile(x, (1, width // _LANES))
+
+
+def _walk(table_ref, own: int):
+    """This step's pair, and whether it is the first and the last of
+    the tile that accumulates (row ``own`` of the table)."""
+    p, n = pl.program_id(2), pl.num_programs(2)
+    tile = table_ref[own, p]
+    first = (p == 0) | (table_ref[own, jnp.maximum(p - 1, 0)] != tile)
+    last = (p == n - 1) | (table_ref[own, jnp.minimum(p + 1, n - 1)] != tile)
+    return table_ref[0, p], table_ref[1, p], table_ref[2, p], first, last
+
+
+def _by_kind(kind, pair):
+    """``pair(masked)``: a full pair applies no mask."""
+    pl.when(kind == FULL)(functools.partial(pair, False))
+    pl.when(kind == PARTIAL)(functools.partial(pair, True))
+
+
+def _seen_tile(seen, first_query, first_key, shape, query_axis: int):
+    """The mask of a tile, queries along ``query_axis``."""
+    return seen(
+        first_query + lax.broadcasted_iota(jnp.int32, shape, query_axis),
+        first_key + lax.broadcasted_iota(jnp.int32, shape, 1 - query_axis))
+
+
+def _scores(a, b, scale, keep, mask_value):
+    s = lax.dot_general(a, b, _NT, preferred_element_type=jnp.float32)
+    s = s * scale
+    return s if keep is None else jnp.where(keep, s, mask_value)
+
+
+def _fwd_kernel(table_ref, q_ref, k_ref, v_ref, out_ref, lse_ref, *rest,
+                heads, hd, scale, seen, mask_value):
+    # an operand narrower than f32 brings one more result: ``out``
+    # before it is rounded
+    *exact_ref, m_scr, l_scr, acc_scr = rest
+    bq, bkv = q_ref.shape[0], k_ref.shape[0]
+    qi, kj, kind, first, last = _walk(table_ref, 0)
+
+    @pl.when(first)
+    def _():
+        m_scr[...] = jnp.full_like(m_scr, mask_value)
+        l_scr[...] = jnp.zeros_like(l_scr)
+        acc_scr[...] = jnp.zeros_like(acc_scr)
+
+    def pair(masked):
+        k, v = k_ref[...], v_ref[...]
+        keep = _seen_tile(seen, qi * bq, kj * bkv, (bq, bkv), 0) \
+            if masked else None
+        for r in range(heads):
+            cols = slice(r * hd, (r + 1) * hd)
+            s = _scores(q_ref[:, cols], k, scale, keep, mask_value)
+            m_old = m_scr[r]
+            m_new = jnp.maximum(m_old, s.max(axis=-1, keepdims=True))
+            alpha = jnp.exp(m_old - m_new)
+            p = jnp.exp(s - _lanes(m_new, bkv))
+            m_scr[r] = m_new
+            l_scr[r] = alpha * l_scr[r] + p.sum(axis=-1, keepdims=True)
+            acc_scr[:, cols] = _lanes(alpha, hd) * acc_scr[:, cols] + jnp.dot(
+                p.astype(v.dtype), v, preferred_element_type=jnp.float32)
+
+    _by_kind(kind, pair)
+
+    @pl.when(last)
+    def _():
+        for r in range(heads):
+            cols = slice(r * hd, (r + 1) * hd)
+            l = l_scr[r]
+            out = acc_scr[:, cols] / _lanes(l, hd)
+            out_ref[:, cols] = out.astype(out_ref.dtype)
+            for ref in exact_ref:
+                ref[:, cols] = out
+            lse_ref[:, r:r + 1] = (m_scr[r] + jnp.log(l))[:, :1]
+
+
+def _dq_kernel(table_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
+               dq_ref, dq_scr, *, heads, hd, scale, seen, mask_value):
+    bq, bkv = q_ref.shape[0], k_ref.shape[0]
+    qi, kj, kind, first, last = _walk(table_ref, 0)
+
+    @pl.when(first)
+    def _():
+        dq_scr[...] = jnp.zeros_like(dq_scr)
+
+    def pair(masked):
+        k, v = k_ref[...], v_ref[...]
+        keep = _seen_tile(seen, qi * bq, kj * bkv, (bq, bkv), 0) \
+            if masked else None
+        for r in range(heads):
+            cols = slice(r * hd, (r + 1) * hd)
+            q = q_ref[:, cols]
+            p = jnp.exp(_scores(q, k, scale, keep, mask_value)
+                        - lse_ref[:, r:r + 1])
+            dp = lax.dot_general(do_ref[:, cols], v, _NT,
+                                 preferred_element_type=jnp.float32)
+            ds = (p * (dp - delta_ref[:, r:r + 1]) * scale).astype(q.dtype)
+            dq_scr[:, cols] += jnp.dot(
+                ds, k, preferred_element_type=jnp.float32)
+
+    _by_kind(kind, pair)
+
+    @pl.when(last)
+    def _():
+        dq_ref[...] = dq_scr[...].astype(dq_ref.dtype)
+
+
+def _dkv_kernel(table_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
+                dk_ref, dv_ref, dk_scr, dv_scr, *, heads, hd, scale, seen,
+                mask_value):
+    """Scores transposed, ``[keys, queries]``: every product is then a
+    plain or a last-axes one, and ``lse`` and ``delta`` lie along the
+    lanes."""
+    bq, bkv = q_ref.shape[0], k_ref.shape[0]
+    qi, kj, kind, first, last = _walk(table_ref, 1)
+
+    @pl.when(first)
+    def _():
+        dk_scr[...] = jnp.zeros_like(dk_scr)
+        dv_scr[...] = jnp.zeros_like(dv_scr)
+
+    def pair(masked):
+        k, v = k_ref[...], v_ref[...]
+        keep = _seen_tile(seen, qi * bq, kj * bkv, (bkv, bq), 1) \
+            if masked else None
+        for r in range(heads):
+            cols = slice(r * hd, (r + 1) * hd)
+            q, do = q_ref[:, cols], do_ref[:, cols]
+            p = jnp.exp(_scores(k, q, scale, keep, mask_value)
+                        - lse_ref[r:r + 1, :])
+            dv_scr[...] += jnp.dot(p.astype(do.dtype), do,
+                                   preferred_element_type=jnp.float32)
+            dp = lax.dot_general(v, do, _NT,
+                                 preferred_element_type=jnp.float32)
+            ds = (p * (dp - delta_ref[r:r + 1, :]) * scale).astype(q.dtype)
+            dk_scr[...] += jnp.dot(ds, q, preferred_element_type=jnp.float32)
+
+    _by_kind(kind, pair)
+
+    @pl.when(last)
+    def _():
+        dk_ref[...] = dk_scr[...].astype(dk_ref.dtype)
+        dv_ref[...] = dv_scr[...].astype(dv_ref.dtype)
+
+
+class _Kernels(NamedTuple):
+    """What the three kernels of one attention share."""
+    batch: int
+    positions: int
+    groups: int
+    heads: int      # query heads of a group
+    hd: int
+    schedule: PairSchedule
+    static: dict    # scale, mask_value, seen: the kernel bodies' keywords
+    interpret: bool
+
+    @classmethod
+    def of(cls, q, k, schedule, seen, scale, mask_value, interpret):
+        batch, positions, all_heads, hd = q.shape
+        groups = k.shape[2]
+        return cls(batch, positions, groups, all_heads // groups, hd,
+                   schedule, dict(scale=scale, mask_value=mask_value,
+                                  seen=seen), interpret)
+
+    def operand(self, kind: str):
+        """An operand's shape and how its blocks are cut, by its kind:
+        ``wide`` (a group's query heads of a query tile), ``narrow`` (the
+        group's key/value head of a key tile), ``column`` and ``row`` (a
+        query tile's f32 statistics down the sublanes or along the
+        lanes).  An index map reads the pair's tiles from the table."""
+        bq, bkv = self.schedule.block_q, self.schedule.block_kv
+        b, p, g, h, hd = self[:5]
+        return {
+            "wide": ((b, p, g * h * hd), pl.BlockSpec(
+                (None, bq, h * hd), lambda b, g, i, t: (b, t[0, i], g))),
+            "narrow": ((b, p, g * hd), pl.BlockSpec(
+                (None, bkv, hd), lambda b, g, i, t: (b, t[1, i], g))),
+            "column": ((b, g, p, h), pl.BlockSpec(
+                (None, None, bq, h), lambda b, g, i, t: (b, g, t[0, i], 0))),
+            "row": ((b, g, h, p), pl.BlockSpec(
+                (None, None, h, bq), lambda b, g, i, t: (b, g, 0, t[0, i]))),
+        }[kind]
+
+    def call(self, body, name, table, ins, outs, scratch, *operands):
+        """Run ``body`` over the pairs of ``table``; ``ins`` the kinds of
+        ``operands``, ``outs`` the results' ``(kind, dtype)``."""
+        # no ``metadata=``: it would break the instruction over three
+        # lines of the compiled text, where the benchmark's scopes cannot
+        # follow
+        return pl.pallas_call(
+            functools.partial(body, heads=self.heads, hd=self.hd,
+                              **self.static),
+            name=name,
+            grid_spec=pltpu.PrefetchScalarGridSpec(
+                num_scalar_prefetch=1,
+                grid=(self.batch, self.groups, self.schedule.pairs),
+                in_specs=[self.operand(kind)[1] for kind in ins],
+                out_specs=[self.operand(kind)[1] for kind, _ in outs],
+                scratch_shapes=scratch),
+            out_shape=[jax.ShapeDtypeStruct(self.operand(kind)[0], dtype)
+                       for kind, dtype in outs],
+            interpret=self.interpret,
+            compiler_params=pltpu.CompilerParams(
+                dimension_semantics=("parallel", "parallel", "arbitrary"),
+                vmem_limit_bytes=_VMEM_LIMIT),
+        )(jnp.asarray(table), *operands)
+
+
+def _flat(x):
+    return x.reshape(*x.shape[:2], -1)
+
+
+def forward(q, k, v, schedule: PairSchedule, seen: Callable, *,
+            scale: float, mask_value: float, interpret: bool = False):
+    """``(out, lse, exact)``: ``out`` like ``q``; every row's
+    log-sum-exp, f32 ``[B, G, P, query heads of a group]``; and ``out``
+    in f32 as it was before it was rounded to ``q``'s type (``out``
+    itself where that is f32), which is what ``backward`` wants."""
+    ks = _Kernels.of(q, k, schedule, seen, scale, mask_value, interpret)
+    f32, bq = jnp.float32, schedule.block_q
+    outs = (("wide", q.dtype), ("column", f32)) + (
+        () if q.dtype == f32 else (("wide", f32),))
+    out, lse, *exact = ks.call(
+        _fwd_kernel, "hvtpu_flash_attention_fwd", schedule.by_query,
+        ("wide", "narrow", "narrow"), outs,
+        [pltpu.VMEM((ks.heads, bq, _LANES), f32),
+         pltpu.VMEM((ks.heads, bq, _LANES), f32),
+         pltpu.VMEM((bq, ks.heads * ks.hd), f32)],
+        _flat(q), _flat(k), _flat(v))
+    out = out.reshape(q.shape)
+    return out, lse, exact[0].reshape(q.shape) if exact else out
+
+
+def backward(q, k, v, out, lse, d_out, schedule: PairSchedule,
+             seen: Callable, *, scale: float, mask_value: float,
+             interpret: bool = False):
+    """``dq``, ``dk``, ``dv`` in the operands' types, from ``forward``'s
+    ``exact`` (as ``out``) and ``lse``.  ``delta``, a row's ``sum(d_out
+    * out)``, is an XLA reduction: one pass over two arrays.  It takes
+    ``out`` before its rounding because ``dp - delta`` cancels: in a
+    row with one dominant key nearly all of ``dp`` goes, and what
+    ``out``'s rounding to bf16 adds to ``delta`` stays (15 % further
+    from an f32 reference in ``dq`` and ``dk`` on the chip, PERF.md,
+    findings of PR 28).  XLA gives its own tiles the same: it drops a
+    rounding between two of its own fusions (excess precision), which
+    it cannot do across a kernel's boundary."""
+    ks = _Kernels.of(q, k, schedule, seen, scale, mask_value, interpret)
+    f32 = jnp.float32
+    delta = jnp.sum(d_out.astype(f32) * out.astype(f32), axis=-1).reshape(
+        ks.batch, ks.positions, ks.groups, ks.heads).transpose(0, 2, 1, 3)
+    operands = (_flat(q), _flat(k), _flat(v), _flat(d_out))
+    ins = ("wide", "narrow", "narrow", "wide")
+    dq, = ks.call(
+        _dq_kernel, "hvtpu_flash_attention_dq", schedule.by_query,
+        ins + ("column", "column"), (("wide", q.dtype),),
+        [pltpu.VMEM((schedule.block_q, ks.heads * ks.hd), f32)],
+        *operands, lse, delta)
+    dk, dv = ks.call(
+        _dkv_kernel, "hvtpu_flash_attention_dkv", schedule.by_key,
+        ins + ("row", "row"), (("narrow", k.dtype), ("narrow", v.dtype)),
+        [pltpu.VMEM((schedule.block_kv, ks.hd), f32)] * 2,
+        *operands, lse.swapaxes(2, 3), delta.swapaxes(2, 3))
+    return dq.reshape(q.shape), dk.reshape(k.shape), dv.reshape(v.shape)

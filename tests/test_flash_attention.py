@@ -1,0 +1,311 @@
+"""The Pallas kernels behind ``models.block_diffusion.tiled_attention``
+(``ops/flash_attention.py``) on the CPU, under the Pallas interpreter:
+against the XLA tiles they replace, against a dense masked softmax and
+against the library's splash-attention kernel with the same computed
+mask; their schedule against ``allowed`` itself; which path runs; and
+how the kernels appear in a program lowered for a TPU."""
+
+import re
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from horovod_tpu.models import block_diffusion as bd
+from horovod_tpu.obs import metrics
+from horovod_tpu.ops import flash_attention, pallas_ops
+
+HD = 128
+KERNELS = ("hvtpu_flash_attention_fwd", "hvtpu_flash_attention_dq",
+           "hvtpu_flash_attention_dkv")
+
+
+def distance(got, want):
+    got, want = np.asarray(got, np.float64), np.asarray(want, np.float64)
+    return np.linalg.norm(got - want) / np.linalg.norm(want)
+
+
+def calls(path):
+    return metrics.REGISTRY.counter(
+        "hvtpu_attention_calls_total").value(path=path)
+
+
+@pytest.fixture
+def interpreted(monkeypatch):
+    monkeypatch.setenv("HVTPU_PALLAS_INTERPRET", "1")
+    monkeypatch.delenv("HVTPU_PALLAS", raising=False)
+
+
+def blocks(monkeypatch, q, kv):
+    monkeypatch.setattr(bd, "_FLASH_BLOCK_Q", q)
+    monkeypatch.setattr(bd, "_FLASH_BLOCK_KV", kv)
+
+
+def operands(seq_len, heads, kv_heads, dtype=jnp.float32, seed=0):
+    ks = jax.random.split(jax.random.PRNGKey(seed), 4)
+    shape = (2, 2 * seq_len, heads, HD)
+    kv = (2, 2 * seq_len, kv_heads, HD)
+    return (jax.random.normal(ks[0], shape).astype(dtype),
+            jax.random.normal(ks[1], kv).astype(dtype),
+            jax.random.normal(ks[2], kv).astype(dtype),
+            jax.random.normal(ks[3], shape).astype(dtype))
+
+
+def with_gradients(attention, q, k, v, target):
+    """``(out, dq, dk, dv)`` of ``sum(attention * target)``."""
+    def loss(q, k, v):
+        out = attention(q, k, v)
+        return jnp.sum(out.astype(jnp.float32) * target), out
+
+    grads, out = jax.jit(jax.grad(loss, (0, 1, 2), has_aux=True))(q, k, v)
+    return (out, *grads)
+
+
+def dense_attention(q, k, v, seq_len, block_length):
+    pos = np.arange(2 * seq_len)
+    seen = jnp.asarray(
+        bd.allowed(pos[:, None], pos[None, :], seq_len, block_length))
+    k, v = (jnp.repeat(a, q.shape[2] // a.shape[2], axis=2) for a in (k, v))
+    s = jnp.einsum("bqhd,bkhd->bhqk", q, k) / np.sqrt(q.shape[-1])
+    p = jax.nn.softmax(jnp.where(seen, s, -jnp.inf), axis=-1)
+    return jnp.einsum("bhqk,bkhd->bqhd", p, v)
+
+
+def splash_attention(q, k, v, seq_len, block_length, block):
+    """The library's kernels in the interpreter, their block-sparse
+    walk made from ``allowed`` by the library's own classifier."""
+    from jax.experimental.pallas.ops.tpu.splash_attention import (
+        splash_attention_kernel as splash, splash_attention_mask as masks)
+
+    class BlockDiffusionMask(masks._ComputableMask):
+        def __init__(self):
+            super().__init__(
+                (2 * seq_len, 2 * seq_len),
+                lambda q_ids, kv_ids: bd.allowed(
+                    q_ids, kv_ids, seq_len, block_length))
+
+        def __eq__(self, other):
+            return isinstance(other, type(self))
+
+        def __hash__(self):
+            return hash((type(self), seq_len, block_length))
+
+    group = q.shape[2] // k.shape[2]
+    kernel = splash.make_splash_mqa_single_device(
+        masks.MultiHeadMask([BlockDiffusionMask()] * group),
+        block_sizes=splash.BlockSizes(
+            block_q=block, block_kv=block, block_q_dkv=block,
+            block_kv_dkv=block, block_q_dq=block, block_kv_dq=block),
+        interpret=True)
+    b, positions, _, hd = q.shape
+    # a (sequence, key/value head) at a time: [B, G, R, P, hd]
+    grouped = (q / np.sqrt(hd)).reshape(b, positions, -1, group, hd)
+    out = jax.vmap(jax.vmap(kernel))(
+        grouped.transpose(0, 2, 3, 1, 4), k.transpose(0, 2, 1, 3),
+        v.transpose(0, 2, 1, 3))
+    return out.transpose(0, 3, 1, 2, 4).reshape(q.shape)
+
+
+# (seq_len, block_q, block_kv): one tile a half; several; a tile larger
+# than the half; key blocks larger than the queries'
+TILINGS = [(128, 128, 128), (256, 128, 128), (128, 512, 512),
+           (256, 128, 256)]
+
+
+@pytest.mark.parametrize("kv_heads", [1, 2])
+@pytest.mark.parametrize("block_length", [4, 32])
+@pytest.mark.parametrize("seq_len, block_q, block_kv", TILINGS)
+def test_kernels_equal_the_xla_tiles_and_the_dense_mask(
+        interpreted, monkeypatch, kv_heads, block_length, seq_len, block_q,
+        block_kv):
+    blocks(monkeypatch, block_q, block_kv)
+    q, k, v, target = operands(seq_len, 2 * kv_heads, kv_heads)
+
+    def tiled(q, k, v):
+        return bd.tiled_attention(q, k, v, block_length=block_length,
+                                  tile=128)
+
+    before = calls("pallas"), calls("xla")
+    got = with_gradients(tiled, q, k, v, target)
+    assert (calls("pallas"), calls("xla")) == (before[0] + 1, before[1])
+    monkeypatch.setenv("HVTPU_PALLAS", "0")
+    in_xla = with_gradients(lambda *a: tiled(*a), q, k, v, target)
+    assert (calls("pallas"), calls("xla")) == (before[0] + 1, before[1] + 1)
+    dense = with_gradients(
+        lambda *a: dense_attention(*a, seq_len, block_length), q, k, v,
+        target)
+    for name, g, x, d in zip(("out", "dq", "dk", "dv"), got, in_xla, dense):
+        assert distance(g, x) < 1e-5, name
+        assert distance(g, d) < 1e-5, name
+
+
+@pytest.mark.parametrize("kv_heads, block_length", [(1, 4), (2, 32)])
+def test_kernels_equal_the_librarys_splash_attention(
+        interpreted, monkeypatch, kv_heads, block_length):
+    seq_len = 256
+    blocks(monkeypatch, 128, 128)
+    q, k, v, target = operands(seq_len, 2 * kv_heads, kv_heads, seed=3)
+    got = with_gradients(
+        lambda *a: bd.tiled_attention(*a, block_length=block_length,
+                                      tile=128), q, k, v, target)
+    want = with_gradients(
+        lambda *a: splash_attention(*a, seq_len, block_length, 128),
+        q, k, v, target)
+    for name, g, w in zip(("out", "dq", "dk", "dv"), got, want):
+        assert distance(g, w) < 1e-5, name
+
+
+@pytest.mark.parametrize("kv_heads", [1, 2])
+def test_bf16_kernels_differ_from_f32_by_the_rounding_of_their_products(
+        interpreted, monkeypatch, kv_heads):
+    """The same bf16 operands through the bf16 kernels and, widened,
+    through the f32 XLA tiles: what is left is the rounding of ``p`` and
+    ``ds`` to bf16 before the second products (2**-9 an element) and of
+    the results."""
+    seq_len, block_length = 256, 4
+    blocks(monkeypatch, 128, 128)
+    q, k, v, target = operands(seq_len, 2 * kv_heads, kv_heads,
+                               jnp.bfloat16, seed=5)
+
+    def tiled(q, k, v):
+        return bd.tiled_attention(q, k, v, block_length=block_length,
+                                  tile=128)
+
+    got = with_gradients(tiled, q, k, v, target)
+    assert all(g.dtype == jnp.bfloat16 for g in got)
+    monkeypatch.setenv("HVTPU_PALLAS", "0")
+    want = with_gradients(lambda *a: tiled(*a), *(
+        a.astype(jnp.float32) for a in (q, k, v)), target)
+    for name, g, w in zip(("out", "dq", "dk", "dv"), got, want):
+        assert distance(g, w) < 6e-3, name
+
+
+def test_the_forward_kernel_hands_the_backward_out_before_its_rounding(
+        interpreted):
+    """``delta = sum(d_out * out)`` cancels against ``dp``; it is made
+    from the f32 ``out``, as XLA makes it for its own tiles."""
+    seq_len = 128
+    schedule = flash_attention.pair_schedule(
+        bd.tile_work(seq_len, 4, 128, 128), 128, 128)
+
+    def forward(dtype):
+        q, k, v, _ = operands(seq_len, 2, 1, dtype, seed=7)
+        return flash_attention.forward(
+            q, k, v, schedule, lambda a, b: bd.allowed(a, b, seq_len, 4),
+            scale=HD ** -0.5, mask_value=bd._MASKED, interpret=True)
+
+    out, _, exact = forward(jnp.bfloat16)
+    assert (out.dtype, exact.dtype) == (jnp.bfloat16, jnp.float32)
+    assert jnp.array_equal(exact.astype(jnp.bfloat16), out)
+    assert not jnp.array_equal(exact, out.astype(jnp.float32))
+    out, _, exact = forward(jnp.float32)
+    assert exact is out
+
+
+@pytest.mark.parametrize("seq_len, block_length, tile_q, tile_k", [
+    (64, 4, 16, 16), (64, 4, 16, 32), (64, 32, 8, 16), (60, 5, 16, 8),
+    (24, 1, 16, 16), (24, 24, 16, 8), (32, 16, 16, 16), (32, 32, 16, 16)])
+def test_the_tables_are_allowed_block_by_block(seq_len, block_length,
+                                               tile_q, tile_k):
+    def positions(tile):
+        n = -(-seq_len // tile)
+        pos = np.full((2, n * tile), -1)             # padding
+        pos[0, :seq_len] = np.arange(seq_len)
+        pos[1, :seq_len] = seq_len + np.arange(seq_len)
+        return pos.reshape(2 * n, tile)
+
+    q_pos, k_pos = positions(tile_q), positions(tile_k)
+    want = np.zeros((len(q_pos), len(k_pos)), np.int8)
+    for i, qp in enumerate(q_pos):
+        for j, kp in enumerate(k_pos):
+            seen = (bd.allowed(qp[:, None], kp[None, :], seq_len,
+                               block_length)
+                    & (qp[:, None] >= 0) & (kp[None, :] >= 0))
+            want[i, j] = 2 if seen.all() else 1 if seen.any() else 0
+    got = bd.tile_work(seq_len, block_length, tile_q, tile_k)
+    assert np.array_equal(got, want)
+    if seq_len % tile_q == 0 and seq_len % tile_k == 0:
+        schedule = flash_attention.pair_schedule(got, tile_q, tile_k)
+        for table, own in ((schedule.by_query, 0), (schedule.by_key, 1)):
+            assert {(i, j): kind for i, j, kind in table.T} == {
+                (i, j): want[i, j] for i, j in zip(*np.nonzero(want))}
+            assert np.all(np.diff(table[own]) >= 0)    # a tile's pairs in a row
+
+
+def test_the_cells_schedule():
+    """8,192 tokens, block length 4, blocks of 512: 288 of 1,024 pairs,
+    the 16 diagonal ones of each of three quadrants partial, at most 17
+    key blocks a query block and 32 query blocks a key block."""
+    work = bd.tile_work(8192, 4, 512, 512)
+    assert work.shape == (32, 32)
+    assert np.count_nonzero(work) == 288
+    assert np.count_nonzero(work == flash_attention.PARTIAL) == 48
+    assert np.count_nonzero(work == flash_attention.FULL) == 240
+    assert np.count_nonzero(work, axis=1).max() == 17
+    assert np.count_nonzero(work, axis=0).max() == 32
+    assert not work[16:, :16].any()
+    partial = np.argwhere(work == flash_attention.PARTIAL)
+    assert np.array_equal(partial[:, 0] % 16, partial[:, 1] % 16)
+    schedule = bd._flash_schedule(8192, 4, 512, 512)
+    assert schedule.pairs == 288
+    assert bd._flash_schedule(8192, 4, 512, 512) is schedule   # memoised
+    # keys fetched 1,024 at a time: every pair on the diagonal partial
+    assert np.count_nonzero(bd.tile_work(8192, 4, 512, 1024)) == 160
+
+
+@pytest.mark.parametrize("seq_len, head_dim, why", [
+    (256, 64, "half a vector's lanes a head"),
+    (192, 128, "a half no block divides"),
+    (136, 128, "not whole blocks of 128")])
+def test_other_shapes_take_the_xla_path(interpreted, monkeypatch, seq_len,
+                                        head_dim, why):
+    blocks(monkeypatch, 128, 128)
+    ks = jax.random.split(jax.random.PRNGKey(1), 3)
+    q = jax.random.normal(ks[0], (1, 2 * seq_len, 2, head_dim))
+    k = jax.random.normal(ks[1], (1, 2 * seq_len, 1, head_dim))
+    v = jax.random.normal(ks[2], (1, 2 * seq_len, 1, head_dim))
+    before = calls("pallas"), calls("xla")
+    out = jax.jit(lambda *a: bd.tiled_attention(
+        *a, block_length=4, tile=128))(q, k, v)
+    assert (calls("pallas"), calls("xla")) == (before[0], before[1] + 1), why
+    assert distance(out, dense_attention(q, k, v, seq_len, 4)) < 1e-5
+
+
+def test_without_pallas_the_xla_path_runs(monkeypatch):
+    """No interpreter and no TPU: what every CPU run of the model takes."""
+    monkeypatch.delenv("HVTPU_PALLAS_INTERPRET", raising=False)
+    q, k, v, _ = operands(128, 2, 1)
+    before = calls("pallas"), calls("xla")
+    jax.jit(lambda *a: bd.tiled_attention(
+        *a, block_length=4, tile=128)).lower(q, k, v)
+    assert (calls("pallas"), calls("xla")) == (before[0], before[1] + 1)
+
+
+def test_lowered_for_a_tpu_the_kernels_are_three_plain_custom_calls(
+        monkeypatch):
+    """The join the benchmark's roofline depends on (``benchmark/scopes
+    .py`` reads an instruction as one line): each kernel a
+    ``tpu_custom_call`` under the scope, named for what it is, and none
+    with kernel metadata, which XLA would print over several lines."""
+    monkeypatch.delenv("HVTPU_PALLAS_INTERPRET", raising=False)
+    monkeypatch.delenv("HVTPU_PALLAS", raising=False)
+    monkeypatch.setattr(pallas_ops, "_on_tpu", lambda: True)
+    q, k, v, target = (jax.ShapeDtypeStruct(a.shape, jnp.bfloat16)
+                       for a in operands(1024, 4, 1))
+
+    def loss(q, k, v, target):
+        out = bd.tiled_attention(q, k, v, block_length=4, tile=512)
+        return jnp.sum(out.astype(jnp.float32) * target)
+
+    text = jax.jit(jax.grad(loss, (0, 1, 2))).trace(q, k, v, target).lower(
+        lowering_platforms=("tpu",)).as_text(debug_info=True)
+    found = re.findall(r"stablehlo\.custom_call @tpu_custom_call.*", text)
+    assert len(found) == 3
+    for name, line in zip(KERNELS, sorted(
+            found, key=lambda l: KERNELS.index(next(
+                n for n in KERNELS if n in l)))):
+        assert f'kernel_name = "{name}"' in line
+        assert "kernel_metadata" not in line.replace(
+            'kernel_metadata = "{}"', "")
+    assert text.count("hvtpu:attention") >= 3

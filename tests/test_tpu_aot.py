@@ -13,6 +13,9 @@ start-up noise tier-1 does not need.  Run with
 ``pytest -m slow tests/test_tpu_aot.py``.
 """
 
+import os
+import sys
+
 import numpy as np
 import pytest
 
@@ -20,7 +23,9 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from horovod_tpu.ops import pallas_ops, ring
+sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
+
+from horovod_tpu.ops import pallas_ops, ring  # noqa: E402
 
 pytestmark = pytest.mark.slow
 
@@ -80,3 +85,88 @@ def test_ring_kernels_compile(v5e_2x2, n_dev, per_rank):
         sharding=NamedSharding(mesh, P("world")))
     _compile(wrap(lambda xs: ring.ring_allgather_2d(
         xs, axis_name="world")), block)
+
+
+# -- the block-diffusion attention's kernels (ops/flash_attention.py) -------
+
+ATTENTION_KERNELS = ("hvtpu_flash_attention_fwd", "hvtpu_flash_attention_dq",
+                     "hvtpu_flash_attention_dkv")
+
+
+def _attention_kernels_by_scope(text):
+    """Which scope ``benchmark/scopes.py`` finds for each kernel of the
+    compiled text: the join ``attention_ms_per_step`` and
+    ``block_attention_roofline`` read the kernels' device time by."""
+    from benchmark import scopes
+
+    by_instruction = scopes.scope_by_instruction(text)
+    return {kernel: {scope for name, scope in by_instruction.items()
+                     if name.startswith(kernel)}
+            for kernel in ATTENTION_KERNELS}
+
+
+def test_attention_kernels_compile_at_the_cells_shape_and_keep_their_scope(
+        v5e_2x2):
+    """``sdar-30b-a3b-1of8-t8k-b2``'s attention: 2 sequences of 2 x
+    8,192 positions, 4 query heads over 1 key/value head of 128, bf16,
+    forward and gradient, at the block sizes the model states."""
+    from horovod_tpu.models import block_diffusion as bd
+
+    sh = NamedSharding(Mesh(np.array(v5e_2x2[:1]), ("world",)), P())
+    q = jax.ShapeDtypeStruct((2, 16384, 4, 128), jnp.bfloat16, sharding=sh)
+    kv = jax.ShapeDtypeStruct((2, 16384, 1, 128), jnp.bfloat16, sharding=sh)
+
+    def loss(q, k, v, target):
+        out = bd.tiled_attention(q, k, v, block_length=4,
+                                 tile=bd._ATTENTION_TILE)
+        return jnp.sum((out * target).astype(jnp.float32))
+
+    compiled = _compile(jax.grad(loss, (0, 1, 2)), q, kv, kv, q)
+    assert _attention_kernels_by_scope(compiled.as_text()) == {
+        kernel: {"hvtpu:attention"} for kernel in ATTENTION_KERNELS}
+
+
+def test_the_transformer_cells_step_fits_and_its_kernels_keep_their_scope(
+        v5e_2x2):
+    """The whole step of ``sdar-30b-a3b-1of8-t8k-b2`` as ``benchmark/job
+    .py`` builds it, for one described chip: it needs no more memory
+    than with attention in XLA tiles (12.80 GB: arguments, outputs and
+    temporaries less what is aliased; PERF.md, findings of PR 28), and
+    every attention kernel in it, the recomputed forward too, is found
+    under ``hvtpu:attention``."""
+    import horovod_tpu as hvt
+    from benchmark import cells, job
+
+    cell = cells.load_cell("sdar-30b-a3b-1of8-t8k-b2")
+    workload = cells.load_builder(cell.config).build(cell.config)
+    hvt.init()
+    try:
+        mesh = Mesh(np.array(v5e_2x2[:1]), ("world",))
+        tx = hvt.DistributedOptimizer(
+            job.make_optimizer(cell.config["optimizer"]), axis_name="world",
+            compression=getattr(hvt.Compression, cell.traffic["compression"]))
+
+        def fresh(key):
+            params, model_state = workload.init(key, None)
+            return params, model_state, tx.init(params)
+
+        def placed(tree, spec):
+            return jax.tree_util.tree_map(
+                lambda a: jax.ShapeDtypeStruct(
+                    a.shape, a.dtype, sharding=NamedSharding(mesh, spec)),
+                tree)
+
+        batch = workload.make_pool(
+            np.random.default_rng(0), cell.traffic["batch_per_chip"],
+            cell.traffic["feed"]["dtype"])
+        compiled = job.make_step(mesh, workload.loss_fn, tx).trace(
+            *placed(jax.eval_shape(fresh, jax.random.PRNGKey(0)), P()),
+            placed(batch, P("world"))).lower(
+                lowering_platforms=("tpu",)).compile()
+    finally:
+        hvt.shutdown()
+    m = compiled.memory_analysis()
+    assert (m.argument_size_in_bytes + m.output_size_in_bytes
+            + m.temp_size_in_bytes - m.alias_size_in_bytes) <= 12.80e9
+    assert _attention_kernels_by_scope(compiled.as_text()) == {
+        kernel: {"hvtpu:attention"} for kernel in ATTENTION_KERNELS}
