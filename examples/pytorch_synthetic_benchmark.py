@@ -8,9 +8,9 @@ Only the import line changes from the reference idiom
 (``import horovod.torch as hvd`` -> ``import horovod_tpu.torch as
 hvd``).  The default model is a small conv net so the *eager torch*
 data path (DLPack adapter -> eager controller -> fused collectives) is
-what's being measured — for peak TPU numbers use the jit-path
-benchmark at the repo root (bench.py), which is the TPU-idiomatic
-equivalent of this script.
+what's being measured.  It is the frontend's example, not a record:
+the jit path's speed on the chip is measured by ``benchmark/run.py``
+(``BENCHMARK.json``, ``PERF.md``).
 
 Run:  hvtpurun -np 2 --cpu-devices 1 python \
           examples/pytorch_synthetic_benchmark.py --num-iters 3

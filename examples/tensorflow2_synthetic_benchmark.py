@@ -7,8 +7,9 @@ images/sec with the same log format.
 Only the import line changes from the reference idiom
 (``import horovod.tensorflow as hvd`` -> ``import
 horovod_tpu.tensorflow as hvd``).  A small dense model keeps the
-TF-eager data path (the system under test) tractable offline; peak TPU
-numbers come from the jit-path benchmark at the repo root (bench.py).
+TF-eager data path (the system under test) tractable offline.  It is
+the frontend's example, not a record: the jit path's speed on the chip
+is measured by ``benchmark/run.py`` (``BENCHMARK.json``, ``PERF.md``).
 
 Run:  hvtpurun -np 2 --cpu-devices 1 python \
           examples/tensorflow2_synthetic_benchmark.py --num-iters 3

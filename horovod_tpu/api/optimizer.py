@@ -209,7 +209,7 @@ def allreduce_gradients(
         st.autotuner.record_step(total_bytes)
     # Step telemetry for the eager reduction path (the jit path's
     # update is traced once, so its host loop reports via
-    # metrics.note_step directly — see bench.py).
+    # metrics.note_step directly).
     obs_metrics.note_step()
     return jax.tree_util.tree_unflatten(treedef, out)
 

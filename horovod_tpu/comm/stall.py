@@ -40,8 +40,7 @@ decorator catches that error as a recoverable failure, so a stalled
 elastic job rolls back and re-rendezvouses like the reference's
 shutdown-on-stall path.  Detection latency is one heartbeat; the
 healthy-path cost is ~1 µs/op and two KV RPCs per heartbeat — vs one
-KV write plus a polled read PER OP for strict mode, which doubled
-small-op latency (BENCH_SCALING.json coordination_vs_P, round 4).
+KV write plus a polled read PER OP for strict mode.
 
 **strict** — the round-4 pre-dispatch rendezvous: post
 ``stall/<gen>/<set>/<seq>/<rank> = op-descriptor``, await every member

@@ -508,8 +508,8 @@ class ElasticDataLoader:
             self.state.cursor = 0
 
     def stream(self):
-        """Infinite batch iterator across epoch boundaries (the bench
-        shape: the prefetcher keeps the queue full through rollovers)."""
+        """Infinite batch iterator across epoch boundaries (the
+        prefetcher keeps the queue full through rollovers)."""
         while True:
             yield from self
 

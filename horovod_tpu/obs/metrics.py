@@ -263,8 +263,7 @@ class MetricsRegistry:
     # -- export ----------------------------------------------------------
     def snapshot(self) -> Dict[str, dict]:
         """JSON-serializable dump of every family (the unit that rides
-        the coordination KV in ``aggregate`` and embeds in bench.py's
-        report)."""
+        the coordination KV in ``aggregate``)."""
         with self._lock:
             return {
                 name: {
@@ -351,7 +350,7 @@ def note_step(examples: float = 0.0, steps: float = 1.0):
     inter-call time.  Called by the eager ``allreduce_gradients`` path
     once per step; jit training loops (whose update is traced once)
     call it from the host loop, passing the steps and examples per
-    dispatch (see bench.py's lax.scan dispatches)."""
+    dispatch."""
     REGISTRY.counter(
         "hvtpu_optimizer_steps_total", "Optimizer steps applied."
     ).inc(steps)

@@ -1,9 +1,7 @@
 """Step-level compute/communication overlap profiler.
 
-Every scaling verdict in this repo used to lean on an *assumed*
-overlap budget (BENCH_SCALING's 0.5, bench.py's hand-tabulated FLOPs).
-This module measures instead of modeling, by joining the two timelines
-the repo already produces but never correlated:
+This module measures overlap instead of assuming a budget for it, by
+joining the two timelines the repo already produces:
 
   * the **XLA device profile** — ``obs/profile.load_profile`` parses
     ``*.xplane.pb`` into timestamped per-op intervals, with wire

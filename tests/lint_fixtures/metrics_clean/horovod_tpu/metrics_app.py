@@ -1,4 +1,4 @@
-"""metrics-catalog fixture (clean): registry, docs, and bench agree."""
+"""metrics-catalog fixture (clean): registry and docs agree."""
 
 from .registry import REGISTRY, counter, gauge
 

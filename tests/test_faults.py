@@ -397,7 +397,7 @@ class TestFlapWindow:
 def test_inactive_guard_is_zero_overhead():
     """Acceptance: with an empty fault spec the hot-path hook is one
     module-attribute read — bound it at far under a microsecond per op
-    so the bench wire-bytes/latency numbers cannot regress."""
+    so a job without faults pays nothing for the hooks."""
     import timeit
 
     assert faults.ACTIVE is False

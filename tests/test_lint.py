@@ -176,8 +176,6 @@ class TestMetricsCatalog:
         assert keys(findings) == {
             "hvtpu_fixture_undocumented_total",        # registered, uncataloged
             "hvtpu_fixture_stale",                     # cataloged, unregistered
-            "required:hvtpu_fixture_missing_total",    # bench key unregistered
-            "required-doc:hvtpu_fixture_missing_total",  # bench key uncataloged
         }
 
 

@@ -155,7 +155,7 @@ class TestTpuBatchNorm:
 class TestBenchmarkTrio:
     """The reference's README benchmark trio (docs/benchmarks.rst):
     Inception V3 / ResNet-101 / VGG-16 — all available for
-    like-for-like scaling runs (bench.py HVTPU_BENCH_MODEL)."""
+    like-for-like runs (VGG-16 is a configuration of the benchmark)."""
 
     def test_vgg16_forward_and_grads(self):
         import optax

@@ -13,8 +13,8 @@ measurable, not asserted:
 - Fusion-level: a compressed fused bucket's wire buffer is half (fp16)
   / about a quarter (int8 + scale sidecar) of the f32 payload bytes.
 
-The throughput side of the story is ``bench_eager.py --compression-ab``
-(BENCH_EAGER.json, P=4 real processes).
+What the smaller wire buys in step time has not been measured on a
+chip (PERF.md §7, ``vgg16-b64-dp4-int8``).
 """
 
 import jax

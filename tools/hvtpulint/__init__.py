@@ -7,7 +7,7 @@ runtime (see docs/static-analysis.md):
   rank-divergence  collectives issued under rank-dependent control flow
   thread-safety    guarded-by lock discipline in eager/controller.py
   knob-registry    HVTPU_* env knobs vs the generated docs/knobs.md
-  metrics-catalog  registered metrics vs docs/observability.md vs bench
+  metrics-catalog  registered metrics vs docs/observability.md
   sim-purity       no host time / ambient RNG in horovod_tpu/sim
   kv-discipline    raw coordination-client KV calls outside the
                    FencedKV/ResilientKV wrappers (core/retry.py)

@@ -36,7 +36,7 @@ PASS = "knob-registry"
 KNOBS_MD = "docs/knobs.md"
 LAUNCH_PY = "horovod_tpu/runner/launch.py"
 SCAN_DIRS = ("horovod_tpu", "examples")
-SCAN_FILES = ("bench.py", "bench_eager.py", "bench_scaling.py", "setup.py")
+SCAN_FILES = ("setup.py",)
 
 _ENV_HELPER_RE = re.compile(r"^_env(_\w+)?$")
 _GET_LIKE = {"get", "getenv", "pop", "setdefault"}

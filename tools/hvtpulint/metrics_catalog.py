@@ -1,6 +1,6 @@
-"""metrics-catalog pass: registered metrics vs docs vs bench contract.
+"""metrics-catalog pass: registered metrics vs docs/observability.md.
 
-Three sources, checked in both directions:
+Two sources, checked in both directions:
 
   * registered: literal first arguments of counter()/gauge()/
     histogram() calls under horovod_tpu/, plus op_counter() — the one
@@ -8,10 +8,8 @@ Three sources, checked in both directions:
     collective kinds (the kind_to_type map in eager/controller.py
     plus literal op_counter call sites)
   * cataloged: every `hvtpu_*` token in docs/observability.md
-  * required: bench.py REQUIRED_METRIC_KEYS (the bench-guard contract)
 
-Findings: registered-but-uncataloged, cataloged-but-unregistered, and
-required keys missing from either side.
+Findings: registered-but-uncataloged and cataloged-but-unregistered.
 """
 
 from __future__ import annotations
@@ -26,7 +24,6 @@ PASS = "metrics-catalog"
 
 SCAN_DIRS = ("horovod_tpu",)
 OBS_MD = "docs/observability.md"
-BENCH_PY = "bench.py"
 CONTROLLER_PY = "horovod_tpu/eager/controller.py"
 
 _REGISTER_FUNCS = {"counter", "gauge", "histogram"}
@@ -99,21 +96,6 @@ def cataloged_metrics(text: str) -> Dict[str, int]:
     return out
 
 
-def required_keys(project: Project) -> List[str]:
-    tree = project.parse(BENCH_PY)
-    if tree is None:
-        return []
-    for node in ast.walk(tree):
-        if (isinstance(node, ast.Assign) and len(node.targets) == 1
-                and isinstance(node.targets[0], ast.Name)
-                and node.targets[0].id == "REQUIRED_METRIC_KEYS"):
-            try:
-                return [str(v) for v in ast.literal_eval(node.value)]
-            except ValueError:
-                return []
-    return []
-
-
 def run(project: Project) -> List[Finding]:
     findings: List[Finding] = []
     obs_text = project.read(OBS_MD)
@@ -123,7 +105,6 @@ def run(project: Project) -> List[Finding]:
 
     registered = registered_metrics(project)
     cataloged = cataloged_metrics(obs_text)
-    required = required_keys(project)
 
     for name, (rel, line) in sorted(registered.items()):
         if name not in cataloged:
@@ -136,20 +117,4 @@ def run(project: Project) -> List[Finding]:
                 PASS, OBS_MD, line, name,
                 f"metric {name} is cataloged but never registered — "
                 "stale doc or a renamed registration"))
-    if not required:
-        findings.append(Finding(
-            PASS, BENCH_PY, 0, "required-metric-keys",
-            "REQUIRED_METRIC_KEYS not found in bench.py — the bench "
-            "contract the metrics-catalog pass cross-checks is gone"))
-    for name in required:
-        if name not in registered:
-            findings.append(Finding(
-                PASS, BENCH_PY, 0, f"required:{name}",
-                f"bench REQUIRED_METRIC_KEYS entry {name} is not a "
-                "registered metric"))
-        if name not in cataloged:
-            findings.append(Finding(
-                PASS, BENCH_PY, 0, f"required-doc:{name}",
-                f"bench REQUIRED_METRIC_KEYS entry {name} is missing "
-                f"from {OBS_MD}"))
     return findings

@@ -508,10 +508,9 @@ def _cmd_bench(args) -> int:
                 "MEASURED on the fabric simulator (horovod_tpu/sim): "
                 "real KVTransport/audit/drain code over the virtual-"
                 "time KV with the default link model (50us, 1GbE, 10% "
-                "jitter).  Supersedes the coordination_vs_P projection "
-                "for control-plane scaling: these are protocol-"
-                "faithful virtual-time measurements at the stated "
-                "world sizes, not extrapolations."),
+                "jitter).  Protocol-faithful virtual-time "
+                "measurements at the stated world sizes, not "
+                "extrapolations, and not device time."),
             "rows": rows,
         }
         with open(path, "w") as f:
