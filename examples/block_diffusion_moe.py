@@ -28,6 +28,7 @@ from jax.sharding import NamedSharding, PartitionSpec as P  # noqa: E402
 import horovod_tpu as hvt  # noqa: E402
 from horovod_tpu.models import block_diffusion as bd  # noqa: E402
 from horovod_tpu.obs import metrics  # noqa: E402
+from horovod_tpu.parallel import moe  # noqa: E402
 
 TOY = bd.BlockDiffusionConfig(
     vocab_size=512, hidden_size=128, num_layers=2, num_heads=4,
@@ -104,7 +105,11 @@ def main():
                 params, opt_state, next(batches))
             if i % 5 == 0 or i == args.steps - 1:
                 # logging cadence: the routing's counts come to the host
-                metrics.note_moe_routing(routing["moe_rows_per_expert"])
+                metrics.note_moe_routing(
+                    routing["moe_rows_per_expert"],
+                    buffer_rows=moe.buffer_rows(
+                        2 * seq_len * args.batch_per_chip, cfg.top_k,
+                        cfg.experts_held))
                 snap = metrics.snapshot()
                 print(f"step {i}: loss {float(loss):.4f} (ln vocabulary "
                       f"{np.log(cfg.vocab_size):.2f}); busiest expert over "

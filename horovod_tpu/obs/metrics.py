@@ -692,14 +692,16 @@ def note_attention_path(path: str) -> None:
         "kernels or XLA tiles.").inc(path=path)
 
 
-def note_moe_routing(rows_per_expert) -> None:
+def note_moe_routing(rows_per_expert, buffer_rows=None) -> None:
     """Record what a step's expert layers saw: ``rows_per_expert`` is
     the ``[layers, experts_held]`` (or ``[experts_held]``) count that
     ``parallel.moe.dropless_topk_moe`` returns and a model hands back in
-    its ``model_state``.  Call it from the host loop at logging cadence,
-    on a state the loop has already fetched: it reads the array (a
-    device-to-host copy if it still lives on the chip) and is never
-    called from inside the step."""
+    its ``model_state``; ``buffer_rows``, where the caller knows it
+    (``parallel.moe.buffer_rows`` of the layer's sizes), the rows of the
+    buffer the layer keeps for the worst routing.  Call it from the
+    host loop at logging cadence, on a state the loop has already
+    fetched: it reads the array (a device-to-host copy if it still
+    lives on the chip) and is never called from inside the step."""
     import numpy as np
 
     rows = np.asarray(rows_per_expert, np.float64)
@@ -717,3 +719,11 @@ def note_moe_routing(rows_per_expert) -> None:
         "hvtpu_moe_local_rows_total",
         "Rows (token, expert) the experts held here multiplied, summed "
         "over layers and over the steps noted.").inc(float(rows.sum()))
+    if buffer_rows:
+        REGISTRY.gauge(
+            "hvtpu_moe_buffer_live_share",
+            "Rows the experts held here got over the rows of the buffer "
+            "the layer keeps for a routing that sends every token here, "
+            "the fullest layer of the last step noted: the buffer is "
+            "allocated and never cleared, so the rest costs memory and "
+            "no time.").set(float(rows.sum(axis=-1).max() / buffer_rows))

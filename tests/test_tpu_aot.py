@@ -168,5 +168,63 @@ def test_the_transformer_cells_step_fits_and_its_kernels_keep_their_scope(
     m = compiled.memory_analysis()
     assert (m.argument_size_in_bytes + m.output_size_in_bytes
             + m.temp_size_in_bytes - m.alias_size_in_bytes) <= 12.80e9
-    assert _attention_kernels_by_scope(compiled.as_text()) == {
+    text = compiled.as_text()
+    assert _attention_kernels_by_scope(text) == {
         kernel: {"hvtpu:attention"} for kernel in ATTENTION_KERNELS}
+    _no_pass_over_a_whole_row_buffer(text, cell)
+    _the_expert_layers_loops_lie_under_its_scopes(text)
+
+
+def _no_pass_over_a_whole_row_buffer(text, cell):
+    """The expert layer's buffers in expert order (270,848 rows of
+    2,048 in this cell, of which an even routing uses an eighth) are
+    allocated and written a tile at a time: nothing fills one, and
+    nothing copies one (which is what XLA does in every layer with an
+    ``AllocateBuffer`` it has moved out of the layers' scan)."""
+    import re
+
+    from horovod_tpu.parallel import moe
+
+    rows = moe.buffer_rows(
+        cell.traffic["batch_per_chip"] * 2 * cell.traffic["sequence_length"],
+        cell.config["num_experts_per_tok"], cell.config["num_experts"])
+    made = {}
+    for m in re.finditer(
+            r"^\s*(?:ROOT\s+)?%(?P<name>\S+) = \w+\[" + str(rows)
+            + r",\d+\]\S* (?P<op>[\w-]+)\((?P<rest>.*)$", text,
+            re.MULTILINE):
+        made.setdefault(m["op"], []).append(m)
+    assert made, f"no array of {rows} rows in the step"
+    assert set(made) <= {"custom-call", "fusion", "dynamic-update-slice",
+                         "get-tuple-element", "parameter", "while"}, {
+        op: [m["name"] for m in found] for op, found in made.items()}
+    for m in made["custom-call"]:
+        assert m["name"].startswith("hvtpu_moe_row_buffer"), m["name"]
+    for m in made["fusion"]:            # a loop's write of one tile, in place
+        body = re.search(r"calls=%([\w.-]+)", m["rest"])[1]
+        start = text.index(f"\n%{body} ")
+        assert "dynamic-update-slice(" in text[start:text.index(
+            "\n}", start)], m["name"]
+
+
+def _the_expert_layers_loops_lie_under_its_scopes(text):
+    """On a TPU the attention runs in kernels, so every loop inside the
+    layers' scan is the expert layer's: each instruction of one, and
+    the kernels that allocate its buffers, carries an ``hvtpu:moe.``
+    scope, so that ``moe_ms_per_step`` holds the layer's whole cost."""
+    import re
+
+    from benchmark import scopes
+
+    by_instruction = scopes.scope_by_instruction(text)
+    in_a_loop_of_a_layer = [
+        (name, op_name) for name, op_name in re.findall(
+            r'^\s*(?:ROOT\s+)?%(\S+) = .*?op_name="([^"]*)"', text,
+            re.MULTILINE) if op_name.count("while/body") >= 2]
+    assert len(in_a_loop_of_a_layer) > 500
+    assert [pair for pair in in_a_loop_of_a_layer
+            if not by_instruction.get(pair[0], "").startswith("hvtpu:moe.")
+            ] == []
+    buffers = {scope for name, scope in by_instruction.items()
+               if name.startswith("hvtpu_moe_row_buffer")}
+    assert buffers and buffers <= {"hvtpu:moe.dispatch", "hvtpu:moe.combine"}
