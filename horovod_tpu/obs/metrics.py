@@ -727,3 +727,39 @@ def note_moe_routing(rows_per_expert, buffer_rows=None) -> None:
             "the fullest layer of the last step noted: the buffer is "
             "allocated and never cleared, so the rest costs memory and "
             "no time.").set(float(rows.sum(axis=-1).max() / buffer_rows))
+
+
+# ---------------------------------------------------------------------------
+# state-space layers and packed documents
+# ---------------------------------------------------------------------------
+
+def note_ssm_chunks(chunks: int) -> None:
+    """Count the chunks one call of ``models.hybrid_ssm.ssd_scan`` walks
+    (rows x chunks a row).  Called while a program is traced, once a
+    call site and a trace, like ``note_attention_path``: a step that
+    scans its layers counts one layer's chunks."""
+    REGISTRY.counter(
+        "hvtpu_ssm_chunks_total",
+        "Chunks the chunked state-space scan walks in one call (rows x "
+        "chunks a row), counted when a program is traced.").inc(float(chunks))
+
+
+def note_packed_batch(segment) -> None:
+    """Record what a packed batch holds: ``segment`` is the int ``[rows,
+    T]`` array of the document's index at every position, as the batch
+    of ``models.hybrid_ssm.next_token_loss`` brings it.  Call it from
+    the host loop at logging cadence on a batch the loop still holds on
+    the host; never from inside the step."""
+    import numpy as np
+
+    seg = np.asarray(segment)
+    started = seg.shape[0] + int(np.count_nonzero(seg[:, 1:] != seg[:, :-1]))
+    REGISTRY.counter(
+        "hvtpu_ssm_state_resets_total",
+        "Documents started in the batches noted: each resets the "
+        "state-space layers' state, cuts the convolution's taps and "
+        "bounds the keys a query sees.").inc(float(started))
+    REGISTRY.gauge(
+        "hvtpu_packed_documents_per_row",
+        "Documents a row of the last batch noted holds, the mean over "
+        "its rows: 1.0 is an unpacked batch.").set(started / seg.shape[0])

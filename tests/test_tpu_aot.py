@@ -126,24 +126,19 @@ def test_attention_kernels_compile_at_the_cells_shape_and_keep_their_scope(
         kernel: {"hvtpu:attention"} for kernel in ATTENTION_KERNELS}
 
 
-def test_the_transformer_cells_step_fits_and_its_kernels_keep_their_scope(
-        v5e_2x2):
-    """The whole step of ``sdar-30b-a3b-1of8-t8k-b2`` as ``benchmark/job
-    .py`` builds it, for one described chip: it needs no more memory
-    than with attention in XLA tiles (12.80 GB: arguments, outputs and
-    temporaries less what is aliased; PERF.md, findings of PR 28), and
-    every attention kernel in it, the recomputed forward too, is found
-    under ``hvtpu:attention``."""
+def _compiled_step(cell, v5e_2x2, config=None):
+    """The whole step of a cell as ``benchmark/job.py`` builds it,
+    compiled for one described chip."""
     import horovod_tpu as hvt
     from benchmark import cells, job
 
-    cell = cells.load_cell("sdar-30b-a3b-1of8-t8k-b2")
-    workload = cells.load_builder(cell.config).build(cell.config)
+    config = config or cell.config
+    workload = cells.load_builder(config).build(config)
     hvt.init()
     try:
         mesh = Mesh(np.array(v5e_2x2[:1]), ("world",))
         tx = hvt.DistributedOptimizer(
-            job.make_optimizer(cell.config["optimizer"]), axis_name="world",
+            job.make_optimizer(config["optimizer"]), axis_name="world",
             compression=getattr(hvt.Compression, cell.traffic["compression"]))
 
         def fresh(key):
@@ -159,12 +154,26 @@ def test_the_transformer_cells_step_fits_and_its_kernels_keep_their_scope(
         batch = workload.make_pool(
             np.random.default_rng(0), cell.traffic["batch_per_chip"],
             cell.traffic["feed"]["dtype"])
-        compiled = job.make_step(mesh, workload.loss_fn, tx).trace(
+        return job.make_step(mesh, workload.loss_fn, tx).trace(
             *placed(jax.eval_shape(fresh, jax.random.PRNGKey(0)), P()),
             placed(batch, P("world"))).lower(
                 lowering_platforms=("tpu",)).compile()
     finally:
         hvt.shutdown()
+
+
+def test_the_transformer_cells_step_fits_and_its_kernels_keep_their_scope(
+        v5e_2x2):
+    """The whole step of ``sdar-30b-a3b-1of8-t8k-b2`` as ``benchmark/job
+    .py`` builds it, for one described chip: it needs no more memory
+    than with attention in XLA tiles (12.80 GB: arguments, outputs and
+    temporaries less what is aliased; PERF.md, findings of PR 28), and
+    every attention kernel in it, the recomputed forward too, is found
+    under ``hvtpu:attention``."""
+    from benchmark import cells
+
+    cell = cells.load_cell("sdar-30b-a3b-1of8-t8k-b2")
+    compiled = _compiled_step(cell, v5e_2x2)
     m = compiled.memory_analysis()
     assert (m.argument_size_in_bytes + m.output_size_in_bytes
             + m.temp_size_in_bytes - m.alias_size_in_bytes) <= 12.80e9
@@ -228,3 +237,79 @@ def _the_expert_layers_loops_lie_under_its_scopes(text):
     buffers = {scope for name, scope in by_instruction.items()
                if name.startswith("hvtpu_moe_row_buffer")}
     assert buffers and buffers <= {"hvtpu:moe.dispatch", "hvtpu:moe.combine"}
+
+
+# -- the hybrid state-space cell (models/hybrid_ssm.py) ----------------------
+
+HYBRID_CELL = "granite-4.0-h-micro-10of40-t8k-b2"
+# what lax.scan and the layer's jax.checkpoint themselves add to a loop's
+# body around the layer they run: the slices of the stacked parameters,
+# the writes of their gradients, the copies of what is kept
+SCAN_PLUMBING = ("dynamic_slice", "dynamic_update_slice", "squeeze",
+                 "broadcast_in_dim", "add", "sub", "closed_call", "remat2")
+
+
+def _in_loops(text, depth):
+    """(instruction, op_name) of the compiled text that lie inside at
+    least ``depth`` nested loops."""
+    import re
+
+    return [(name, op_name) for name, op_name in re.findall(
+        r'^\s*(?:ROOT\s+)?%(\S+) = .*?op_name="([^"]*)"', text, re.MULTILINE)
+        if op_name.count("while/body") >= depth]
+
+
+def test_the_hybrid_cells_step_fits_one_chip(v5e_2x2):
+    """The whole step of ``granite-4.0-h-micro-10of40-t8k-b2`` as
+    ``benchmark/job.py`` builds it, 772 M parameters trained at 12 bytes
+    each and 16,384 tokens a step, for one described chip.  By the
+    figure the transformer cell's case uses (arguments, outputs and
+    temporaries less what is aliased) it needs 15.6 GB; the allocator
+    on the chip counts 14.4 (PERF.md, findings of PR 32).  No softmax's
+    maximum is recomputed for every score (a ``reduce-window`` as wide
+    as the keys: 47 ms a tile on the chip), and the chunk scan's loops
+    hold nothing outside ``hvtpu:ssm.scan``."""
+    import re
+
+    from benchmark import cells, scopes
+
+    compiled = _compiled_step(cells.load_cell(HYBRID_CELL), v5e_2x2)
+    m = compiled.memory_analysis()
+    assert (m.argument_size_in_bytes + m.output_size_in_bytes
+            + m.temp_size_in_bytes - m.alias_size_in_bytes) <= 15.9e9
+    text = compiled.as_text()
+    windows = [max(int(n) for n in size.split("x")) for size in re.findall(
+        r"reduce-window\([^\n]*window=\{size=([\dx]+)", text)]
+    assert windows and max(windows) <= 256, sorted(set(windows))
+    by_instruction = scopes.scope_by_instruction(text)
+    in_a_chunk_loop = _in_loops(text, 2)
+    assert len(in_a_chunk_loop) > 300
+    assert {by_instruction.get(name) for name, _ in in_a_chunk_loop} == {
+        "hvtpu:ssm.scan"}
+    assert {"hvtpu:ssm.proj", "hvtpu:ssm.conv", "hvtpu:ssm.scan",
+            "hvtpu:ssm.gate", "hvtpu:mlp", "hvtpu:attention",
+            "hvtpu:lm_head"} <= set(by_instruction.values())
+
+
+def test_a_mamba_layers_instructions_lie_under_its_scopes(v5e_2x2):
+    """Two Mamba layers alone at the cell's widths and tokens, forward
+    and backward: every instruction of the layers' loop that the layer
+    wrote, and not ``lax.scan`` around it, carries one of ``hvtpu:ssm.
+    proj|conv|scan|gate`` or ``hvtpu:mlp``, so that ``ssm_ms_per_step``
+    and the MLP's scope hold the layer's whole cost."""
+    from benchmark import cells, scopes
+
+    cell = cells.load_cell(HYBRID_CELL)
+    config = {**cell.config, "num_hidden_layers": 2,
+              "layer_types": ["mamba", "mamba"]}
+    text = _compiled_step(cell, v5e_2x2, config).as_text()
+    by_instruction = scopes.scope_by_instruction(text)
+    in_a_layer = [(name, op_name) for name, op_name in _in_loops(text, 1)
+                  if op_name.rsplit("/", 1)[-1] not in SCAN_PLUMBING]
+    assert len(in_a_layer) > 1000
+    outside = [pair for pair in in_a_layer
+               if pair[0] not in by_instruction]
+    assert outside == [], outside
+    assert {by_instruction[name] for name, _ in in_a_layer} == {
+        "hvtpu:ssm.proj", "hvtpu:ssm.conv", "hvtpu:ssm.scan",
+        "hvtpu:ssm.gate", "hvtpu:mlp"}
