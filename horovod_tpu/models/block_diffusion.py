@@ -396,10 +396,10 @@ def tiled_attention(q, k, v, *, block_length: int, tile: int):
     if not use:
         why = "no Pallas on this backend"
     elif not (q.dtype == k.dtype == v.dtype and flash_attention.supports(
-            hd, q.dtype, seq_len, *blocks)):
-        why = (f"the kernels take heads of whole 128 lanes and halves of "
-               f"whole blocks, not head_dim {hd}, {q.dtype}, blocks of "
-               f"{blocks}")
+            hd, q.dtype, seq_len, *blocks, groups)):
+        why = (f"the kernels take heads of 128 lanes, or pairs of heads of "
+               f"64, and halves of whole blocks, not {groups} heads of "
+               f"{hd}, {q.dtype}, blocks of {blocks}")
     else:
         metrics.note_attention_path("pallas")
         return _flash(q, k, v, (seq_len, block_length, *blocks, interpret))

@@ -29,8 +29,9 @@ from the state carried in; the chunks are walked in time by a
 
 *The attention layer*: q, k, v, o without bias, no positional encoding,
 scores times ``attention_multiplier`` (not ``1/sqrt(head_dim)``),
-causal.  Its heads are 64 wide and ``ops/flash_attention`` takes whole
-128-lane heads, so it runs in XLA, a tile of queries at a time.
+causal.  On a TPU its tile pairs run in the Pallas kernels of
+``ops/flash_attention.py``, which are handed the document ids; elsewhere
+in XLA, a tile of queries at a time (``causal_document_attention``).
 
 *Documents.*  A row is several documents back to back; ``segment``
 gives the document's index at every position.  At a document's first
@@ -57,14 +58,17 @@ of the heads is not a part of a sum that can be left out.
 from __future__ import annotations
 
 import dataclasses
+import functools
 import itertools
 from typing import Any, Dict, List, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax import lax
 
 from ..obs import metrics
+from ..ops import flash_attention, pallas_ops
 from .block_diffusion import _MASKED, rms_norm
 
 Params = Dict[str, Any]
@@ -72,6 +76,12 @@ Params = Dict[str, Any]
 # queries of a tile of the attention layer: the f32 scores of a tile
 # against every earlier key, 2 rows x 32 heads x 256 x 8,192, are 0.5 GB
 _ATTENTION_TILE = 256
+
+# queries and keys of a block of the Pallas kernels
+# (``ops/flash_attention.py``): the transformer's, PERF.md, findings of
+# PRs 28 and 33
+_FLASH_BLOCK_Q = 512
+_FLASH_BLOCK_KV = 512
 
 
 @dataclasses.dataclass(frozen=True)
@@ -300,17 +310,116 @@ def mamba_mixer(cfg: HybridSSMConfig, p: Params, u, segment):
 # the attention layer
 # ---------------------------------------------------------------------------
 
+def _seen(q_pos, k_pos, q_doc, k_doc):
+    """The mask: a key at or before the query, in its document."""
+    return (k_pos <= q_pos) & (q_doc == k_doc)
+
+
+@functools.lru_cache(maxsize=None)
+def _flash_schedule(positions: int, block_q: int,
+                    block_kv: int) -> flash_attention.PairSchedule:
+    """The kernels' walk: the causal triangle of block pairs, made from
+    positions alone.  Which of them hold a pair of one document is data
+    (``live_pairs``), so none is known to be full."""
+    first_key = np.arange(0, positions, block_kv)
+    last_query = np.arange(0, positions, block_q) + block_q - 1
+    return flash_attention.pair_schedule(
+        flash_attention.PARTIAL * (first_key[None, :] <= last_query[:, None]),
+        block_q, block_kv)
+
+
+def live_pairs(segment, block_q: int, block_kv: int):
+    """int32 ``[B, query blocks, key blocks]``: 0 where no query of the
+    one block and no key of the other can be of one document, because
+    the ranges of their ids do not meet.  ``segment`` numpy or jax."""
+    b = segment.shape[0]
+
+    def ranges(block):
+        ids = segment.reshape(b, -1, block)
+        return ids.min(axis=-1), ids.max(axis=-1)
+
+    (q_lo, q_hi), (k_lo, k_hi) = ranges(block_q), ranges(block_kv)
+    return ((k_lo[:, None, :] <= q_hi[:, :, None])
+            & (q_lo[:, :, None] <= k_hi[:, None, :])).astype(np.int32)
+
+
+def _flash_blocks(positions: int) -> Tuple[int, int]:
+    return min(_FLASH_BLOCK_Q, positions), min(_FLASH_BLOCK_KV, positions)
+
+
+def note_attention_pairs(segment) -> None:
+    """Count, from the host's loop like ``metrics.note_packed_batch``,
+    the block pairs of the causal triangle the kernels run and skip on a
+    batch of this ``segment`` (numpy ``[B, T]``, whole blocks)."""
+    blocks = _flash_blocks(segment.shape[1])
+    query_block, key_block = _flash_schedule(
+        segment.shape[1], *blocks).by_query[:2]
+    run = int(live_pairs(np.asarray(segment), *blocks)[
+        :, query_block, key_block].sum())
+    metrics.note_attention_pairs(
+        run, segment.shape[0] * len(query_block) - run)
+
+
+def _in_kernels(kernels, spec, segment, *operands):
+    """``flash_attention.forward`` or ``.backward`` on ``operands``
+    (``q`` first) under the document mask."""
+    scale, block_q, block_kv, interpret = spec
+    return kernels(
+        *operands, _flash_schedule(operands[0].shape[1], block_q, block_kv),
+        _seen, scale=scale, mask_value=_MASKED, interpret=interpret,
+        ids=segment, live=live_pairs(segment, block_q, block_kv))
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4,))
+def _flash(q, k, v, segment, spec):
+    return _flash_fwd(q, k, v, segment, spec)[0]
+
+
+@jax.named_scope("hvtpu:attention")
+def _flash_fwd(q, k, v, segment, spec):
+    out, lse, exact = _in_kernels(
+        flash_attention.forward, spec, segment, q, k, v)
+    return out, (q, k, v, exact, lse, segment)
+
+
+@jax.named_scope("hvtpu:attention")
+def _flash_bwd(spec, res, d_out):
+    *res, segment = res
+    return (*_in_kernels(flash_attention.backward, spec, segment, *res,
+                         d_out), None)
+
+
+_flash.defvjp(_flash_fwd, _flash_bwd)
+
+
 def causal_document_attention(q, k, v, segment, *, scale: float, tile: int):
     """Attention of ``q`` ``[B, T, H, hd]`` over ``k``, ``v`` ``[B, T, G,
     hd]``, every query head reading the key/value head of its group: a
-    query sees the keys at or before it in its own document.  A tile of
-    queries at a time against the keys up to the tile's end (the tiles
-    beyond are never computed), a plain softmax in f32, each tile
-    recomputed in the backward pass.  Which tile pairs hold no pair of
-    one document is data, so none of them is left out.  Everything in
-    it runs under the scope ``hvtpu:attention``."""
+    query sees the keys at or before it in its own document.  Scores
+    and softmax in f32; the products take ``q``'s type.  Everything in
+    it runs under the scope ``hvtpu:attention``.
+
+    Which implementation runs is observed, not set, as in
+    ``block_diffusion.tiled_attention``.  Where ``ops.pallas_ops``
+    compiles kernels and the shapes are ones they take, the causal
+    triangle of block pairs runs in the Pallas kernels of
+    ``ops/flash_attention.py`` with ``segment`` as the mask's data: a
+    pair's scores stay in VMEM, and a pair whose blocks share no
+    document is skipped when the step runs.  Elsewhere in XLA: a tile
+    of queries at a time against the keys up to the tile's end (the
+    tiles beyond are never computed), a plain softmax, each tile
+    recomputed in the backward pass, no pair left out.
+    ``hvtpu_attention_calls_total{path=}`` counts, when a program is
+    traced, which it was."""
     b, t, heads, hd = q.shape
     groups = k.shape[2]
+    use, interpret = pallas_ops._pallas_mode()
+    blocks = _flash_blocks(t)
+    if use and q.dtype == k.dtype == v.dtype and flash_attention.supports(
+            hd, q.dtype, t, *blocks, groups):
+        metrics.note_attention_path("pallas")
+        return _flash(q, k, v, segment.astype(jnp.int32),
+                      (scale, *blocks, interpret))
     tile = min(tile, t)
     metrics.note_attention_path("xla")
 

@@ -679,17 +679,35 @@ def aggregate(process_set=None, timeout_s: float = 60.0,
 # ---------------------------------------------------------------------------
 
 def note_attention_path(path: str) -> None:
-    """Count one call of ``models.block_diffusion.tiled_attention`` by
-    the implementation it took: ``"pallas"`` (the kernels of
+    """Count one call of ``models.block_diffusion.tiled_attention`` or
+    ``models.hybrid_ssm.causal_document_attention`` by the
+    implementation it took: ``"pallas"`` (the kernels of
     ``ops/flash_attention.py``) or ``"xla"``.  Called while a program is
     traced, once a call site and a trace, never from inside the step: a
     step that scans its layers counts one call however many layers run
     it."""
     REGISTRY.counter(
         "hvtpu_attention_calls_total",
-        "Calls of the block-diffusion attention, counted when a program "
+        "Calls of the models' attention in tiles, counted when a program "
         "is traced, by the implementation that was built in: the Pallas "
         "kernels or XLA tiles.").inc(path=path)
+
+
+def note_attention_pairs(run: int, skipped: int) -> None:
+    """Count the block pairs the attention kernels ran and skipped on
+    the batches noted: a pair whose blocks share no document is decided
+    by the batch's data when the step runs, so the count comes from the
+    host's loop (``models.hybrid_ssm.note_attention_pairs(segment)``
+    makes it from the flags the step itself computes), never from inside
+    the step."""
+    pairs = REGISTRY.counter(
+        "hvtpu_attention_pairs_total",
+        "Block pairs (row, query block, key block) of the document "
+        "attention's schedule on the batches noted, by whether the "
+        "kernels ran them or skipped them because the blocks share no "
+        "document.")
+    pairs.inc(float(run), kind="run")
+    pairs.inc(float(skipped), kind="skipped")
 
 
 def note_moe_routing(rows_per_expert, buffer_rows=None) -> None:

@@ -10,14 +10,22 @@ lists the (query tile, key tile) pairs that hold work and says of each
 whether every query sees every key, and ``seen(q_pos, k_pos)``, the
 mask itself, which a kernel evaluates on iotas in the pairs that are
 not full.  Nothing here knows which mask it is
-(``models/block_diffusion.py`` brings ``allowed``);
+(``models/block_diffusion.py`` brings ``allowed``).  A mask may also
+be *data*: with ``ids`` ``[B, P]`` (a document's index at every
+position, say) ``seen(q_pos, k_pos, q_id, k_id)`` gets the ids of the
+tile's queries and keys too, cut into blocks by the same index maps as
+``q`` and ``k``, and ``live`` ``[B, query tiles, key tiles]``, computed
+in the step from the same data, says which pairs of the schedule a row
+need not run at all (``models/hybrid_ssm.py`` brings both).  Without
+them the kernels are built as if neither existed.
 ``jax.experimental.pallas.ops.tpu.splash_attention`` is the model for
 the schedule and the oracle in the tests, and does not ship: it hands
 ``pallas_call`` a ``metadata=``, which XLA prints over three lines of
 the compiled text, and ``benchmark/scopes.py`` reads an instruction as
 one line (PERF.md, section 7).
 
-*The walk.*  A kernel's grid is ``(B, G, pairs)``: the pairs in the
+*The walk.*  A kernel's grid is ``(B, G, pairs)`` (``G / 2`` for heads
+of 64, below): the pairs in the
 order of the tile that accumulates (the query tile for the forward and
 ``dq`` kernels, the key tile for ``dk``/``dv``), handed over by scalar
 prefetch, so that a block's index map reads both tiles' indices from
@@ -25,7 +33,11 @@ SMEM and no step is spent on a pair without work.  The accumulators
 live in VMEM scratch, are cleared at a tile's first pair and written
 out at its last.  The query heads of a group are walked inside a step
 against the one key/value tile the step fetched, so a partial pair's
-mask is computed once for all of them.
+mask is computed once for all of them.  Heads of 64 go two key/value
+heads to a block (a block's last axis is whole 128-lane vectors) with
+the query heads of both, and a head is a 64-lane slice of its block:
+half of the MXU idles either way, but nothing is zero-filled or laid
+out anew around the kernels (PERF.md, findings of PR 33).
 
 *The arithmetic* is ``models.block_diffusion._tiled``'s: f32 scores
 scaled after the product, f32 softmax, the products in the operands'
@@ -35,7 +47,7 @@ type with f32 accumulation, a masked score ``mask_value``.
 from __future__ import annotations
 
 import functools
-from typing import Callable, NamedTuple
+from typing import Callable, NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
@@ -81,10 +93,13 @@ def pair_schedule(work: np.ndarray, block_q: int, block_kv: int):
 
 
 def supports(head_dim: int, dtype, positions: int, block_q: int,
-             block_kv: int) -> bool:
-    """Shapes the kernels take: whole 128-lane heads, blocks of whole
-    vector tiles that divide the positions, operands the MXU takes."""
-    return (head_dim % _LANES == 0
+             block_kv: int, kv_heads: int) -> bool:
+    """Shapes the kernels take: heads of whole 128-lane vectors, or of
+    half of one where the key/value heads pair up into whole ones;
+    blocks of whole vector tiles that divide the positions; operands
+    the MXU takes."""
+    return ((head_dim % _LANES == 0
+             or head_dim == _LANES // 2 and kv_heads % 2 == 0)
             and jnp.dtype(dtype) in (jnp.bfloat16, jnp.float32)
             and block_q % _LANES == 0 and block_kv % _LANES == 0
             and positions % block_q == 0 and positions % block_kv == 0)
@@ -92,17 +107,32 @@ def supports(head_dim: int, dtype, positions: int, block_q: int,
 
 def _lanes(x, width):
     """``[rows, 128]`` of equal lanes -> ``[rows, width]``."""
-    return x if width == _LANES else jnp.tile(x, (1, width // _LANES))
+    if width <= _LANES:
+        return x[:, :width]
+    return jnp.tile(x, (1, width // _LANES))
 
 
-def _walk(table_ref, own: int):
+def _optional(refs, live: bool, ids: bool):
+    """A kernel's references after the table: ``live``'s and the two
+    of the ids where the call has them, then the rest."""
+    refs = list(refs)
+    live_ref = refs.pop(0) if live else None
+    id_refs = (refs.pop(0), refs.pop(0)) if ids else ()
+    return live_ref, id_refs, refs
+
+
+def _walk(table_ref, live_ref, own: int):
     """This step's pair, and whether it is the first and the last of
-    the tile that accumulates (row ``own`` of the table)."""
+    the tile that accumulates (row ``own`` of the table).  A pair this
+    row need not run is of no kind."""
     p, n = pl.program_id(2), pl.num_programs(2)
     tile = table_ref[own, p]
     first = (p == 0) | (table_ref[own, jnp.maximum(p - 1, 0)] != tile)
     last = (p == n - 1) | (table_ref[own, jnp.minimum(p + 1, n - 1)] != tile)
-    return table_ref[0, p], table_ref[1, p], table_ref[2, p], first, last
+    kind = table_ref[2, p]
+    if live_ref is not None:
+        kind = jnp.where(live_ref[pl.program_id(0), p] != 0, kind, 0)
+    return table_ref[0, p], table_ref[1, p], kind, first, last
 
 
 def _by_kind(kind, pair):
@@ -111,11 +141,26 @@ def _by_kind(kind, pair):
     pl.when(kind == PARTIAL)(functools.partial(pair, True))
 
 
-def _seen_tile(seen, first_query, first_key, shape, query_axis: int):
-    """The mask of a tile, queries along ``query_axis``."""
+def _seen_tile(seen, first_query, first_key, shape, query_axis: int,
+               id_refs):
+    """The mask of a tile, queries along ``query_axis``; the ids, where
+    there are any, a column of the queries' against a row of the keys'
+    or the other way round."""
     return seen(
         first_query + lax.broadcasted_iota(jnp.int32, shape, query_axis),
-        first_key + lax.broadcasted_iota(jnp.int32, shape, 1 - query_axis))
+        first_key + lax.broadcasted_iota(jnp.int32, shape, 1 - query_axis),
+        *(ref[...] for ref in id_refs))
+
+
+def _heads(k_ref, v_ref, heads, group, hd):
+    """For every query head of a block: its index, its columns in a
+    wide block, its key/value head's in a narrow one, and that head's
+    keys and values, loaded once a pair."""
+    for r in range(heads):
+        own = slice(r // group * hd, (r // group + 1) * hd)
+        if r % group == 0:
+            k, v = k_ref[:, own], v_ref[:, own]
+        yield r, slice(r * hd, (r + 1) * hd), own, k, v
 
 
 def _scores(a, b, scale, keep, mask_value):
@@ -124,13 +169,15 @@ def _scores(a, b, scale, keep, mask_value):
     return s if keep is None else jnp.where(keep, s, mask_value)
 
 
-def _fwd_kernel(table_ref, q_ref, k_ref, v_ref, out_ref, lse_ref, *rest,
-                heads, hd, scale, seen, mask_value):
+def _fwd_kernel(table_ref, *refs, heads, group, hd, scale, seen, mask_value,
+                live, ids):
+    live_ref, id_refs, (q_ref, k_ref, v_ref, out_ref, lse_ref, *rest) = (
+        _optional(refs, live, ids))
     # an operand narrower than f32 brings one more result: ``out``
     # before it is rounded
     *exact_ref, m_scr, l_scr, acc_scr = rest
     bq, bkv = q_ref.shape[0], k_ref.shape[0]
-    qi, kj, kind, first, last = _walk(table_ref, 0)
+    qi, kj, kind, first, last = _walk(table_ref, live_ref, 0)
 
     @pl.when(first)
     def _():
@@ -139,11 +186,9 @@ def _fwd_kernel(table_ref, q_ref, k_ref, v_ref, out_ref, lse_ref, *rest,
         acc_scr[...] = jnp.zeros_like(acc_scr)
 
     def pair(masked):
-        k, v = k_ref[...], v_ref[...]
-        keep = _seen_tile(seen, qi * bq, kj * bkv, (bq, bkv), 0) \
+        keep = _seen_tile(seen, qi * bq, kj * bkv, (bq, bkv), 0, id_refs) \
             if masked else None
-        for r in range(heads):
-            cols = slice(r * hd, (r + 1) * hd)
+        for r, cols, _, k, v in _heads(k_ref, v_ref, heads, group, hd):
             s = _scores(q_ref[:, cols], k, scale, keep, mask_value)
             m_old = m_scr[r]
             m_new = jnp.maximum(m_old, s.max(axis=-1, keepdims=True))
@@ -168,21 +213,21 @@ def _fwd_kernel(table_ref, q_ref, k_ref, v_ref, out_ref, lse_ref, *rest,
             lse_ref[:, r:r + 1] = (m_scr[r] + jnp.log(l))[:, :1]
 
 
-def _dq_kernel(table_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
-               dq_ref, dq_scr, *, heads, hd, scale, seen, mask_value):
+def _dq_kernel(table_ref, *refs, heads, group, hd, scale, seen, mask_value,
+               live, ids):
+    live_ref, id_refs, (q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
+                        dq_ref, dq_scr) = _optional(refs, live, ids)
     bq, bkv = q_ref.shape[0], k_ref.shape[0]
-    qi, kj, kind, first, last = _walk(table_ref, 0)
+    qi, kj, kind, first, last = _walk(table_ref, live_ref, 0)
 
     @pl.when(first)
     def _():
         dq_scr[...] = jnp.zeros_like(dq_scr)
 
     def pair(masked):
-        k, v = k_ref[...], v_ref[...]
-        keep = _seen_tile(seen, qi * bq, kj * bkv, (bq, bkv), 0) \
+        keep = _seen_tile(seen, qi * bq, kj * bkv, (bq, bkv), 0, id_refs) \
             if masked else None
-        for r in range(heads):
-            cols = slice(r * hd, (r + 1) * hd)
+        for r, cols, _, k, v in _heads(k_ref, v_ref, heads, group, hd):
             q = q_ref[:, cols]
             p = jnp.exp(_scores(q, k, scale, keep, mask_value)
                         - lse_ref[:, r:r + 1])
@@ -199,14 +244,16 @@ def _dq_kernel(table_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         dq_ref[...] = dq_scr[...].astype(dq_ref.dtype)
 
 
-def _dkv_kernel(table_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
-                dk_ref, dv_ref, dk_scr, dv_scr, *, heads, hd, scale, seen,
-                mask_value):
+def _dkv_kernel(table_ref, *refs, heads, group, hd, scale, seen, mask_value,
+                live, ids):
     """Scores transposed, ``[keys, queries]``: every product is then a
-    plain or a last-axes one, and ``lse`` and ``delta`` lie along the
-    lanes."""
+    plain or a last-axes one, and ``lse``, ``delta`` and the queries'
+    ids lie along the lanes."""
+    live_ref, id_refs, (q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
+                        dk_ref, dv_ref, dk_scr, dv_scr) = _optional(
+                            refs, live, ids)
     bq, bkv = q_ref.shape[0], k_ref.shape[0]
-    qi, kj, kind, first, last = _walk(table_ref, 1)
+    qi, kj, kind, first, last = _walk(table_ref, live_ref, 1)
 
     @pl.when(first)
     def _():
@@ -214,20 +261,19 @@ def _dkv_kernel(table_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         dv_scr[...] = jnp.zeros_like(dv_scr)
 
     def pair(masked):
-        k, v = k_ref[...], v_ref[...]
-        keep = _seen_tile(seen, qi * bq, kj * bkv, (bkv, bq), 1) \
+        keep = _seen_tile(seen, qi * bq, kj * bkv, (bkv, bq), 1, id_refs) \
             if masked else None
-        for r in range(heads):
-            cols = slice(r * hd, (r + 1) * hd)
+        for r, cols, own, k, v in _heads(k_ref, v_ref, heads, group, hd):
             q, do = q_ref[:, cols], do_ref[:, cols]
             p = jnp.exp(_scores(k, q, scale, keep, mask_value)
                         - lse_ref[r:r + 1, :])
-            dv_scr[...] += jnp.dot(p.astype(do.dtype), do,
-                                   preferred_element_type=jnp.float32)
+            dv_scr[:, own] += jnp.dot(p.astype(do.dtype), do,
+                                      preferred_element_type=jnp.float32)
             dp = lax.dot_general(v, do, _NT,
                                  preferred_element_type=jnp.float32)
             ds = (p * (dp - delta_ref[r:r + 1, :]) * scale).astype(q.dtype)
-            dk_scr[...] += jnp.dot(ds, q, preferred_element_type=jnp.float32)
+            dk_scr[:, own] += jnp.dot(
+                ds, q, preferred_element_type=jnp.float32)
 
     _by_kind(kind, pair)
 
@@ -241,52 +287,91 @@ class _Kernels(NamedTuple):
     """What the three kernels of one attention share."""
     batch: int
     positions: int
-    groups: int
-    heads: int      # query heads of a group
+    groups: int     # blocks of key/value heads: whole 128-lane vectors
+    heads: int      # query heads of a block
+    group: int      # query heads of a key/value head
     hd: int
     schedule: PairSchedule
     static: dict    # scale, mask_value, seen: the kernel bodies' keywords
     interpret: bool
+    ids: Optional[jax.Array]
+    live: Optional[jax.Array]
 
     @classmethod
-    def of(cls, q, k, schedule, seen, scale, mask_value, interpret):
+    def of(cls, q, k, schedule, seen, scale, mask_value, interpret, ids,
+           live):
         batch, positions, all_heads, hd = q.shape
-        groups = k.shape[2]
-        return cls(batch, positions, groups, all_heads // groups, hd,
-                   schedule, dict(scale=scale, mask_value=mask_value,
-                                  seen=seen), interpret)
+        groups = k.shape[2] // max(1, _LANES // hd)
+        return cls(batch, positions, groups, all_heads // groups,
+                   all_heads // k.shape[2], hd, schedule,
+                   dict(scale=scale, mask_value=mask_value, seen=seen),
+                   interpret, ids, live)
+
+    @property
+    def kv_lanes(self) -> int:
+        """The key/value heads of a block, side by side."""
+        return self.hd * self.heads // self.group
 
     def operand(self, kind: str):
         """An operand's shape and how its blocks are cut, by its kind:
-        ``wide`` (a group's query heads of a query tile), ``narrow`` (the
-        group's key/value head of a key tile), ``column`` and ``row`` (a
+        ``wide`` (a block's query heads of a query tile), ``narrow`` (its
+        key/value heads of a key tile), ``column`` and ``row`` (a
         query tile's f32 statistics down the sublanes or along the
-        lanes).  An index map reads the pair's tiles from the table."""
+        lanes), ``query_ids`` and ``key_ids`` down the sublanes or, with
+        ``_row``, along the lanes.  An index map reads the pair's tiles
+        from the table, the first of what is prefetched."""
         bq, bkv = self.schedule.block_q, self.schedule.block_kv
-        b, p, g, h, hd = self[:5]
+        b, p, g, h = self[:4]
+        hd, kv = self.hd, self.kv_lanes
         return {
             "wide": ((b, p, g * h * hd), pl.BlockSpec(
-                (None, bq, h * hd), lambda b, g, i, t: (b, t[0, i], g))),
-            "narrow": ((b, p, g * hd), pl.BlockSpec(
-                (None, bkv, hd), lambda b, g, i, t: (b, t[1, i], g))),
+                (None, bq, h * hd), lambda b, g, i, t, *_: (b, t[0, i], g))),
+            "narrow": ((b, p, g * kv), pl.BlockSpec(
+                (None, bkv, kv), lambda b, g, i, t, *_: (b, t[1, i], g))),
             "column": ((b, g, p, h), pl.BlockSpec(
-                (None, None, bq, h), lambda b, g, i, t: (b, g, t[0, i], 0))),
+                (None, None, bq, h),
+                lambda b, g, i, t, *_: (b, g, t[0, i], 0))),
             "row": ((b, g, h, p), pl.BlockSpec(
-                (None, None, h, bq), lambda b, g, i, t: (b, g, 0, t[0, i]))),
+                (None, None, h, bq),
+                lambda b, g, i, t, *_: (b, g, 0, t[0, i]))),
+            "query_ids": ((b, p, 1), pl.BlockSpec(
+                (None, bq, 1), lambda b, g, i, t, *_: (b, t[0, i], 0))),
+            "key_ids": ((b, p, 1), pl.BlockSpec(
+                (None, bkv, 1), lambda b, g, i, t, *_: (b, t[1, i], 0))),
+            "query_ids_row": ((b, 1, p), pl.BlockSpec(
+                (None, 1, bq), lambda b, g, i, t, *_: (b, 0, t[0, i]))),
+            "key_ids_row": ((b, 1, p), pl.BlockSpec(
+                (None, 1, bkv), lambda b, g, i, t, *_: (b, 0, t[1, i]))),
         }[kind]
 
-    def call(self, body, name, table, ins, outs, scratch, *operands):
-        """Run ``body`` over the pairs of ``table``; ``ins`` the kinds of
-        ``operands``, ``outs`` the results' ``(kind, dtype)``."""
+    def call(self, body, name, own, ins, outs, scratch, *operands):
+        """Run ``body`` over the pairs in the order of the tile that
+        accumulates (``own``: 0 the query tile, 1 the key tile); ``ins``
+        the kinds of ``operands``, ``outs`` the results' ``(kind,
+        dtype)``.  ``live``, where there is one, is prefetched beside the
+        table in the table's order; the ids go in before ``operands``,
+        the accumulating tile's down the sublanes."""
+        table = (self.schedule.by_query, self.schedule.by_key)[own]
+        prefetch = [jnp.asarray(table)]
+        if self.live is not None:
+            prefetch.append(self.live.reshape(self.batch, -1)[
+                :, table[0] * self.live.shape[2] + table[1]])
+        if self.ids is not None:
+            id_kinds = (("query_ids", "key_ids_row"),
+                        ("query_ids_row", "key_ids"))[own]
+            ins = id_kinds + ins
+            operands = tuple(self.ids.reshape(self.operand(kind)[0])
+                             for kind in id_kinds) + operands
         # no ``metadata=``: it would break the instruction over three
         # lines of the compiled text, where the benchmark's scopes cannot
         # follow
         return pl.pallas_call(
-            functools.partial(body, heads=self.heads, hd=self.hd,
-                              **self.static),
+            functools.partial(body, heads=self.heads, group=self.group,
+                              hd=self.hd, live=self.live is not None,
+                              ids=self.ids is not None, **self.static),
             name=name,
             grid_spec=pltpu.PrefetchScalarGridSpec(
-                num_scalar_prefetch=1,
+                num_scalar_prefetch=len(prefetch),
                 grid=(self.batch, self.groups, self.schedule.pairs),
                 in_specs=[self.operand(kind)[1] for kind in ins],
                 out_specs=[self.operand(kind)[1] for kind, _ in outs],
@@ -297,7 +382,7 @@ class _Kernels(NamedTuple):
             compiler_params=pltpu.CompilerParams(
                 dimension_semantics=("parallel", "parallel", "arbitrary"),
                 vmem_limit_bytes=_VMEM_LIMIT),
-        )(jnp.asarray(table), *operands)
+        )(*prefetch, *operands)
 
 
 def _flat(x):
@@ -305,17 +390,28 @@ def _flat(x):
 
 
 def forward(q, k, v, schedule: PairSchedule, seen: Callable, *,
-            scale: float, mask_value: float, interpret: bool = False):
+            scale: float, mask_value: float, interpret: bool = False,
+            ids: Optional[jax.Array] = None,
+            live: Optional[jax.Array] = None):
     """``(out, lse, exact)``: ``out`` like ``q``; every row's
-    log-sum-exp, f32 ``[B, G, P, query heads of a group]``; and ``out``
+    log-sum-exp, f32 ``[B, blocks of key/value heads, P, query heads of
+    a block]`` (a block is one key/value head of 128 or two of 64), which
+    only ``backward`` reads; and ``out``
     in f32 as it was before it was rounded to ``q``'s type (``out``
-    itself where that is f32), which is what ``backward`` wants."""
-    ks = _Kernels.of(q, k, schedule, seen, scale, mask_value, interpret)
+    itself where that is f32), which is what ``backward`` wants.
+
+    ``ids`` int32 ``[B, P]``: ``seen`` then takes the queries' and the
+    keys' ids after their positions.  ``live`` int32 ``[B, query tiles,
+    key tiles]``: 0 where row ``b`` need not run a pair of the schedule
+    because ``seen`` is false all over it; every query tile keeps a pair
+    (one with none would divide by a sum of nothing)."""
+    ks = _Kernels.of(q, k, schedule, seen, scale, mask_value, interpret,
+                     ids, live)
     f32, bq = jnp.float32, schedule.block_q
     outs = (("wide", q.dtype), ("column", f32)) + (
         () if q.dtype == f32 else (("wide", f32),))
     out, lse, *exact = ks.call(
-        _fwd_kernel, "hvtpu_flash_attention_fwd", schedule.by_query,
+        _fwd_kernel, "hvtpu_flash_attention_fwd", 0,
         ("wide", "narrow", "narrow"), outs,
         [pltpu.VMEM((ks.heads, bq, _LANES), f32),
          pltpu.VMEM((ks.heads, bq, _LANES), f32),
@@ -327,10 +423,12 @@ def forward(q, k, v, schedule: PairSchedule, seen: Callable, *,
 
 def backward(q, k, v, out, lse, d_out, schedule: PairSchedule,
              seen: Callable, *, scale: float, mask_value: float,
-             interpret: bool = False):
+             interpret: bool = False, ids: Optional[jax.Array] = None,
+             live: Optional[jax.Array] = None):
     """``dq``, ``dk``, ``dv`` in the operands' types, from ``forward``'s
-    ``exact`` (as ``out``) and ``lse``.  ``delta``, a row's ``sum(d_out
-    * out)``, is an XLA reduction: one pass over two arrays.  It takes
+    ``exact`` (as ``out``) and ``lse``, under the same ``ids`` and
+    ``live``.  ``delta``, a row's ``sum(d_out * out)``, is an XLA
+    reduction: one pass over two arrays.  It takes
     ``out`` before its rounding because ``dp - delta`` cancels: in a
     row with one dominant key nearly all of ``dp`` goes, and what
     ``out``'s rounding to bf16 adds to ``delta`` stays (15 % further
@@ -338,20 +436,21 @@ def backward(q, k, v, out, lse, d_out, schedule: PairSchedule,
     findings of PR 28).  XLA gives its own tiles the same: it drops a
     rounding between two of its own fusions (excess precision), which
     it cannot do across a kernel's boundary."""
-    ks = _Kernels.of(q, k, schedule, seen, scale, mask_value, interpret)
+    ks = _Kernels.of(q, k, schedule, seen, scale, mask_value, interpret,
+                     ids, live)
     f32 = jnp.float32
     delta = jnp.sum(d_out.astype(f32) * out.astype(f32), axis=-1).reshape(
         ks.batch, ks.positions, ks.groups, ks.heads).transpose(0, 2, 1, 3)
     operands = (_flat(q), _flat(k), _flat(v), _flat(d_out))
     ins = ("wide", "narrow", "narrow", "wide")
     dq, = ks.call(
-        _dq_kernel, "hvtpu_flash_attention_dq", schedule.by_query,
+        _dq_kernel, "hvtpu_flash_attention_dq", 0,
         ins + ("column", "column"), (("wide", q.dtype),),
         [pltpu.VMEM((schedule.block_q, ks.heads * ks.hd), f32)],
         *operands, lse, delta)
     dk, dv = ks.call(
-        _dkv_kernel, "hvtpu_flash_attention_dkv", schedule.by_key,
+        _dkv_kernel, "hvtpu_flash_attention_dkv", 1,
         ins + ("row", "row"), (("narrow", k.dtype), ("narrow", v.dtype)),
-        [pltpu.VMEM((schedule.block_kv, ks.hd), f32)] * 2,
+        [pltpu.VMEM((schedule.block_kv, ks.kv_lanes), f32)] * 2,
         *operands, lse.swapaxes(2, 3), delta.swapaxes(2, 3))
     return dq.reshape(q.shape), dk.reshape(k.shape), dv.reshape(v.shape)

@@ -1,9 +1,11 @@
 """The Pallas kernels behind ``models.block_diffusion.tiled_attention``
+and ``models.hybrid_ssm.causal_document_attention``
 (``ops/flash_attention.py``) on the CPU, under the Pallas interpreter:
 against the XLA tiles they replace, against a dense masked softmax and
 against the library's splash-attention kernel with the same computed
-mask; their schedule against ``allowed`` itself; which path runs; and
-how the kernels appear in a program lowered for a TPU."""
+mask; their schedule against ``allowed`` itself; with a mask that is
+data (document ids) and heads of 64; which path runs; and how the
+kernels appear in a program lowered for a TPU."""
 
 import re
 
@@ -13,6 +15,7 @@ import numpy as np
 import pytest
 
 from horovod_tpu.models import block_diffusion as bd
+from horovod_tpu.models import hybrid_ssm as hs
 from horovod_tpu.obs import metrics
 from horovod_tpu.ops import flash_attention, pallas_ops
 
@@ -255,7 +258,7 @@ def test_the_cells_schedule():
 
 
 @pytest.mark.parametrize("seq_len, head_dim, why", [
-    (256, 64, "half a vector's lanes a head"),
+    (256, 32, "a quarter of a vector's lanes a head"),
     (192, 128, "a half no block divides"),
     (136, 128, "not whole blocks of 128")])
 def test_other_shapes_take_the_xla_path(interpreted, monkeypatch, seq_len,
@@ -270,6 +273,178 @@ def test_other_shapes_take_the_xla_path(interpreted, monkeypatch, seq_len,
         *a, block_length=4, tile=128))(q, k, v)
     assert (calls("pallas"), calls("xla")) == (before[0], before[1] + 1), why
     assert distance(out, dense_attention(q, k, v, seq_len, 4)) < 1e-5
+
+
+def test_heads_of_64_take_the_kernels_two_to_a_block(interpreted,
+                                                    monkeypatch):
+    """Heads of half a vector's lanes, which ``supports`` used to
+    refuse: two key/value heads share a 128-lane block."""
+    blocks(monkeypatch, 128, 128)
+    seq_len = 128
+    ks = jax.random.split(jax.random.PRNGKey(2), 4)
+    q, target = (jax.random.normal(k, (2, 2 * seq_len, 4, 64))
+                 for k in ks[:2])
+    k, v = (jax.random.normal(k_, (2, 2 * seq_len, 2, 64)) for k_ in ks[2:])
+    before = calls("pallas"), calls("xla")
+    got = with_gradients(lambda *a: bd.tiled_attention(
+        *a, block_length=4, tile=128), q, k, v, target)
+    assert (calls("pallas"), calls("xla")) == (before[0] + 1, before[1])
+    dense = with_gradients(
+        lambda *a: dense_attention(*a, seq_len, 4), q, k, v, target)
+    for name, g, d in zip(("out", "dq", "dk", "dv"), got, dense):
+        assert distance(g, d) < 1e-5, name
+    # an odd number of key/value heads of 64 fills no whole block
+    assert not flash_attention.supports(64, jnp.float32, 256, 128, 128, 3)
+    assert flash_attention.supports(64, jnp.float32, 256, 128, 128, 8)
+    assert flash_attention.supports(128, jnp.float32, 256, 128, 128, 3)
+
+
+# -- a mask that is data: models.hybrid_ssm.causal_document_attention -------
+
+# where the documents after the first start, a row each; blocks of 128
+PACKINGS = {
+    "a boundary on a block's edge": [[128, 384], [256]],
+    "boundaries inside blocks": [[100, 300], [7, 450]],
+    "one document a row": [[], []],
+    "a document of 16 tokens": [[200, 216], [496]],
+}
+
+
+def segments(boundaries, positions):
+    segment = np.zeros((len(boundaries), positions), np.int32)
+    for row, starts in zip(segment, boundaries):
+        for start in starts:
+            row[start:] += 1
+    return segment
+
+
+def document_operands(head_dim, group, positions=512, kv_heads=2, seed=11):
+    ks = jax.random.split(jax.random.PRNGKey(seed), 4)
+    shape = (2, positions, group * kv_heads, head_dim)
+    kv = (2, positions, kv_heads, head_dim)
+    return (jax.random.normal(ks[0], shape), jax.random.normal(ks[1], kv),
+            jax.random.normal(ks[2], kv), jax.random.normal(ks[3], shape))
+
+
+@pytest.mark.parametrize("skipping", [True, False],
+                         ids=["pairs skipped", "every pair run"])
+@pytest.mark.parametrize("packing", sorted(PACKINGS))
+@pytest.mark.parametrize("group", [1, 4])
+@pytest.mark.parametrize("head_dim", [64, 128])
+def test_with_document_ids_the_kernels_equal_the_xla_tiles(
+        interpreted, monkeypatch, head_dim, group, packing, skipping):
+    """``causal_document_attention`` through the kernels (the document
+    ids their mask's data, the pairs without a common document skipped
+    or not) against its own XLA tiles: ``out`` and every gradient."""
+    monkeypatch.setattr(hs, "_FLASH_BLOCK_Q", 128)
+    monkeypatch.setattr(hs, "_FLASH_BLOCK_KV", 128)
+    if not skipping:
+        live = hs.live_pairs
+        monkeypatch.setattr(
+            hs, "live_pairs", lambda *a: jnp.ones_like(live(*a)))
+    segment = jnp.asarray(segments(PACKINGS[packing], 512))
+    q, k, v, target = document_operands(head_dim, group)
+
+    def layer(q, k, v):
+        return hs.causal_document_attention(
+            q, k, v, segment, scale=0.125, tile=128)
+
+    before = calls("pallas"), calls("xla")
+    got = with_gradients(layer, q, k, v, target)
+    assert (calls("pallas"), calls("xla")) == (before[0] + 1, before[1])
+    monkeypatch.setenv("HVTPU_PALLAS", "0")
+    in_xla = with_gradients(lambda *a: layer(*a), q, k, v, target)
+    assert (calls("pallas"), calls("xla")) == (before[0] + 1, before[1] + 1)
+    for name, g, x in zip(("out", "dq", "dk", "dv"), got, in_xla):
+        assert distance(g, x) < 1e-5, name
+
+
+def test_in_bf16_the_document_kernels_differ_by_their_products_rounding(
+        interpreted, monkeypatch):
+    """As for the block-diffusion mask: heads of 64 in bf16 against the
+    f32 XLA tiles on the same operands, widened."""
+    monkeypatch.setattr(hs, "_FLASH_BLOCK_Q", 128)
+    monkeypatch.setattr(hs, "_FLASH_BLOCK_KV", 128)
+    segment = jnp.asarray(segments(PACKINGS["boundaries inside blocks"], 512))
+    q, k, v, target = document_operands(64, 4, seed=13)
+    q, k, v = (a.astype(jnp.bfloat16) for a in (q, k, v))
+
+    def layer(q, k, v):
+        return hs.causal_document_attention(
+            q, k, v, segment, scale=0.125, tile=128)
+
+    got = with_gradients(layer, q, k, v, target)
+    assert all(g.dtype == jnp.bfloat16 for g in got)
+    monkeypatch.setenv("HVTPU_PALLAS", "0")
+    want = with_gradients(lambda *a: layer(*a), *(
+        a.astype(jnp.float32) for a in (q, k, v)), target)
+    for name, g, w in zip(("out", "dq", "dk", "dv"), got, want):
+        assert distance(g, w) < 6e-3, name
+
+
+@pytest.mark.parametrize("packing", sorted(PACKINGS))
+def test_a_pair_is_skipped_only_where_no_query_sees_a_key(packing):
+    """``live_pairs`` against the mask itself, block pair by block pair
+    of the causal triangle: it may keep a pair that holds nothing (it
+    knows the ranges of the ids, not the ids), never drop one that
+    holds something; with documents in order it is exact."""
+    segment = segments(PACKINGS[packing], 512)
+    live = hs.live_pairs(segment, 128, 128)
+    assert live.shape == (2, 4, 4) and live.dtype == np.int32
+    pos = np.arange(512)
+    seen = np.asarray(hs._seen(pos[None, :, None], pos[None, None, :],
+                               segment[:, :, None], segment[:, None, :]))
+    holds = seen.reshape(2, 4, 128, 4, 128).any(axis=(2, 4))
+    schedule = hs._flash_schedule(512, 128, 128)
+    assert schedule.pairs == 10 and np.all(
+        schedule.by_query[2] == flash_attention.PARTIAL)
+    qi, kj = schedule.by_query[:2]
+    assert np.array_equal(live[:, qi, kj] != 0, holds[:, qi, kj])
+    assert not holds[:, np.triu_indices(4, 1)[0],
+                     np.triu_indices(4, 1)[1]].any()
+    # ids in no order: a range that straddles another keeps the pair
+    shuffled = np.where(segment == 0, 5, segment)
+    kept = hs.live_pairs(shuffled, 128, 128)
+    same = (shuffled[:, :, None] == shuffled[:, None, :]).reshape(
+        2, 4, 128, 4, 128).any(axis=(2, 4))
+    assert np.all(kept[same] == 1)
+
+
+def test_the_hosts_loop_counts_the_pairs_run_and_skipped(monkeypatch):
+    monkeypatch.setattr(hs, "_FLASH_BLOCK_Q", 128)
+    monkeypatch.setattr(hs, "_FLASH_BLOCK_KV", 128)
+    pairs = metrics.REGISTRY.counter("hvtpu_attention_pairs_total")
+    before = pairs.value(kind="run"), pairs.value(kind="skipped")
+    hs.note_attention_pairs(segments([[128, 384], []], 512))
+    # row 0: documents of blocks {0}, {1, 2}, {3}: 1 + 3 + 1 pairs;
+    # row 1: one document, the whole triangle of 10
+    assert pairs.value(kind="run") - before[0] == 5 + 10
+    assert pairs.value(kind="skipped") - before[1] == 5
+
+
+def test_without_ids_the_kernels_are_built_as_if_there_were_none(
+        interpreted):
+    """The transformer cell's call: one prefetched table and the three
+    operands, nothing for ids or for pairs to skip."""
+    seq_len = 128
+    schedule = flash_attention.pair_schedule(
+        bd.tile_work(seq_len, 4, 128, 128), 128, 128)
+    q, k, v, _ = operands(seq_len, 2, 1)
+
+    def call_of(**data):
+        jaxpr = jax.make_jaxpr(lambda q, k, v: flash_attention.forward(
+            q, k, v, schedule,
+            (lambda a, b, *ids: bd.allowed(a, b, seq_len, 4)),
+            scale=HD ** -0.5, mask_value=bd._MASKED, interpret=True,
+            **data))(q, k, v)
+        call, = (e for e in jaxpr.eqns if e.primitive.name == "pallas_call")
+        return (call.params["grid_mapping"].num_index_operands,
+                len(call.invars))
+
+    assert call_of() == (1, 4)
+    ids = jnp.zeros(q.shape[:2], jnp.int32)
+    assert call_of(ids=ids) == (1, 6)
+    assert call_of(ids=ids, live=jnp.ones((2, 2, 2), jnp.int32)) == (2, 7)
 
 
 def test_without_pallas_the_xla_path_runs(monkeypatch):

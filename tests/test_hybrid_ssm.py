@@ -381,6 +381,69 @@ def test_the_attention_in_tiles_is_the_dense_masked_softmax(tile):
             assert distance(got[row], want) < 1e-6
 
 
+def _tiles_of_pr_32(q, k, v, segment, *, scale, tile):
+    """``causal_document_attention`` as PR 32 left it, line for line:
+    what every backend but a TPU must still run."""
+    from jax import lax
+
+    b, t, heads, hd = q.shape
+    groups = k.shape[2]
+    tile = min(tile, t)
+
+    @jax.checkpoint
+    def rows(q_rows, keys, values, seg_q, seg_k, first):
+        s = jnp.einsum("bqgrd,bkgd->bgrqk", q_rows, keys,
+                       preferred_element_type=jnp.float32) * scale
+        at = first + jnp.arange(q_rows.shape[1])
+        seen = ((jnp.arange(keys.shape[1])[None, :] <= at[:, None])[None]
+                & (seg_q[:, :, None] == seg_k[:, None, :]))
+        s = jnp.where(seen[:, None, None], s, -1e30)
+        top = lax.optimization_barrier(
+            lax.stop_gradient(jnp.max(s, axis=-1, keepdims=True)))
+        p = jnp.exp(s - top)
+        p = p / jnp.sum(p, axis=-1, keepdims=True)
+        return jnp.einsum("bgrqk,bkgd->bqgrd", p.astype(values.dtype), values,
+                          preferred_element_type=jnp.float32
+                          ).astype(q_rows.dtype)
+
+    q = q.reshape(b, t, groups, heads // groups, hd)
+    out = [rows(q[:, a:a + tile], k[:, :a + tile], v[:, :a + tile],
+                segment[:, a:a + tile], segment[:, :a + tile], a)
+           for a in range(0, t, tile)]
+    return jnp.concatenate(out, axis=1).reshape(b, t, heads, hd)
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+def test_on_the_cpu_the_attention_is_pr_32s_function_bit_for_bit(
+        monkeypatch, dtype):
+    """Shapes the Pallas kernels would take on a TPU (heads of 64, 1,024
+    positions): off one, the XLA tiles run, counted as such, and give
+    ``out`` and every gradient as they did."""
+    monkeypatch.delenv("HVTPU_PALLAS_INTERPRET", raising=False)
+    monkeypatch.delenv("HVTPU_PALLAS", raising=False)
+    rng = np.random.default_rng(9)
+    q, target = (jnp.asarray(rng.standard_normal((2, 1024, 4, 64)), dtype)
+                 for _ in range(2))
+    k, v = (jnp.asarray(rng.standard_normal((2, 1024, 2, 64)), dtype)
+            for _ in range(2))
+    segment = jnp.asarray(batch_of([[300, 512, 528], [77]], 1024)["segment"])
+
+    def results(attention):
+        def loss(q, k, v):
+            out = attention(q, k, v, segment, scale=0.125, tile=256)
+            return jnp.sum((out * target).astype(jnp.float32)), out
+        grads, out = jax.jit(jax.grad(loss, (0, 1, 2), has_aux=True))(q, k, v)
+        return (out, *grads)
+
+    counter = metrics.REGISTRY.counter("hvtpu_attention_calls_total")
+    before = counter.value(path="xla"), counter.value(path="pallas")
+    got = results(hs.causal_document_attention)
+    assert (counter.value(path="xla"), counter.value(path="pallas")) == (
+        before[0] + 1, before[1])
+    for g, w in zip(got, results(_tiles_of_pr_32)):
+        assert g.dtype == w.dtype and jnp.array_equal(g, w)
+
+
 def test_the_start_is_mamba_2s_own():
     cfg = dataclasses.replace(TOY, ssm_heads=64, ssm_head_dim=2,
                               layer_types=("mamba",) * 4)
