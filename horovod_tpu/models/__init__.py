@@ -32,4 +32,7 @@ __all__ = [
     "ResNet152",
     "VGG", "VGG16", "VGG19",
     "InceptionV3",
+    # a submodule, imported when asked for (``from horovod_tpu.models
+    # import looped``): the other jobs' set-up is imports first
+    "looped",
 ]

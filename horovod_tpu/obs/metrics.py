@@ -781,3 +781,45 @@ def note_packed_batch(segment) -> None:
         "hvtpu_packed_documents_per_row",
         "Documents a row of the last batch noted holds, the mean over "
         "its rows: 1.0 is an unpacked batch.").set(started / seg.shape[0])
+
+
+# ---------------------------------------------------------------------------
+# looped decoders
+# ---------------------------------------------------------------------------
+
+def note_loop_layer_uses(uses: int) -> None:
+    """Count the layer uses of one forward pass of ``models.looped``
+    (layers held x passes over them).  Called while a program is traced,
+    once a call site and a trace, like ``note_attention_path``."""
+    REGISTRY.counter(
+        "hvtpu_loop_layer_uses_total",
+        "Layer uses a forward pass of a looped decoder makes (layers held "
+        "x passes over them), counted when a program is traced.").inc(
+            float(uses))
+
+
+def note_loop_exits(model_state) -> None:
+    """Record what a step's exits saw: ``model_state`` is what
+    ``models.looped.expected_exit_loss`` hands back, the batch's mean
+    exit probability ``loop_exit_mass`` and mean cross-entropy
+    ``loop_exit_loss`` of every exit, f32 ``[passes]`` each.  Call it
+    from the host loop at logging cadence on a state the loop has
+    already fetched, like ``note_moe_routing``; never from inside the
+    step."""
+    import numpy as np
+
+    mass = REGISTRY.gauge(
+        "hvtpu_loop_exit_mass",
+        "Mean probability the exit distribution gave an exit (label exit, "
+        "from 1) over the weighted positions of the last step noted: they "
+        "add up to 1, and a mass that drifts to one exit is a loop that "
+        "stopped using the others.")
+    loss = REGISTRY.gauge(
+        "hvtpu_loop_exit_loss",
+        "Mean next-token cross-entropy of an exit's logits (label exit, "
+        "from 1) over the weighted positions of the last step noted: "
+        "later exits reading lower is what the further passes buy.")
+    for gauge, values in ((mass, model_state["loop_exit_mass"]),
+                          (loss, model_state["loop_exit_loss"])):
+        for i, value in enumerate(np.asarray(values, np.float64)):
+            gauge.set(float(value), exit=str(i + 1))

@@ -326,16 +326,8 @@ def document_operands(head_dim, group, positions=512, kv_heads=2, seed=11):
             jax.random.normal(ks[2], kv), jax.random.normal(ks[3], shape))
 
 
-@pytest.mark.parametrize("skipping", [True, False],
-                         ids=["pairs skipped", "every pair run"])
-@pytest.mark.parametrize("packing", sorted(PACKINGS))
-@pytest.mark.parametrize("group", [1, 4])
-@pytest.mark.parametrize("head_dim", [64, 128])
-def test_with_document_ids_the_kernels_equal_the_xla_tiles(
-        interpreted, monkeypatch, head_dim, group, packing, skipping):
-    """``causal_document_attention`` through the kernels (the document
-    ids their mask's data, the pairs without a common document skipped
-    or not) against its own XLA tiles: ``out`` and every gradient."""
+def kernels_against_xla_tiles(monkeypatch, head_dim, group, packing,
+                              skipping, kv_heads=2):
     monkeypatch.setattr(hs, "_FLASH_BLOCK_Q", 128)
     monkeypatch.setattr(hs, "_FLASH_BLOCK_KV", 128)
     if not skipping:
@@ -343,7 +335,7 @@ def test_with_document_ids_the_kernels_equal_the_xla_tiles(
         monkeypatch.setattr(
             hs, "live_pairs", lambda *a: jnp.ones_like(live(*a)))
     segment = jnp.asarray(segments(PACKINGS[packing], 512))
-    q, k, v, target = document_operands(head_dim, group)
+    q, k, v, target = document_operands(head_dim, group, kv_heads=kv_heads)
 
     def layer(q, k, v):
         return hs.causal_document_attention(
@@ -357,6 +349,30 @@ def test_with_document_ids_the_kernels_equal_the_xla_tiles(
     assert (calls("pallas"), calls("xla")) == (before[0] + 1, before[1] + 1)
     for name, g, x in zip(("out", "dq", "dk", "dv"), got, in_xla):
         assert distance(g, x) < 1e-5, name
+
+
+@pytest.mark.parametrize("skipping", [True, False],
+                         ids=["pairs skipped", "every pair run"])
+@pytest.mark.parametrize("packing", sorted(PACKINGS))
+@pytest.mark.parametrize("group", [1, 4])
+@pytest.mark.parametrize("head_dim", [64, 128])
+def test_with_document_ids_the_kernels_equal_the_xla_tiles(
+        interpreted, monkeypatch, head_dim, group, packing, skipping):
+    """``causal_document_attention`` through the kernels (the document
+    ids their mask's data, the pairs without a common document skipped
+    or not) against its own XLA tiles: ``out`` and every gradient."""
+    kernels_against_xla_tiles(
+        monkeypatch, head_dim, group, packing, skipping)
+
+
+@pytest.mark.parametrize("packing", sorted(PACKINGS))
+def test_sixteen_key_value_heads_of_one_query_head_each(
+        interpreted, monkeypatch, packing):
+    """The looped decoder's shape (``models.looped``: multi-head
+    attention, 16 key/value heads of 128 with one query head a group,
+    document ids): the kernels' grid walks sixteen groups of one."""
+    kernels_against_xla_tiles(
+        monkeypatch, 128, 1, packing, skipping=True, kv_heads=16)
 
 
 def test_in_bf16_the_document_kernels_differ_by_their_products_rounding(

@@ -334,3 +334,56 @@ def test_a_mamba_layers_instructions_lie_under_its_scopes(v5e_2x2):
     assert {by_instruction[name] for name, _ in in_a_layer} == {
         "hvtpu:ssm.proj", "hvtpu:ssm.conv", "hvtpu:ssm.scan",
         "hvtpu:ssm.gate", "hvtpu:mlp"}
+
+
+# -- the looped cell (models/looped.py) ---------------------------------------
+
+LOOPED_CELL = "ouro-2.6b-6of48-t8k-b1"
+
+
+def test_the_looped_cells_step_fits_and_keeps_one_exits_logits(v5e_2x2):
+    """The whole step of ``ouro-2.6b-6of48-t8k-b1`` as ``benchmark/job
+    .py`` builds it, 510 M parameters trained at 12 bytes each, six
+    layers walked four times over 8,192 tokens and the whole vocabulary
+    after every pass, for one described chip.  By the figure the other
+    cells' cases use it needs 11.5 GB (15.0 GB when the label's logit
+    was a ``take_along_axis``, whose gradient is a scatter into 1.6 GB
+    of zeros: PERF.md, findings of PR 34).  An exit's f32 logits,
+    ``[8192, 49152]``, are made and used inside one pass: no loop
+    carries such an array from one pass to the next and none is stacked
+    over the passes, so no more than one exit's are live, forward or
+    backward.  Its attention, 16 key/value heads of one query head each
+    with document ids, runs in the three kernels of
+    ``ops/flash_attention.py`` under ``hvtpu:attention``, the recomputed
+    forward too, none with kernel metadata; no scatter is as large as
+    the logits; and the five scopes the cell's readers join are there."""
+    import re
+
+    from benchmark import cells, scopes
+
+    compiled = _compiled_step(cells.load_cell(LOOPED_CELL), v5e_2x2)
+    m = compiled.memory_analysis()
+    assert (m.argument_size_in_bytes + m.output_size_in_bytes
+            + m.temp_size_in_bytes - m.alias_size_in_bytes) <= 14e9
+    text = compiled.as_text()
+    logits = re.findall(
+        r"^\s*(?:ROOT\s+)?%(\S+) = (\S*f32\[(?:1,)?8192,49152\]\S*) "
+        r"([\w-]+)\(", text, re.MULTILINE)
+    assert logits
+    loops = re.findall(r"^\s*(?:ROOT\s+)?%\S+ = (\(.*?\)) while\(", text,
+                       re.MULTILINE)
+    assert len(loops) >= 4              # passes and layers, there and back
+    assert not any("8192,49152]" in carried for carried in loops)
+    assert not re.search(r"\[4,(?:1,)?8192,49152\]", text)
+    assert not any(op == "scatter" for _, _, op in logits)
+    assert _attention_kernels_by_scope(text) == {
+        kernel: {"hvtpu:attention"} for kernel in ATTENTION_KERNELS}
+    kernels = re.findall(
+        r"^\s*(?:ROOT\s+)?%hvtpu_flash_attention_\w+(?:\.\d+)? = .*$", text,
+        re.MULTILINE)
+    assert len(kernels) == 4            # forward, recomputed, dq, dk/dv
+    assert not any("kernel_metadata" in line.replace(
+        "kernel_metadata={}", "") for line in kernels)
+    assert {"hvtpu:loop.proj", "hvtpu:loop.mlp", "hvtpu:loop.exit",
+            "hvtpu:attention", "hvtpu:lm_head"} <= set(
+                scopes.scope_by_instruction(text).values())
