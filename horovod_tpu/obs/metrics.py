@@ -693,6 +693,20 @@ def note_attention_path(path: str) -> None:
         "kernels or XLA tiles.").inc(path=path)
 
 
+def note_attention_block(query_heads: int, kv_heads: int) -> None:
+    """Record the heads one grid step of the attention kernels carries,
+    as ``ops.flash_attention.block_heads`` chose them from the shapes of
+    the last attention built.  Called while a program is traced, like
+    ``note_attention_path``, never from inside the step."""
+    heads = REGISTRY.gauge(
+        "hvtpu_attention_block_heads",
+        "Heads a grid step of the Pallas attention kernels carries in the "
+        "last attention built, by kind: the query heads walked inside a "
+        "step and the key/value heads its blocks hold side by side.")
+    heads.set(float(query_heads), kind="query")
+    heads.set(float(kv_heads), kind="key_value")
+
+
 def note_attention_pairs(run: int, skipped: int) -> None:
     """Count the block pairs the attention kernels ran and skipped on
     the batches noted: a pair whose blocks share no document is decided

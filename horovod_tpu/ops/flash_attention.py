@@ -24,20 +24,40 @@ the schedule and the oracle in the tests, and does not ship: it hands
 the compiled text, and ``benchmark/scopes.py`` reads an instruction as
 one line (PERF.md, section 7).
 
-*The walk.*  A kernel's grid is ``(B, G, pairs)`` (``G / 2`` for heads
-of 64, below): the pairs in the
-order of the tile that accumulates (the query tile for the forward and
-``dq`` kernels, the key tile for ``dk``/``dv``), handed over by scalar
-prefetch, so that a block's index map reads both tiles' indices from
-SMEM and no step is spent on a pair without work.  The accumulators
-live in VMEM scratch, are cleared at a tile's first pair and written
-out at its last.  The query heads of a group are walked inside a step
-against the one key/value tile the step fetched, so a partial pair's
-mask is computed once for all of them.  Heads of 64 go two key/value
-heads to a block (a block's last axis is whole 128-lane vectors) with
-the query heads of both, and a head is a 64-lane slice of its block:
-half of the MXU idles either way, but nothing is zero-filled or laid
-out anew around the kernels (PERF.md, findings of PR 33).
+*The walk.*  A kernel's grid is ``(B, blocks of heads, pairs)``: the
+pairs in the order of the tile that accumulates (the query tile for the
+forward and ``dq`` kernels, the key tile for ``dk``/``dv``), handed
+over by scalar prefetch, so that a block's index map reads both tiles'
+indices from SMEM and no step is spent on a pair without work.  The
+accumulators live in VMEM scratch, are cleared at a tile's first pair
+and written out at its last.
+
+*What a block holds* is chosen from the shapes alone (``block_heads``).
+The query heads of a group are walked inside a step against the one
+key/value tile the step fetched, so a partial pair's mask is computed
+once for all of them.  Heads of 64 go two key/value heads to a block (a
+block's last axis is whole 128-lane vectors) with the query heads of
+both, and a head is a 64-lane slice of its block: half of the MXU idles
+either way, but nothing is zero-filled or laid out anew around the
+kernels (PERF.md, findings of PR 33).  Where a group holds few query
+heads, more key/value heads go side by side in a block, up to eight
+query heads a step: with one query head a group a step would carry one
+head's 512 x 512 pair, 0.7 us of products at a v5e's peak under a
+microsecond of a grid step's bookkeeping and a fetch it cannot hide,
+and eight to a block took the three kernels from 9.97 to 5.67 ms a
+layer use at 16 x 1 x 128 (PERF.md, findings of PR 35).  A head's
+result does not depend on which heads share its block, bit for bit.
+
+*A pair a row skips* (``live``) takes its grid step, since the schedule
+is static, but runs no body and fetches nothing: beside ``live`` the
+step prefetches, a row, the pair whose blocks a step holds
+(``held_pairs``: its own where it is live, else the last live pair the
+walk passed), and the index maps of the operands that change along the
+walk read their tile through it, so the step names the blocks the
+pipeline already has and Pallas issues no copy.  The accumulating
+tile's blocks and the results read the table itself.  What a skipped
+step still costs is its bookkeeping, about a microsecond for the eight
+blocks a step names.
 
 *The arithmetic* is ``models.block_diffusion._tiled``'s: f32 scores
 scaled after the product, f32 softmax, the products in the operands'
@@ -56,7 +76,14 @@ from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from ..obs import metrics
+
 _LANES = 128
+# the most query heads a grid step carries where a block could hold fewer:
+# what the hybrid cell's blocks of two key/value heads of 64 carry.  At
+# 16 x 1 x 128 the three kernels took 9.97 ms a layer use with 1 a step,
+# 7.14 with 2, 6.21 with 4 and 5.67 with 8 (PERF.md, findings of PR 35)
+_BLOCK_QUERY_HEADS = 8
 # a pair's kind in the tables
 PARTIAL, FULL = 1, 2
 
@@ -113,10 +140,13 @@ def _lanes(x, width):
 
 
 def _optional(refs, live: bool, ids: bool):
-    """A kernel's references after the table: ``live``'s and the two
-    of the ids where the call has them, then the rest."""
+    """A kernel's references after the table: ``live``'s (and ``held``'s
+    behind it, which only the index maps read) and the two of the ids
+    where the call has them, then the rest."""
     refs = list(refs)
     live_ref = refs.pop(0) if live else None
+    if live:
+        refs.pop(0)
     id_refs = (refs.pop(0), refs.pop(0)) if ids else ()
     return live_ref, id_refs, refs
 
@@ -283,11 +313,34 @@ def _dkv_kernel(table_ref, *refs, heads, group, hd, scale, seen, mask_value,
         dv_ref[...] = dv_scr[...].astype(dv_ref.dtype)
 
 
+def block_heads(heads: int, kv_heads: int, hd: int):
+    """``(query heads, key/value heads)`` of a block, from the shapes
+    alone: the fewest key/value heads that fill whole 128-lane vectors,
+    and more of them, as many as divide ``kv_heads`` evenly, while their
+    query heads stay at or under ``_BLOCK_QUERY_HEADS``."""
+    group = heads // kv_heads
+    fewest = max(1, _LANES // hd)
+    most = max(fewest, _BLOCK_QUERY_HEADS // group)
+    kv = max(n for n in range(fewest, most + 1, fewest) if kv_heads % n == 0)
+    return kv * group, kv
+
+
+def held_pairs(live):
+    """``live`` ``[B, pairs]`` in a walk's order -> int32 ``[B, pairs]``,
+    the pair whose blocks a step holds: its own where it is live, else
+    the last live pair the walk passed (the first live one before any
+    was), whose blocks the pipeline has already."""
+    at = lax.broadcasted_iota(jnp.int32, live.shape, 1)
+    last = lax.cummax(jnp.where(live != 0, at, -1), axis=1)
+    first = jnp.argmax(live != 0, axis=1).astype(jnp.int32)
+    return jnp.where(last < 0, first[:, None], last)
+
+
 class _Kernels(NamedTuple):
     """What the three kernels of one attention share."""
     batch: int
     positions: int
-    groups: int     # blocks of key/value heads: whole 128-lane vectors
+    groups: int     # blocks of heads (``block_heads``) the heads make
     heads: int      # query heads of a block
     group: int      # query heads of a key/value head
     hd: int
@@ -301,8 +354,8 @@ class _Kernels(NamedTuple):
     def of(cls, q, k, schedule, seen, scale, mask_value, interpret, ids,
            live):
         batch, positions, all_heads, hd = q.shape
-        groups = k.shape[2] // max(1, _LANES // hd)
-        return cls(batch, positions, groups, all_heads // groups,
+        heads, kv_heads = block_heads(all_heads, k.shape[2], hd)
+        return cls(batch, positions, k.shape[2] // kv_heads, heads,
                    all_heads // k.shape[2], hd, schedule,
                    dict(scale=scale, mask_value=mask_value, seen=seen),
                    interpret, ids, live)
@@ -312,36 +365,49 @@ class _Kernels(NamedTuple):
         """The key/value heads of a block, side by side."""
         return self.hd * self.heads // self.group
 
-    def operand(self, kind: str):
+    def operand(self, kind: str, own: int = 0):
         """An operand's shape and how its blocks are cut, by its kind:
         ``wide`` (a block's query heads of a query tile), ``narrow`` (its
         key/value heads of a key tile), ``column`` and ``row`` (a
         query tile's f32 statistics down the sublanes or along the
         lanes), ``query_ids`` and ``key_ids`` down the sublanes or, with
         ``_row``, along the lanes.  An index map reads the pair's tiles
-        from the table, the first of what is prefetched."""
+        from the table, the first of what is prefetched: the tile that
+        accumulates (row ``own``) of the step's own pair, the other, with
+        ``live``, of the pair the step holds (``held_pairs``, the last
+        prefetched), so that a pair a row skips names the blocks the
+        pipeline has and nothing is copied for it."""
         bq, bkv = self.schedule.block_q, self.schedule.block_kv
         b, p, g, h = self[:4]
         hd, kv = self.hd, self.kv_lanes
+
+        def tile(row):
+            through_held = self.live is not None and row != own
+
+            def of(b, i, table, *flags):
+                return table[row, flags[-1][b, i] if through_held else i]
+            return of
+
+        tq, tk = tile(0), tile(1)
         return {
             "wide": ((b, p, g * h * hd), pl.BlockSpec(
-                (None, bq, h * hd), lambda b, g, i, t, *_: (b, t[0, i], g))),
+                (None, bq, h * hd), lambda b, g, i, *t: (b, tq(b, i, *t), g))),
             "narrow": ((b, p, g * kv), pl.BlockSpec(
-                (None, bkv, kv), lambda b, g, i, t, *_: (b, t[1, i], g))),
+                (None, bkv, kv), lambda b, g, i, *t: (b, tk(b, i, *t), g))),
             "column": ((b, g, p, h), pl.BlockSpec(
                 (None, None, bq, h),
-                lambda b, g, i, t, *_: (b, g, t[0, i], 0))),
+                lambda b, g, i, *t: (b, g, tq(b, i, *t), 0))),
             "row": ((b, g, h, p), pl.BlockSpec(
                 (None, None, h, bq),
-                lambda b, g, i, t, *_: (b, g, 0, t[0, i]))),
+                lambda b, g, i, *t: (b, g, 0, tq(b, i, *t)))),
             "query_ids": ((b, p, 1), pl.BlockSpec(
-                (None, bq, 1), lambda b, g, i, t, *_: (b, t[0, i], 0))),
+                (None, bq, 1), lambda b, g, i, *t: (b, tq(b, i, *t), 0))),
             "key_ids": ((b, p, 1), pl.BlockSpec(
-                (None, bkv, 1), lambda b, g, i, t, *_: (b, t[1, i], 0))),
+                (None, bkv, 1), lambda b, g, i, *t: (b, tk(b, i, *t), 0))),
             "query_ids_row": ((b, 1, p), pl.BlockSpec(
-                (None, 1, bq), lambda b, g, i, t, *_: (b, 0, t[0, i]))),
+                (None, 1, bq), lambda b, g, i, *t: (b, 0, tq(b, i, *t)))),
             "key_ids_row": ((b, 1, p), pl.BlockSpec(
-                (None, 1, bkv), lambda b, g, i, t, *_: (b, 0, t[1, i]))),
+                (None, 1, bkv), lambda b, g, i, *t: (b, 0, tk(b, i, *t)))),
         }[kind]
 
     def call(self, body, name, own, ins, outs, scratch, *operands):
@@ -349,13 +415,15 @@ class _Kernels(NamedTuple):
         accumulates (``own``: 0 the query tile, 1 the key tile); ``ins``
         the kinds of ``operands``, ``outs`` the results' ``(kind,
         dtype)``.  ``live``, where there is one, is prefetched beside the
-        table in the table's order; the ids go in before ``operands``,
-        the accumulating tile's down the sublanes."""
+        table in the table's order, and ``held_pairs`` of it; the ids go
+        in before ``operands``, the accumulating tile's down the
+        sublanes."""
         table = (self.schedule.by_query, self.schedule.by_key)[own]
         prefetch = [jnp.asarray(table)]
         if self.live is not None:
-            prefetch.append(self.live.reshape(self.batch, -1)[
-                :, table[0] * self.live.shape[2] + table[1]])
+            live = self.live.reshape(self.batch, -1)[
+                :, table[0] * self.live.shape[2] + table[1]]
+            prefetch += [live, held_pairs(live)]
         if self.ids is not None:
             id_kinds = (("query_ids", "key_ids_row"),
                         ("query_ids_row", "key_ids"))[own]
@@ -373,8 +441,8 @@ class _Kernels(NamedTuple):
             grid_spec=pltpu.PrefetchScalarGridSpec(
                 num_scalar_prefetch=len(prefetch),
                 grid=(self.batch, self.groups, self.schedule.pairs),
-                in_specs=[self.operand(kind)[1] for kind in ins],
-                out_specs=[self.operand(kind)[1] for kind, _ in outs],
+                in_specs=[self.operand(kind, own)[1] for kind in ins],
+                out_specs=[self.operand(kind, own)[1] for kind, _ in outs],
                 scratch_shapes=scratch),
             out_shape=[jax.ShapeDtypeStruct(self.operand(kind)[0], dtype)
                        for kind, dtype in outs],
@@ -394,9 +462,8 @@ def forward(q, k, v, schedule: PairSchedule, seen: Callable, *,
             ids: Optional[jax.Array] = None,
             live: Optional[jax.Array] = None):
     """``(out, lse, exact)``: ``out`` like ``q``; every row's
-    log-sum-exp, f32 ``[B, blocks of key/value heads, P, query heads of
-    a block]`` (a block is one key/value head of 128 or two of 64), which
-    only ``backward`` reads; and ``out``
+    log-sum-exp, f32 ``[B, blocks of heads, P, query heads of a block]``
+    (``block_heads``), which only ``backward`` reads; and ``out``
     in f32 as it was before it was rounded to ``q``'s type (``out``
     itself where that is f32), which is what ``backward`` wants.
 
@@ -407,6 +474,7 @@ def forward(q, k, v, schedule: PairSchedule, seen: Callable, *,
     (one with none would divide by a sum of nothing)."""
     ks = _Kernels.of(q, k, schedule, seen, scale, mask_value, interpret,
                      ids, live)
+    metrics.note_attention_block(ks.heads, ks.heads // ks.group)
     f32, bq = jnp.float32, schedule.block_q
     outs = (("wide", q.dtype), ("column", f32)) + (
         () if q.dtype == f32 else (("wide", f32),))

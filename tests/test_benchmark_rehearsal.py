@@ -8,6 +8,7 @@ program registers or emits, by reading both sides.  A rehearsal is never
 a result: no time it prints is a device number."""
 
 import ast
+import importlib
 import json
 import os
 import re
@@ -17,9 +18,13 @@ import sys
 import pytest
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+sys.path.insert(0, ROOT)            # the readers import ``benchmark``
 RUN = os.path.join(ROOT, "benchmark", "run.py")
 with open(os.path.join(ROOT, "BENCHMARK.json")) as _f:
-    CELLS = [w["name"] for w in json.load(_f)["workloads"]]
+    BENCHMARK = json.load(_f)
+CELLS = [w["name"] for w in BENCHMARK["workloads"]]
+# a per-layer metric -> its entry; the cells it is reported in
+PER_LAYER = {m["name"]: m for m in BENCHMARK["per_layer"]}
 
 
 def parsed(directory, skip=()):
@@ -101,6 +106,19 @@ def test_every_cell_walks_through_the_rehearsal(cell, trace, compile_cache):
     checks = next(line for line in lines if " checks: " in line)
     assert "False" not in checks, checks
     assert "0 compilation(s) in the window" in proc.stdout
+    if trace == "1":
+        # every per-layer metric the cell reports was asked for: read, or
+        # named as one the CPU has nothing to read for (a device trace,
+        # peaks, the allocator: ``loop_attention_ms_per_step`` and the
+        # other scope metrics need a chip)
+        read = next(line for line in lines if "per_layer metrics read: " in line)
+        found, absent = (set(ast.literal_eval(names))
+                         for names in re.findall(r"\[.*?\]", read))
+        assert found | absent == {
+            name for name, entry in PER_LAYER.items()
+            if cell in entry.get("workloads", CELLS)}
+        assert not found & {name for name, entry in PER_LAYER.items()
+                            if entry["source"] == "device_trace"}
 
 
 def test_off_a_tpu_nothing_is_measured(compile_cache):
@@ -109,6 +127,19 @@ def test_off_a_tpu_nothing_is_measured(compile_cache):
     assert proc.returncode != 0
     assert "Nothing was measured" in proc.stderr
     assert not any(line.startswith("{") for line in proc.stdout.splitlines())
+
+
+@pytest.mark.parametrize("name", sorted(PER_LAYER))
+def test_a_per_layer_metric_has_the_reader_its_entry_names(name):
+    """``benchmark/layer_metrics/<name>.py`` is found by the entry's
+    name and says of itself what the entry says: its layer, its unit and
+    the end-to-end metric it moves."""
+    reader = importlib.import_module(f"benchmark.layer_metrics.{name}")
+    entry = PER_LAYER[name]
+    assert callable(reader.read)
+    assert (reader.LAYER, reader.UNIT, reader.MOVES) == (
+        entry["layer"], entry["unit"], entry["moves"])
+    assert set(entry.get("workloads", CELLS)) <= set(CELLS)
 
 
 @pytest.mark.parametrize(

@@ -365,14 +365,141 @@ def test_with_document_ids_the_kernels_equal_the_xla_tiles(
         monkeypatch, head_dim, group, packing, skipping)
 
 
+def block_heads_noted():
+    noted = metrics.REGISTRY.gauge("hvtpu_attention_block_heads")
+    return noted.value(kind="query"), noted.value(kind="key_value")
+
+
+@pytest.mark.parametrize("kv_heads, block", [(16, 8), (6, 6)],
+                         ids=["sixteen heads", "six heads"])
 @pytest.mark.parametrize("packing", sorted(PACKINGS))
 def test_sixteen_key_value_heads_of_one_query_head_each(
-        interpreted, monkeypatch, packing):
+        interpreted, monkeypatch, packing, kv_heads, block):
     """The looped decoder's shape (``models.looped``: multi-head
     attention, 16 key/value heads of 128 with one query head a group,
-    document ids): the kernels' grid walks sixteen groups of one."""
+    document ids): the kernels walk them eight to a block (six heads go
+    six to one), and a head's results do not depend on which heads
+    share its block: the same heads in as many calls of one head each
+    are equal bit for bit, forward and the three gradients."""
     kernels_against_xla_tiles(
-        monkeypatch, 128, 1, packing, skipping=True, kv_heads=16)
+        monkeypatch, 128, 1, packing, skipping=True, kv_heads=kv_heads)
+    segment = jnp.asarray(segments(PACKINGS[packing], 512))
+    q, k, v, target = document_operands(128, 1, kv_heads=kv_heads)
+    monkeypatch.delenv("HVTPU_PALLAS", raising=False)
+
+    @jax.jit
+    def layer(q, k, v, target):
+        def loss(q, k, v):
+            out = hs.causal_document_attention(
+                q, k, v, segment, scale=0.125, tile=128)
+            return jnp.sum(out * target), out
+
+        grads, out = jax.grad(loss, (0, 1, 2), has_aux=True)(q, k, v)
+        return (out, *grads)
+
+    together = layer(q, k, v, target)
+    assert block_heads_noted() == (block, block)
+    alone = [layer(*(a[:, :, h:h + 1] for a in (q, k, v, target)))
+             for h in range(kv_heads)]
+    assert block_heads_noted() == (1, 1)
+    for name, got, want in zip(("out", "dq", "dk", "dv"), together,
+                               zip(*alone)):
+        assert np.array_equal(got, jnp.concatenate(want, axis=2)), name
+
+
+@pytest.mark.parametrize("heads, kv_heads, hd, block", [
+    (4, 1, 128, (4, 1)),        # the transformer cell: as before
+    (32, 8, 64, (8, 2)),        # the hybrid cell: as before
+    (16, 16, 128, (8, 8)),      # the looped cell
+    (32, 4, 128, (8, 1)),       # a group of eight fills a step
+    (6, 6, 128, (6, 6)), (10, 10, 128, (5, 5)), (7, 7, 128, (7, 7)),
+    (20, 10, 128, (4, 2)), (12, 6, 64, (4, 2)), (6, 6, 64, (6, 6)),
+    (16, 2, 64, (16, 2)), (1, 1, 128, (1, 1)), (2, 1, 256, (2, 1))],
+    ids=lambda case: str(case).replace(" ", ""))
+def test_a_blocks_heads_are_chosen_from_the_shapes(heads, kv_heads, hd,
+                                                   block):
+    """The fewest key/value heads that fill whole vectors, and more
+    while their query heads number at most eight and they divide the
+    key/value heads evenly; ``lse`` is laid out by it."""
+    assert flash_attention.block_heads(heads, kv_heads, hd) == block
+    query, key_value = block
+    assert (key_value * hd) % 128 == 0 and kv_heads % key_value == 0
+    assert query == key_value * (heads // kv_heads)
+    schedule = hs._flash_schedule(256, 128, 128)
+    q = jax.ShapeDtypeStruct((2, 256, heads, hd), jnp.float32)
+    kv = jax.ShapeDtypeStruct((2, 256, kv_heads, hd), jnp.float32)
+    out, lse, exact = jax.eval_shape(
+        lambda q, k, v: flash_attention.forward(
+            q, k, v, schedule, hs._seen, scale=1.0, mask_value=-1e30,
+            interpret=True, ids=jnp.zeros((2, 256), jnp.int32)), q, kv, kv)
+    assert out.shape == q.shape
+    assert lse.shape == (2, heads // query, 256, query)
+
+
+def walks(packing, first_skipped=False):
+    """For both walks over a packing's rows: the table, the rows' flags
+    in its order, and the pairs held."""
+    live = np.array(hs.live_pairs(segments(PACKINGS[packing], 512), 128,
+                                  128))
+    if first_skipped:          # no walk of a causal mask: a caller's own
+        live[:, 0, 0] = 0
+    schedule = hs._flash_schedule(512, 128, 128)
+    for own, table in enumerate((schedule.by_query, schedule.by_key)):
+        flags = live[:, table[0], table[1]]
+        yield own, table, flags, np.asarray(
+            flash_attention.held_pairs(jnp.asarray(flags)))
+
+
+@pytest.mark.parametrize("packing", sorted(PACKINGS))
+def test_a_skipped_pair_holds_the_last_live_pairs_blocks(packing):
+    """Every live pair names itself and every skipped pair the last
+    live pair the walk passed, so the blocks fetched along a row's walk
+    number its live pairs and no more, in both orders; the index maps
+    of the operands that change along the walk read their tile through
+    it, the accumulating tile's and the results' from the table."""
+    for own, table, flags, held in walks(packing):
+        assert flags[:, 0].all()            # a walk starts on the diagonal
+        at = np.arange(table.shape[1])
+        for row_flags, row_held in zip(flags != 0, held):
+            assert np.array_equal(row_held[row_flags], at[row_flags])
+            for i in at[~row_flags]:
+                assert row_held[i] == at[:i][row_flags[:i]].max()
+            fetched = 1 + np.count_nonzero(np.diff(table[1 - own, row_held]))
+            assert fetched <= row_flags.sum()
+            assert 1 + np.count_nonzero(np.diff(row_held)) == row_flags.sum()
+        q = jnp.zeros((2, 512, 4, 128))
+        ks = flash_attention._Kernels.of(
+            q, q, hs._flash_schedule(512, 128, 128), hs._seen, 1.0, -1e30,
+            True, jnp.zeros((2, 512), jnp.int32), jnp.asarray(flags))
+        through_held = {
+            0: {"narrow", "key_ids", "key_ids_row"},
+            1: {"wide", "row", "column", "query_ids", "query_ids_row"}}[own]
+        where = {      # an index of batch b, block of heads 7 and tile t
+            "wide": lambda b, t: (b, t, 7), "narrow": lambda b, t: (b, t, 7),
+            "column": lambda b, t: (b, 7, t, 0),
+            "row": lambda b, t: (b, 7, 0, t),
+            "query_ids": lambda b, t: (b, t, 0),
+            "key_ids": lambda b, t: (b, t, 0),
+            "query_ids_row": lambda b, t: (b, 0, t),
+            "key_ids_row": lambda b, t: (b, 0, t)}
+        for kind, index_of in where.items():
+            spec = ks.operand(kind, own)[1]
+            keys = int("key" in kind or kind == "narrow")
+            for b in range(2):
+                for i in at:
+                    pair = held[b, i] if kind in through_held else i
+                    assert spec.index_map(b, 7, i, table, flags, held) == (
+                        index_of(b, table[keys, pair])), (kind, own)
+
+
+@pytest.mark.parametrize("packing", sorted(PACKINGS))
+def test_a_walk_that_starts_on_a_skipped_pair_holds_the_first_live_one(
+        packing):
+    for own, table, flags, held in walks(packing, first_skipped=True):
+        first = np.argmax(flags != 0, axis=1)
+        assert (first > 0).all()
+        for row_held, row_first in zip(held, first):
+            assert (row_held[:row_first + 1] == row_first).all()
 
 
 def test_in_bf16_the_document_kernels_differ_by_their_products_rounding(
@@ -441,7 +568,9 @@ def test_the_hosts_loop_counts_the_pairs_run_and_skipped(monkeypatch):
 def test_without_ids_the_kernels_are_built_as_if_there_were_none(
         interpreted):
     """The transformer cell's call: one prefetched table and the three
-    operands, nothing for ids or for pairs to skip."""
+    operands, nothing for ids or for pairs to skip.  With ids two more
+    operands; with pairs to skip their flags and the pairs held, both
+    prefetched, and nothing else."""
     seq_len = 128
     schedule = flash_attention.pair_schedule(
         bd.tile_work(seq_len, 4, 128, 128), 128, 128)
@@ -460,7 +589,7 @@ def test_without_ids_the_kernels_are_built_as_if_there_were_none(
     assert call_of() == (1, 4)
     ids = jnp.zeros(q.shape[:2], jnp.int32)
     assert call_of(ids=ids) == (1, 6)
-    assert call_of(ids=ids, live=jnp.ones((2, 2, 2), jnp.int32)) == (2, 7)
+    assert call_of(ids=ids, live=jnp.ones((2, 2, 2), jnp.int32)) == (3, 8)
 
 
 def test_without_pallas_the_xla_path_runs(monkeypatch):

@@ -448,6 +448,54 @@ def test_the_exits_are_noted_from_the_hosts_loop():
                for t in (1, 2, 3, 4)) == pytest.approx(1.0)
 
 
+def test_the_cells_attention_metric_reads_the_models_attention_scope():
+    """``loop_attention_ms_per_step`` (PR 35) is the device time under
+    ``hvtpu:attention``, the scope the model's attention runs under, a
+    part of ``loop_stack_ms_per_step``, reported in the looped cell
+    alone; it needs a chip's trace, like the other scope metrics."""
+    import types
+
+    from benchmark.layer_metrics import (
+        loop_attention_ms_per_step, loop_stack_ms_per_step)
+
+    with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
+        entry, = (m for m in json.load(f)["per_layer"]
+                  if m["name"] == "loop_attention_ms_per_step")
+    assert entry == {
+        "name": "loop_attention_ms_per_step", "unit": "ms",
+        "better": "lower", "source": "device_trace", "layer": "attention",
+        "moves": "samples_per_s_per_chip",
+        "workloads": ["ouro-2.6b-6of48-t8k-b1"]}
+    text = """
+  %fusion.1 = bf16[1,8192,2048]{2,1,0} fusion(%p.1), kind=kOutput, calls=%fc.1, metadata={op_name="jit(one_step)/jvp()/while/body/closed_call/hvtpu:loop.proj/dot_general"}
+  %hvtpu_flash_attention_fwd.2 = (bf16[1,8192,16,128]{3,2,1,0}) custom-call(%p.2), custom_call_target="tpu_custom_call", metadata={op_name="jit(one_step)/jvp()/while/body/closed_call/hvtpu:attention/pallas_call"}
+  %fusion.3 = s32[1,136]{1,0} fusion(%p.3), kind=kLoop, calls=%fc.3, metadata={op_name="jit(one_step)/jvp()/while/body/closed_call/hvtpu:attention/cummax"}
+  %fusion.4 = f32[8]{0} fusion(%p.4), kind=kLoop, calls=%fc.4, metadata={op_name="jit(one_step)/mul"}
+"""
+    steps = 4
+    op_ms = {"fusion.1 fusion bf16[1,8192,2048]": 200.0,
+             "hvtpu_flash_attention_fwd.2 custom-call "
+             "(bf16[1,8192,16,128])": 130.0,
+             "fusion.3 fusion s32[1,136]": 0.5, "fusion.4 fusion f32[8]": 7.0}
+    device = types.SimpleNamespace(
+        op_ns={name: ms * 1e6 * steps for name, ms in op_ms.items()},
+        step_ns=[1.0] * steps)
+    obs = types.SimpleNamespace(
+        trace=types.SimpleNamespace(
+            devices=[device], busy_s=sum(op_ms.values()) * steps / 1e3),
+        compiled_text=text)
+    assert loop_attention_ms_per_step.read(obs) == pytest.approx(130.5)
+    assert loop_stack_ms_per_step.stack_ms(obs) == pytest.approx(330.5)
+    assert loop_attention_ms_per_step.read(types.SimpleNamespace(
+        trace=None, compiled_text=text)) is None      # no chip: no trace
+    batch = batch_of([[20]], 64)
+    params = params_of(TOY)
+    lowered = jax.jit(
+        lambda x: looped.hidden_states_by_pass(params, x, TOY)).lower(
+            batch["x"]).as_text(debug_info=True)
+    assert "hvtpu:attention" in lowered
+
+
 def test_the_models_package_names_the_model_and_does_not_import_it():
     """The other cells' set-up is imports first."""
     import subprocess

@@ -355,8 +355,12 @@ def test_the_looped_cells_step_fits_and_keeps_one_exits_logits(v5e_2x2):
     backward.  Its attention, 16 key/value heads of one query head each
     with document ids, runs in the three kernels of
     ``ops/flash_attention.py`` under ``hvtpu:attention``, the recomputed
-    forward too, none with kernel metadata; no scatter is as large as
-    the logits; and the five scopes the cell's readers join are there."""
+    forward too, none with kernel metadata, eight heads to a block (a
+    grid of two blocks of heads: ``lse`` and ``delta`` ``[1, 2, 8192,
+    8]``, every block of queries, keys and values ``[512, 1024]``, which
+    Mosaic fits in the VMEM the kernels ask for); no scatter is as large
+    as the logits; and the five scopes the cell's readers join are
+    there."""
     import re
 
     from benchmark import cells, scopes
@@ -384,6 +388,10 @@ def test_the_looped_cells_step_fits_and_keeps_one_exits_logits(v5e_2x2):
     assert len(kernels) == 4            # forward, recomputed, dq, dk/dv
     assert not any("kernel_metadata" in line.replace(
         "kernel_metadata={}", "") for line in kernels)
+    for line in kernels:                # a row's statistics, by block
+        shapes = set(re.findall(r"f32\[1,(\d+),(\d+),(\d+)\]", line))
+        assert shapes == ({("2", "8", "8192")} if "_dkv" in line
+                          else {("2", "8192", "8")}), line
     assert {"hvtpu:loop.proj", "hvtpu:loop.mlp", "hvtpu:loop.exit",
             "hvtpu:attention", "hvtpu:lm_head"} <= set(
                 scopes.scope_by_instruction(text).values())
