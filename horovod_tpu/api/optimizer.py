@@ -280,34 +280,42 @@ def ShardedDistributedOptimizer(
         return optimizer.init(mine)
 
     def update_fn(grads, state, params=None, **extra):
-        gflat, specs, treedef = _flatten(grads)
-        n = _lax.axis_size(axis_name)
-        chunk, pad = _shard_bounds(gflat.shape[0], n)
-        if pad:
-            gflat = jnp.pad(gflat, (0, pad))
-        # wire compression rides the reduce_scatter like the fused
-        # allreduce path's compressors
-        wire, cctx = compression.compress(gflat)
-        gshard = _spmd.reducescatter(
-            wire.reshape(n, chunk), axis_name=axis_name,
-            op=ReduceOp.AVERAGE if average else ReduceOp.SUM,
-        ).reshape(chunk)
-        gshard = compression.decompress(gshard, cctx)
+        # the scopes of DistributedOptimizer's jit path, so that a
+        # device profile of either optimizer reads alike
+        with jax.named_scope("hvtpu:exchange.pack"):
+            gflat, specs, treedef = _flatten(grads)
+            n = _lax.axis_size(axis_name)
+            chunk, pad = _shard_bounds(gflat.shape[0], n)
+            if pad:
+                gflat = jnp.pad(gflat, (0, pad))
+        with jax.named_scope("hvtpu:exchange.reduce"):
+            # wire compression rides the reduce_scatter like the fused
+            # allreduce path's compressors
+            wire, cctx = compression.compress(gflat)
+            gshard = _spmd.reducescatter(
+                wire.reshape(n, chunk), axis_name=axis_name,
+                op=ReduceOp.AVERAGE if average else ReduceOp.SUM,
+            ).reshape(chunk)
+            gshard = compression.decompress(gshard, cctx)
         pshard = None
         if params is not None:
-            pflat, _, _ = _flatten(params)
+            with jax.named_scope("hvtpu:exchange.pack"):
+                pflat, _, _ = _flatten(params)
+                if pad:
+                    pflat = jnp.pad(pflat, (0, pad))
+                idx = _lax.axis_index(axis_name)
+                pshard = _lax.dynamic_slice_in_dim(pflat, idx * chunk, chunk)
+        with jax.named_scope("hvtpu:optimizer.update"):
+            upd_shard, new_state = optimizer.update(
+                gshard.astype(gflat.dtype), state, pshard, **extra
+            )
+        with jax.named_scope("hvtpu:exchange.reduce"):
+            full = _spmd.allgather(upd_shard, axis_name=axis_name)
+        with jax.named_scope("hvtpu:exchange.unpack"):
+            full = full.reshape(-1)
             if pad:
-                pflat = jnp.pad(pflat, (0, pad))
-            idx = _lax.axis_index(axis_name)
-            pshard = _lax.dynamic_slice_in_dim(pflat, idx * chunk, chunk)
-        upd_shard, new_state = optimizer.update(
-            gshard.astype(gflat.dtype), state, pshard, **extra
-        )
-        full = _spmd.allgather(upd_shard, axis_name=axis_name)
-        full = full.reshape(-1)
-        if pad:
-            full = full[:-pad]
-        outs = unpack_flat(full, specs)
+                full = full[:-pad]
+            outs = unpack_flat(full, specs)
         return jax.tree_util.tree_unflatten(treedef, outs), new_state
 
     return optax.GradientTransformation(init_fn, update_fn)
@@ -376,11 +384,9 @@ def DistributedOptimizer(
         guard: the verdict is computed on the REDUCED gradients (the
         allreduce already propagated any rank's NaN/inf to every
         rank), so all ranks skip/zero/abort the step together."""
-        if nonfinite == "off":
-            return optimizer.update(reduced, inner_state, params, **extra)
         if axis_name is None:
             # Eager path: concrete arrays, Python control flow.
-            if not bool(_tree_finite(reduced)):
+            if nonfinite != "off" and not bool(_tree_finite(reduced)):
                 _M_NONFINITE.inc()
                 if nonfinite == "abort":
                     raise HorovodInternalError(
@@ -397,11 +403,19 @@ def DistributedOptimizer(
         # In-jit the flag is traced: skip rides lax.cond.  abort cannot
         # raise from compiled code and degrades to a coordinated skip,
         # and the counter only advances on the eager path — both
-        # documented in docs/robustness.md.
+        # documented in docs/robustness.md.  The named scopes put the
+        # guard's and the update's device time under their own names in a
+        # profile (docs/observability.md); around the whole cond, so that
+        # both branches carry the update's.
         if nonfinite == "zero":
-            return optimizer.update(
-                _zero_nonfinite(reduced), inner_state, params, **extra)
-        finite = _tree_finite(reduced)
+            with jax.named_scope("hvtpu:optimizer.guard"):
+                reduced = _zero_nonfinite(reduced)
+        if nonfinite in ("off", "zero"):
+            with jax.named_scope("hvtpu:optimizer.update"):
+                return optimizer.update(
+                    reduced, inner_state, params, **extra)
+        with jax.named_scope("hvtpu:optimizer.guard"):
+            finite = _tree_finite(reduced)
 
         def _apply(_):
             return optimizer.update(reduced, inner_state, params, **extra)
@@ -410,7 +424,8 @@ def DistributedOptimizer(
             return (jax.tree_util.tree_map(jnp.zeros_like, reduced),
                     inner_state)
 
-        return jax.lax.cond(finite, _apply, _skip, None)
+        with jax.named_scope("hvtpu:optimizer.update"):
+            return jax.lax.cond(finite, _apply, _skip, None)
 
     if backward_passes_per_step == 1:
 
@@ -433,6 +448,14 @@ def DistributedOptimizer(
         )
 
     def update_fn(grads, state, params=None, **extra):
+        if axis_name is None:
+            return accumulate(grads, state, params, extra)
+        # in-jit the accumulator's add and clear are the update's too;
+        # the exchange and the guard keep their own, inner scopes
+        with jax.named_scope("hvtpu:optimizer.update"):
+            return accumulate(grads, state, params, extra)
+
+    def accumulate(grads, state, params, extra):
         acc = jax.tree_util.tree_map(jnp.add, state.acc, grads)
         count = state.step_in_cycle + 1
 

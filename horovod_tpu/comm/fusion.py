@@ -132,8 +132,13 @@ def fused_tree_allreduce(
     from .packing import pack_flat, unpack_flat
 
     out_leaves: List[Any] = [None] * len(leaves)
+    # The named scopes are compile-time metadata (the ``op_name`` of
+    # every instruction made under them): a device profile then says what
+    # the pack, the reduction and the unpack of the buckets cost a step
+    # (docs/observability.md).
     for bucket in plan.buckets:
-        flat, _ = pack_flat([leaves[e.index] for e in bucket])
+        with jax.named_scope("hvtpu:exchange.pack"):
+            flat, _ = pack_flat([leaves[e.index] for e in bucket])
         # Per-tensor segment boundaries keep Adasum's dot products
         # per-tensor inside the fused buffer (reference: tensor_counts
         # in adasum.h DispatchFusedAllreduce) — results must not depend
@@ -145,18 +150,21 @@ def fused_tree_allreduce(
             off += e.size
         # spmd.allreduce handles op routing (incl. the Adasum+groups and
         # int8 rejection paths) so fused and unfused semantics agree.
-        red = spmd.allreduce(
-            flat,
-            axis_name=axis_name,
-            op=rop,
-            prescale_factor=prescale_factor,
-            postscale_factor=postscale_factor,
-            compression=compression,
-            groups=groups,
-            adasum_segments=segments if rop == ReduceOp.ADASUM else None,
-        )
+        with jax.named_scope("hvtpu:exchange.reduce"):
+            red = spmd.allreduce(
+                flat,
+                axis_name=axis_name,
+                op=rop,
+                prescale_factor=prescale_factor,
+                postscale_factor=postscale_factor,
+                compression=compression,
+                groups=groups,
+                adasum_segments=segments if rop == ReduceOp.ADASUM else None,
+            )
         specs = [(e.shape, e.dtype, e.size) for e in bucket]
-        for e, out in zip(bucket, unpack_flat(red, specs)):
+        with jax.named_scope("hvtpu:exchange.unpack"):
+            outs = unpack_flat(red, specs)
+        for e, out in zip(bucket, outs):
             out_leaves[e.index] = out
 
     return jax.tree_util.tree_unflatten(treedef, out_leaves)

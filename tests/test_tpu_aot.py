@@ -182,6 +182,29 @@ def test_the_transformer_cells_step_fits_and_its_kernels_keep_their_scope(
         kernel: {"hvtpu:attention"} for kernel in ATTENTION_KERNELS}
     _no_pass_over_a_whole_row_buffer(text, cell)
     _the_expert_layers_loops_lie_under_its_scopes(text)
+    _the_guards_branch_runs_under_the_updates_scope(text)
+
+
+def _the_guards_branch_runs_under_the_updates_scope(text):
+    """``DistributedOptimizer``'s guard is one ``conditional`` named by
+    ``hvtpu:optimizer.update``, and the branch that applies the update
+    holds instructions of that scope (the momentum's multiply): the
+    scope lies around the whole ``lax.cond``, not beside it.  On one
+    chip the small leaves' bucket is what is left of the exchange."""
+    import re
+
+    guard = re.search(
+        r"^\s*%\S+ = .* conditional\(.*branch_computations=\{"
+        r"%(?P<skip>[^\s,]+), %(?P<apply>[^\s}]+)\}.*"
+        r'op_name="[^"]*hvtpu:optimizer\.update/cond"', text, re.MULTILINE)
+    assert guard, "no conditional under hvtpu:optimizer.update"
+    start = text.index(f"\n%{guard['apply']} (")
+    branch = text[start:text.index("\n}\n", start)]
+    assert "hvtpu:optimizer.update/cond/branch_1_fun/mul" in branch
+    assert "hvtpu:optimizer.guard" not in branch
+    for scope in ("hvtpu:optimizer.guard", "hvtpu:exchange.pack",
+                  "hvtpu:exchange.unpack"):
+        assert scope in text, scope
 
 
 def _no_pass_over_a_whole_row_buffer(text, cell):
