@@ -724,6 +724,21 @@ def note_attention_pairs(run: int, skipped: int) -> None:
     pairs.inc(float(skipped), kind="skipped")
 
 
+def note_moe_products(path: str) -> None:
+    """Count one call of ``parallel.moe.dropless_topk_moe`` by how its
+    experts' products run: ``"grouped"`` (the Pallas kernels of
+    ``ops/grouped_ffn.py``, a grouped product a kernel call) or
+    ``"ragged_dot"`` (``lax.ragged_dot`` over the same buffers).  Called
+    while a program is traced, once a call site and a trace, like
+    ``note_attention_path``: a step that scans its layers counts one
+    call however many layers run it."""
+    REGISTRY.counter(
+        "hvtpu_moe_products_total",
+        "Calls of the dropless expert layer, counted when a program is "
+        "traced, by how the experts' products were built in: the grouped "
+        "Pallas kernels or lax.ragged_dot.").inc(path=path)
+
+
 def note_moe_routing(rows_per_expert, buffer_rows=None) -> None:
     """Record what a step's expert layers saw: ``rows_per_expert`` is
     the ``[layers, experts_held]`` (or ``[experts_held]``) count that

@@ -25,7 +25,8 @@ import jax.numpy as jnp
 from jax import lax
 from jax.experimental import pallas as pl
 
-from horovod_tpu.ops import pallas_ops
+from horovod_tpu.obs import metrics
+from horovod_tpu.ops import grouped_ffn, pallas_ops
 
 
 def switch_route(
@@ -115,18 +116,31 @@ def expert_parallel_moe(
 # Top-k, dropless, over the experts this chip holds
 # ---------------------------------------------------------------------------
 
-# Rows of one grouped product.  Every expert's rows are padded up to a
-# whole number of tiles so that a tile multiplies by one expert's
-# weights; half a tile an expert is wasted on average.
+# Rows of one tile: what a turn of the gathers moves, what a visit of
+# the grouped kernels multiplies and what the way back adds into a block
+# of tokens.  The experts' rows lie contiguous in the buffers, expert
+# after expert with nothing between them: no row is padding, and a tile
+# in which one expert's rows end and the next one's begin is multiplied
+# once for each.
 _TILE_ROWS = 512
+# ... and of those, the rows a visit of the grouped kernels multiplies:
+# at [2048, 768] and 45,047 rows on a v5e the two kernels took 9.89 ms
+# in visits of 128 rows, 13.53 in visits of 256 and 12.73 in visits of
+# 512, where the backward kernel's temporaries crowd its three f32
+# sums in VMEM (PERF.md, findings of PR 37)
+_PRODUCT_ROWS = 128
+# lanes of the routing weights' buffer in expert order: a row's weight in
+# every lane of one vector, the room and the layout a [rows, 1] array
+# gets on the chip anyway
+_WEIGHT_LANES = 128
 
 
 def _row_buffer(rows, width, dtype, near):
     """Room for ``rows`` rows, sized for the routing's worst case and
     **not cleared**: no pass over 1.1 GB of which an even routing uses
     an eighth (PERF.md, findings of PR 31).  What nobody wrote may hold
-    anything, NaN too, so every reader masks by its tile's live rows
-    *before* any product: a 0 in a 0/1 matrix does not make a NaN
+    anything, NaN too, so every reader leaves the rows that are nobody's
+    out *before* any product: a 0 in a 0/1 matrix does not make a NaN
     harmless.
 
     On a TPU the room is the output of a kernel that does nothing, and
@@ -136,7 +150,7 @@ def _row_buffer(rows, width, dtype, near):
     invariant, and then copies, whole, in every layer, because the
     layer's loops write into it.  The room exists from when ``near``
     does: the way back hands in the rows it will read, so that its
-    output is not held while the experts' loop runs (135 MB of the
+    output is not held while the experts' products run (135 MB of the
     step's peak in the benchmark's cell)."""
     use, interpret = pallas_ops._pallas_mode()
     if not use or interpret or not pallas_ops._mosaic_dtype(dtype):
@@ -153,38 +167,16 @@ def _row_buffer(rows, width, dtype, near):
 def buffer_rows(tokens: int, top_k: int, experts_held: int) -> int:
     """Rows of the buffers ``dropless_topk_moe`` keeps in expert order
     for ``tokens`` tokens: the worst case, ``min(top_k, experts_held)``
-    rows a token in whole tiles, and a partial tile an expert."""
+    rows a token, in whole tiles."""
     tile_rows = min(_TILE_ROWS, tokens)
-    return (tokens * min(top_k, experts_held) // tile_rows
-            + experts_held + 1) * tile_rows
-
-
-class _Tiles(NamedTuple):
-    """Rows of a sorted list, group by group, cut into tiles that never
-    span two groups; one entry a tile, for as many tiles as the worst
-    routing makes (``count`` says how many this one made)."""
-    count: jax.Array        # tiles in all
-    group: jax.Array        # a tile's group
-    start: jax.Array        # where its rows begin in the sorted list
-    live: jax.Array         # how many of its rows are the group's
-    first_row: jax.Array    # per group: its first tile's first row
-
-
-def _tiles(counts, tile_rows, max_tiles) -> _Tiles:
-    tiles = (counts + tile_rows - 1) // tile_rows
-    ends = jnp.cumsum(tiles)
-    t = jnp.arange(max_tiles, dtype=jnp.int32)
-    g = jnp.minimum(jnp.searchsorted(ends, t, side="right"),
-                    counts.shape[0] - 1).astype(jnp.int32)
-    first = (t - (ends - tiles)[g]) * tile_rows
-    return _Tiles(ends[-1], g, (jnp.cumsum(counts) - counts)[g] + first,
-                  jnp.clip(counts[g] - first, 0, tile_rows),
-                  (ends - tiles) * tile_rows)
+    return -(-tokens * min(top_k, experts_held) // tile_rows) * tile_rows
 
 
 class _Plan(NamedTuple):
-    by_expert: jax.Array        # the assignments' e * N + n, by expert
-    expert_tiles: _Tiles
+    # row r of a buffer in expert order is assignment by_expert[r], as
+    # e * N + n; the entries past the last assignment are no token's
+    by_expert: jax.Array
+    counts: jax.Array           # the rows of each expert
     # by block of consecutive tokens, [blocks, a whole number of tiles]:
     block_tokens: jax.Array     # an assignment's token, counted in the block
     block_rows: jax.Array       # ... and its row of the buffer
@@ -225,17 +217,18 @@ def _plan(hit, top_k):
     """Where every (token, held expert) assignment of ``hit`` ``[N,
     E_held]`` goes, both ways.
 
-    *By expert*: the assignments sorted by expert, tokens ascending,
-    each expert's rows cut into tiles; tile ``t`` is multiplied by one
-    expert's weights and its result lies at rows ``t * tile_rows``
-    onwards of a buffer in that order.  *By block*: the tokens cut into
-    blocks of ``3/4 tile_rows`` (at an even routing a block then has
-    3/4 of a tile's rows, and one that fills a second tile is rare),
-    and of each block the list of its assignments, each with its row of
-    that buffer, so that a tile of the list adds into one block of
-    consecutive tokens: the way back needs a gather and a product with
-    a 0/1 matrix, and no scatter of rows (which costs microseconds a
-    row on the chip: PERF.md, findings of PR 27).
+    *By expert*: the assignments sorted by expert, tokens ascending;
+    that order is the buffers' own, so an expert's first row is the sum
+    of the counts before it, and the products run a group of rows
+    against one expert's weights with the counts as the groups' sizes.
+    *By block*: the tokens cut into blocks of ``3/4 tile_rows`` (at an
+    even routing a block then has 3/4 of a tile's rows, and one that
+    fills a second tile is rare), and of each block the list of its
+    assignments, each with its row of that buffer, so that a tile of
+    the list adds into one block of consecutive tokens: the way back
+    needs a gather and a product with a 0/1 matrix, and no scatter of
+    rows (which costs microseconds a row on the chip: PERF.md, findings
+    of PR 27).
 
     Returns the plan's arrays and its static sizes ``(tile_rows, tokens
     a block, rows of the buffer)``: the buffer holds the worst case,
@@ -245,8 +238,7 @@ def _plan(hit, top_k):
     block = max(1, 3 * tile_rows // 4)
     blocks = -(-n // block)
     rows = buffer_rows(n, top_k, e_held)
-    most = rows // tile_rows - 1        # tiles: the full ones, one an expert
-    expert_tiles = _tiles(hit.sum(axis=0, dtype=jnp.int32), tile_rows, most)
+    counts = hit.sum(axis=0, dtype=jnp.int32)
     size = n * e_held
     idx = jnp.arange(size, dtype=jnp.int32)
     # the hits' e * N + n ascending, then the others (as index + size):
@@ -254,9 +246,9 @@ def _plan(hit, top_k):
     # starts among the last hits reads ``tile_rows`` past them
     by_expert = jnp.concatenate([
         lax.sort(jnp.where(hit.T.reshape(-1), idx, idx + size),
-                 is_stable=False), jnp.zeros((tile_rows,), jnp.int32)])
+                 is_stable=False), jnp.full((tile_rows,), size, jnp.int32)])
     rank = jnp.cumsum(hit, axis=0, dtype=jnp.int32) - hit   # among e's rows
-    dest = expert_tiles.first_row[None, :] + rank
+    dest = (jnp.cumsum(counts) - counts)[None, :] + rank
 
     def in_blocks(a):
         return jnp.pad(a, ((0, blocks * block - n), (0, 0))).reshape(
@@ -265,33 +257,67 @@ def _plan(hit, top_k):
     hit = in_blocks(hit)
     block_tokens, block_rows = _by_block(hit, in_blocks(dest), tile_rows,
                                          rows)
-    return (_Plan(by_expert, expert_tiles, block_tokens, block_rows,
+    return (_Plan(by_expert, counts, block_tokens, block_rows,
                   hit.sum(axis=(1, 2), dtype=jnp.int32)),
             (tile_rows, block, rows))
 
 
-def _expert(w, e):
-    return lax.dynamic_index_in_dim(w, e, keepdims=False)
+def _live_tiles(plan, tile_rows):
+    return (plan.counts.sum() + tile_rows - 1) // tile_rows
 
 
-def _expert_tile(plan, t, n, tile_rows):
-    """Tile ``t`` by expert: the expert, its rows' tokens (clamped where
-    the row is not the expert's) and which rows are real."""
-    tiles = plan.expert_tiles
-    e = tiles.group[t]
-    idx = lax.dynamic_slice(plan.by_expert, (tiles.start[t],), (tile_rows,))
-    valid = jnp.arange(tile_rows, dtype=jnp.int32) < tiles.live[t]
-    return e, jnp.clip(idx - e * n, 0, n - 1), valid
+def _to_experts(arrays, weight, plan, sizes):
+    """The way out: the rows of each of ``arrays`` ``[N, D]`` and the
+    router's weights ``[N, E_held]``, gathered into buffers in expert
+    order, one turn a tile and the tiles that hold assignments only.
+    The last tile's further rows get some token's row: nobody's, like
+    the rows of the tiles not gathered at all."""
+    tile_rows, _, rows = sizes
+    n = weight.shape[0]
+    by_expert_weight = weight.T.reshape(-1)         # at e * N + n
+
+    def tile(t, bufs):
+        at = t * tile_rows
+        idx = lax.dynamic_slice(plan.by_expert, (at,), (tile_rows,))
+        token = idx % n
+        wt = jnp.take(by_expert_weight, idx, mode="clip")
+        gathered = [jnp.take(a, token, axis=0, mode="clip") for a in arrays]
+        gathered.append(jnp.broadcast_to(wt[:, None],
+                                         (tile_rows, _WEIGHT_LANES)))
+        return tuple(lax.dynamic_update_slice(buf, tile_of, (at, 0))
+                     for buf, tile_of in zip(bufs, gathered))
+
+    # two rooms of one shape made from one operand are one room to XLA,
+    # which then copies it, whole: each gets an operand of its own
+    return lax.fori_loop(
+        0, _live_tiles(plan, tile_rows), tile,
+        tuple(_row_buffer(rows, a.shape[1], a.dtype, near)
+              for a, near in zip(arrays, (plan.block_counts, plan.counts)))
+        + (_row_buffer(rows, _WEIGHT_LANES, weight.dtype,
+                       plan.block_counts),))
 
 
-def _gather_rows(x, tok, valid):
-    rows = jnp.take(x, tok, axis=0, mode="clip")
-    return jnp.where(valid[:, None], rows, jnp.zeros((), x.dtype))
+def _to_assignments(dwt, plan, sizes, n, e_held):
+    """``dwt`` ``[rows, lanes]``, a scalar a row of the buffer (in
+    every lane), to its (token, expert) ``[N, E_held]``: a scatter of
+    scalars costs nanoseconds each, unlike one of rows.  Zero where no
+    assignment is."""
+    tile_rows = sizes[0]
+    live = plan.counts.sum()
 
+    def tile(t, out):
+        at = t * tile_rows
+        idx = lax.dynamic_slice(plan.by_expert, (at,), (tile_rows,))
+        mine = at + jnp.arange(tile_rows, dtype=jnp.int32) < live
+        return out.at[jnp.where(mine, idx, n * e_held)].set(
+            # the tile as it lies: for a slice of one lane XLA lays the
+            # whole buffer out lane by lane first, a copy a layer
+            lax.dynamic_slice(dwt, (at, 0), (tile_rows, dwt.shape[1]))[:, 0],
+            mode="drop", unique_indices=True, indices_are_sorted=True)
 
-def _dot(a, b, dims, precision=None):
-    return lax.dot_general(a, b, (dims, ((), ())), precision=precision,
-                           preferred_element_type=jnp.float32)
+    return lax.fori_loop(
+        0, _live_tiles(plan, tile_rows), tile,
+        jnp.zeros((n * e_held,), dwt.dtype)).reshape(e_held, n).T
 
 
 def _to_tokens(rows_buf, plan, sizes, n):
@@ -313,7 +339,9 @@ def _to_tokens(rows_buf, plan, sizes, n):
         row = lax.dynamic_slice(plan.block_rows, at, (1, tile_rows))[0]
         live = (jnp.arange(tile_rows, dtype=jnp.int32)
                 < plan.block_counts[b] - j * tile_rows)
-        z = _gather_rows(rows_buf, row, live)
+        z = jnp.where(live[:, None],
+                      jnp.take(rows_buf, row, axis=0, mode="clip"),
+                      jnp.zeros((), rows_buf.dtype))
         # 0/1: row r of the tile belongs to token i of the block
         mine = (token == in_block) & live
         return _dot(mine.astype(z.dtype), z, ((1,), (0,)))
@@ -332,103 +360,123 @@ def _to_tokens(rows_buf, plan, sizes, n):
     return out.reshape(blocks * block, d)[:n]
 
 
+def _dot(a, b, dims, precision=None):
+    return lax.dot_general(a, b, (dims, ((), ())), precision=precision,
+                           preferred_element_type=jnp.float32)
+
+
+def products_path(dtype, d: int, f: int, tokens: int, top_k: int,
+                  experts_held: int) -> str:
+    """How the experts' products of a layer of these shapes run, from
+    what the code can see: ``"grouped"``, in the Pallas kernels of
+    ``ops/grouped_ffn.py``, where ``ops.pallas_ops`` compiles kernels (a
+    TPU backend, or the interpreter in tests) and the shapes are ones
+    they take; elsewhere ``"ragged_dot"``, the same products over the
+    same buffers as ``lax.ragged_dot``."""
+    use, _ = pallas_ops._pallas_mode()
+    return ("grouped" if use and grouped_ffn.supports(
+        dtype, d, f, buffer_rows(tokens, top_k, experts_held),
+        _product_rows(min(_TILE_ROWS, tokens))) else "ragged_dot")
+
+
+def _product_rows(tile_rows):
+    return min(_PRODUCT_ROWS, tile_rows)
+
+
+def _nobodys_cleared(counts, *bufs):
+    """For ``lax.ragged_dot``, which promises nothing about the rows
+    past the last group's: those rows of ``bufs`` as zeros."""
+    mine = (jnp.arange(bufs[0].shape[0], dtype=jnp.int32)
+            < counts.sum())[:, None]
+    return [jnp.where(mine, buf, jnp.zeros((), buf.dtype)) for buf in bufs]
+
+
+def _ragged(lhs, rhs, counts):
+    return lax.ragged_dot(lhs, rhs, counts,
+                          preferred_element_type=jnp.float32)
+
+
+def _ragged_forward(xs, wt, counts, w_gate, w_up, w_down):
+    xs, wt = _nobodys_cleared(counts, xs, wt[:, :1])
+    h = (jax.nn.silu(_ragged(xs, w_gate, counts))
+         * _ragged(xs, w_up, counts)).astype(xs.dtype)
+    return (_ragged(h, w_down, counts) * wt).astype(xs.dtype)
+
+
+def _ragged_backward(xs, gs, wt, counts, w_gate, w_up, w_down):
+    def by_group(lhs, rhs):     # lhs[group's rows].T @ rhs[group's rows]
+        return lax.ragged_dot_general(
+            lhs, rhs, counts, lax.RaggedDotDimensionNumbers(
+                (((0,), (0,)), ((), ())), [0], []),
+            preferred_element_type=jnp.float32)
+
+    lanes = wt.shape[1]
+    xs, gs, wt = _nobodys_cleared(counts, xs, gs, wt[:, :1])
+    a = _ragged(xs, w_gate, counts)
+    b = _ragged(xs, w_up, counts)
+    sig = jax.nn.sigmoid(a)
+    s = a * sig
+    h = s * b
+    dh = _ragged(gs, w_down.swapaxes(1, 2), counts)  # before the weighting
+    dwt = jnp.sum(dh * h, axis=-1, keepdims=True)
+    dh = dh * wt
+    da = (dh * b * sig * (1.0 + a * (1.0 - sig))).astype(xs.dtype)
+    db = (dh * s).astype(xs.dtype)
+    dy = (gs.astype(jnp.float32) * wt).astype(xs.dtype)
+    dx = (_ragged(da, w_gate.swapaxes(1, 2), counts)
+          + _ragged(db, w_up.swapaxes(1, 2), counts)).astype(xs.dtype)
+    return (dx, jnp.broadcast_to(dwt, (dwt.shape[0], lanes)),
+            by_group(xs, da), by_group(xs, db),
+            by_group(h.astype(xs.dtype), dy))
+
+
 @functools.partial(jax.custom_vjp, nondiff_argnums=(0,))
 def _grouped_ffn(sizes, x, weight, plan, w_gate, w_up, w_down):
     """``y[n] = sum_e weight[n, e] * FFN_e(x[n])`` over the assignments
-    of ``plan``, a tile of one expert's rows at a time; as many tiles
-    as the routing needs, so no row is dropped and none is computed
-    that no expert got (beyond the padding of each expert's last tile).
-    A row is weighted where it is made, so that it travels once each
-    way and the way back only adds.
+    of ``plan``: the rows gathered once into expert order, every product
+    one grouped product over all of them with the experts' counts as
+    the groups' sizes, so no row is dropped and none is computed that
+    no expert got.  ``sizes`` is the plan's static sizes and how the
+    products run (``products_path``).  A row is weighted where it is
+    made, so that it travels once each way and the way back only adds.
     """
     return _grouped_ffn_fwd(sizes, x, weight, plan, w_gate, w_up, w_down)[0]
 
 
+def _kernels(path, tile_rows):
+    if path != "grouped":
+        return _ragged_forward, _ragged_backward
+    how = dict(tile_rows=_product_rows(tile_rows),
+               interpret=pallas_ops._pallas_mode()[1])
+    return (functools.partial(grouped_ffn.forward, **how),
+            functools.partial(grouped_ffn.backward, **how))
+
+
 def _grouped_ffn_fwd(sizes, x, weight, plan, w_gate, w_up, w_down):
-    n, e_held = weight.shape
-    tile_rows, _, rows = sizes
-
-    def body(t, buf):
-        with jax.named_scope("hvtpu:moe.dispatch"):
-            e, tok, valid = _expert_tile(plan, t, n, tile_rows)
-            xt = _gather_rows(x, tok, valid)
-            wt = jnp.take(weight, tok * e_held + e, mode="clip")
-        with jax.named_scope("hvtpu:moe.experts"):
-            a = _dot(xt, _expert(w_gate, e), ((1,), (0,)))
-            b = _dot(xt, _expert(w_up, e), ((1,), (0,)))
-            h = (jax.nn.silu(a) * b).astype(x.dtype)
-            yt = _dot(h, _expert(w_down, e), ((1,), (0,))) * wt[:, None]
-            return lax.dynamic_update_slice(
-                buf, yt.astype(x.dtype), (t * tile_rows, 0))
-
+    *sizes, path = sizes
     with jax.named_scope("hvtpu:moe.dispatch"):
-        buf = _row_buffer(rows, x.shape[1], x.dtype, plan.block_counts)
+        xs, wt = _to_experts((x,), weight, plan, sizes)
     with jax.named_scope("hvtpu:moe.experts"):
-        buf = lax.fori_loop(0, plan.expert_tiles.count, body, buf)
+        forward, _ = _kernels(path, sizes[0])
+        y = forward(xs, wt, plan.counts, w_gate, w_up, w_down)
     with jax.named_scope("hvtpu:moe.combine"):
-        out = _to_tokens(buf, plan, sizes, n)
+        out = _to_tokens(y, plan, sizes, x.shape[0])
     return out, (x, weight, plan, w_gate, w_up, w_down)
 
 
 def _grouped_ffn_bwd(sizes, res, g):
     x, weight, plan, w_gate, w_up, w_down = res
-    n, e_held = weight.shape
-    tile_rows, _, rows = sizes
-
-    def add_to_expert(acc, e, update):
-        return lax.dynamic_update_index_in_dim(
-            acc, _expert(acc, e) + update, e, axis=0)
-
-    def body(t, carry):
-        dx_buf, dweight, dw_gate, dw_up, dw_down = carry
-        with jax.named_scope("hvtpu:moe.dispatch"):
-            e, tok, valid = _expert_tile(plan, t, n, tile_rows)
-            xt = _gather_rows(x, tok, valid)
-            gt = _gather_rows(g, tok, valid)
-            wt = jnp.take(weight, tok * e_held + e, mode="clip")
-        with jax.named_scope("hvtpu:moe.experts"):
-            wg, wu, wd = (_expert(w, e) for w in (w_gate, w_up, w_down))
-            a = _dot(xt, wg, ((1,), (0,)))
-            b = _dot(xt, wu, ((1,), (0,)))
-            sig = jax.nn.sigmoid(a)
-            s = a * sig
-            h = s * b
-            dh = _dot(gt, wd, ((1,), (1,)))        # before the weighting
-            dwt = jnp.sum(dh * h, axis=-1)
-            dh = dh * wt[:, None]
-            da = (dh * b * sig * (1.0 + a * (1.0 - sig))).astype(x.dtype)
-            db = (dh * s).astype(x.dtype)
-            dy = (gt.astype(jnp.float32) * wt[:, None]).astype(x.dtype)
-            dw_down = add_to_expert(
-                dw_down, e, _dot(h.astype(x.dtype), dy, ((0,), (0,))))
-            dw_gate = add_to_expert(dw_gate, e, _dot(xt, da, ((0,), (0,))))
-            dw_up = add_to_expert(dw_up, e, _dot(xt, db, ((0,), (0,))))
-            dxt = (_dot(da, wg, ((1,), (1,)))
-                   + _dot(db, wu, ((1,), (1,))))
-            dx_buf = lax.dynamic_update_slice(
-                dx_buf, dxt.astype(x.dtype), (t * tile_rows, 0))
-        with jax.named_scope("hvtpu:moe.combine"):
-            # 512 scalars to their (token, expert): a scatter of
-            # scalars costs nanoseconds each, unlike one of rows
-            dweight = dweight.at[
-                jnp.where(valid, tok * e_held + e, n * e_held)].set(
-                    dwt, mode="drop", unique_indices=True,
-                    indices_are_sorted=True)
-        return dx_buf, dweight, dw_gate, dw_up, dw_down
-
-    with jax.named_scope("hvtpu:moe.combine"):
-        dx_buf = _row_buffer(rows, x.shape[1], x.dtype,
-                             plan.block_counts)
+    *sizes, path = sizes
+    with jax.named_scope("hvtpu:moe.dispatch"):
+        xs, gs, wt = _to_experts((x, g), weight, plan, sizes)
     with jax.named_scope("hvtpu:moe.experts"):
-        dx_buf, dweight, dw_gate, dw_up, dw_down = lax.fori_loop(
-            0, plan.expert_tiles.count, body,
-            (dx_buf, jnp.zeros((n * e_held,), jnp.float32),
-             *(jnp.zeros(w.shape, jnp.float32)
-               for w in (w_gate, w_up, w_down))))
+        _, backward = _kernels(path, sizes[0])
+        dx, dwt, dw_gate, dw_up, dw_down = backward(
+            xs, gs, wt, plan.counts, w_gate, w_up, w_down)
     with jax.named_scope("hvtpu:moe.combine"):
-        dx = _to_tokens(dx_buf, plan, sizes, n)
-    return (dx,
-            dweight.reshape(n, e_held).astype(weight.dtype), None,
+        dweight = _to_assignments(dwt, plan, sizes, *weight.shape)
+        dx = _to_tokens(dx, plan, sizes, x.shape[0])
+    return (dx, dweight.astype(weight.dtype), None,
             dw_gate.astype(w_gate.dtype), dw_up.astype(w_up.dtype),
             dw_down.astype(w_down.dtype))
 
@@ -479,8 +527,11 @@ def dropless_topk_moe(
     ``num_experts`` in f32 (``gate_w`` is ``[D, num_experts]``), keeps
     its ``top_k`` largest probabilities (divided by their sum if
     ``renormalise``), and the assignments whose expert is held here are
-    sorted by expert, gathered, multiplied a group at a time and added
-    back weighted (``_plan`` says how, without a scatter).  What the experts held elsewhere would add is left
+    sorted by expert, gathered, multiplied a group at a time (in the
+    kernels of ``ops/grouped_ffn.py`` where they run: ``products_path``;
+    ``hvtpu_moe_products_total{path=}`` counts, when a program is traced,
+    which it was) and added back weighted (``_plan`` says how, without a
+    scatter).  What the experts held elsewhere would add is left
     out: the shares of chips that hold disjoint ranges of experts and
     see the same tokens add up to the whole layer.  Nothing is dropped
     and there is no capacity: a routing that sends every token here
@@ -512,8 +563,13 @@ def dropless_topk_moe(
         hit = chosen.any(axis=1)
     with jax.named_scope("hvtpu:moe.dispatch"):
         plan, sizes = _plan(hit, top_k)
+    path = products_path(x.dtype, x.shape[1],
+                         expert_params["w_gate"].shape[2], x.shape[0],
+                         top_k, e_held)
+    metrics.note_moe_products(path)
     y = _grouped_ffn(
-        sizes, x, weight, plan, *(expert_params[k].astype(x.dtype)
-                                  for k in ("w_gate", "w_up", "w_down")))
+        (*sizes, path), x, weight, plan,
+        *(expert_params[k].astype(x.dtype)
+          for k in ("w_gate", "w_up", "w_down")))
     return y, {"rows_per_expert": hit.sum(axis=0, dtype=jnp.int32),
                "experts": top_i}

@@ -250,6 +250,37 @@ class TestHooks:
         assert metrics.REGISTRY.gauge(
             "hvtpu_steps_per_second").value() > 0
 
+    @pytest.mark.parametrize("width, path", [(16, "ragged_dot"),
+                                             (128, "grouped")])
+    def test_moe_products_are_counted_by_path_when_traced(
+            self, monkeypatch, width, path):
+        """``hvtpu_moe_products_total``: one a trace of a call site,
+        under the path the layer read off the backend and the shapes,
+        and none when the traced program runs again."""
+        import jax
+        import jax.numpy as jnp
+
+        from horovod_tpu.parallel import moe
+
+        monkeypatch.setenv("HVTPU_PALLAS_INTERPRET", "1")
+        monkeypatch.setattr(moe, "_TILE_ROWS", 16)
+        counter = metrics.REGISTRY.counter("hvtpu_moe_products_total")
+        before = {p: counter.value(path=p) for p in ("grouped", "ragged_dot")}
+        x = jnp.ones((32, width))
+        experts = {"w_gate": jnp.ones((2, width, width)),
+                   "w_up": jnp.ones((2, width, width)),
+                   "w_down": jnp.ones((2, width, width))}
+        layer = jax.jit(lambda x, experts: moe.dropless_topk_moe(
+            x, jnp.ones((width, 4)), experts, top_k=2, num_experts=4,
+            first_expert=0, renormalise=True)[0])
+        layer(x, experts)
+        layer(x, experts)
+        other = ({"grouped", "ragged_dot"} - {path}).pop()
+        assert counter.value(path=path) == before[path] + 1
+        assert counter.value(path=other) == before[other]
+        assert metrics.snapshot()["hvtpu_moe_products_total"][
+            "type"] == "counter"
+
     def test_eager_allreduce_counts_ops_and_bytes(self, hvt):
         import jax.numpy as jnp
 

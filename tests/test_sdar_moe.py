@@ -11,14 +11,14 @@ from horovod_tpu.parallel import moe
 N, D, F, E = 50, 16, 12, 16
 
 
-def _weights(seed, held):
+def _weights(seed, held, n=N, d=D, f=F):
     ks = jax.random.split(jax.random.PRNGKey(seed), 5)
-    x = jax.random.normal(ks[0], (N, D))
-    gate_w = jax.random.normal(ks[1], (D, E))
+    x = jax.random.normal(ks[0], (n, d))
+    gate_w = jax.random.normal(ks[1], (d, E))
     experts = {
-        "w_gate": 0.3 * jax.random.normal(ks[2], (held, D, F)),
-        "w_up": 0.3 * jax.random.normal(ks[3], (held, D, F)),
-        "w_down": 0.3 * jax.random.normal(ks[4], (held, F, D))}
+        "w_gate": 0.3 * jax.random.normal(ks[2], (held, d, f)),
+        "w_up": 0.3 * jax.random.normal(ks[3], (held, d, f)),
+        "w_down": 0.3 * jax.random.normal(ks[4], (held, f, d))}
     return x, gate_w, experts
 
 
@@ -96,7 +96,7 @@ def test_nothing_is_dropped_when_the_router_sends_every_token_here(
     _close(y, plain(x, gate_w, experts, **kwargs))
 
 
-def _rigged(routing, held, first_expert, dtype):
+def _rigged(routing, held, first_expert, dtype, **sizes):
     """Inputs whose router is rigged: ``uneven`` (as the weights fall,
     but nobody chooses the last held expert: the rows a reader finds
     for it lie past everything written), ``one_expert`` (every token's
@@ -104,9 +104,10 @@ def _rigged(routing, held, first_expert, dtype):
     whole number of tiles of 5 and of 10), ``all_here`` (every choice
     is a held expert: the buffer's worst case) and ``none_here`` (no
     choice is)."""
-    x, gate_w, experts = _weights(11, held)
+    x, gate_w, experts = _weights(11, held, **sizes)
     here = slice(first_expert, first_expert + held)
-    x = x.at[:, 0].set(10.0)
+    # the rigged coordinate outweighs the others' sum at any width
+    x = x.at[:, 0].set(10.0 * (x.shape[1] / D) ** 0.5)
     if routing == "uneven":
         gate_w = gate_w.at[0, first_expert + held - 1].set(-50.0)
     else:
@@ -151,8 +152,10 @@ def test_rows_of_the_buffers_that_nobody_wrote_are_never_used(
 
     rows, clean = run(0.0)
     _, poisoned = run(jnp.nan)
-    # forward's and backward's, in expert order and in token order
-    assert seen == {0.0: 4, jnp.nan: 4}
+    # in expert order the forward pass's rows and their weights, the
+    # backward pass's rows, cotangents and weights; in token order one
+    # a pass (what the products write is theirs to allocate)
+    assert seen == {0.0: 7, jnp.nan: 7}
     rows = np.asarray(rows)
     assert {"uneven": 0 < rows.sum() < N * top_k and len(set(rows)) > 1,
             "one_expert": rows[0] == N and N % tile_rows == 0,
@@ -165,16 +168,19 @@ def test_rows_of_the_buffers_that_nobody_wrote_are_never_used(
 
 
 @pytest.mark.parametrize("where, custom_calls", [
-    ("tpu", 4), ("tpu_without_pallas", 0), ("tpu_float16", 0), ("cpu", 0)])
+    ("tpu", 7), ("tpu_without_pallas", 0), ("tpu_float16", 2), ("cpu", 0)])
 def test_lowered_for_a_tpu_the_row_buffers_are_plain_custom_calls(
         monkeypatch, where, custom_calls):
-    """On a TPU each of the four allocations (forward's and backward's,
-    in expert order and in token order) is a kernel that does nothing,
-    a ``tpu_custom_call`` named for what it is, under the layer's
-    scopes, and with no kernel metadata, which XLA would print over
-    several lines where ``benchmark/scopes.py`` cannot follow; without
-    Pallas, for a type Mosaic cannot load, and off the TPU it is
-    ``lax.empty``."""
+    """On a TPU each of the seven allocations (in expert order the
+    forward pass's rows and their weights, the backward pass's rows,
+    cotangents and weights; in token order one a pass) is a kernel that
+    does nothing, a ``tpu_custom_call`` named for what it is, under the
+    layer's scopes, and with no kernel metadata, which XLA would print
+    over several lines where ``benchmark/scopes.py`` cannot follow;
+    without Pallas, for a type Mosaic cannot load (float16 rows: the
+    two buffers of float32 weights stay kernels), and off the TPU it is
+    ``lax.empty``.  Widths of 16 are none the grouped kernels take, so
+    the products here are ``lax.ragged_dot``'s."""
     import re
 
     from horovod_tpu.ops import pallas_ops
@@ -207,8 +213,8 @@ def test_lowered_for_a_tpu_the_row_buffers_are_plain_custom_calls(
         scopes.update(re.findall(
             r"hvtpu:moe\.\w+", re.search(
                 "^" + loc + r" = loc\(\"([^\"]*)\"", text, re.MULTILINE)[1]))
-    assert scopes == ({"hvtpu:moe.dispatch", "hvtpu:moe.combine"}
-                      if custom_calls else set())
+    assert scopes == {7: {"hvtpu:moe.dispatch", "hvtpu:moe.combine"},
+                      2: {"hvtpu:moe.dispatch"}, 0: set()}[custom_calls]
 
 
 def test_a_blocks_list_whether_or_not_token_and_row_share_a_sort_key():
@@ -265,3 +271,202 @@ def test_bfloat16_rows_come_back_in_bfloat16():
     assert y.dtype == jnp.bfloat16
     want = plain(x, gate_w, experts, top_k=3, first_expert=4)
     _close(y.astype(jnp.float32), want, rtol=0.05)
+
+
+# -- the grouped kernels (ops/grouped_ffn.py), interpreted ------------------
+
+WIDE = dict(n=64, d=128, f=256)     # widths the kernels take
+
+
+@pytest.fixture
+def grouped(monkeypatch):
+    """The kernels under the interpreter, a gather's tile of 32 rows
+    and a visit of 16: 64 tokens, top-3 of 4 held, a buffer of 192."""
+    monkeypatch.setenv("HVTPU_PALLAS_INTERPRET", "1")
+    monkeypatch.delenv("HVTPU_PALLAS", raising=False)
+    monkeypatch.setattr(moe, "_TILE_ROWS", 32)
+    monkeypatch.setattr(moe, "_PRODUCT_ROWS", 16)
+
+
+def _hits(routing):
+    """Which of 4 held experts each of 64 tokens chose, at most 3."""
+    n = WIDE["n"]
+    token = np.arange(n)[:, None]
+    expert = np.arange(4)[None, :]
+    rng = np.random.default_rng(3)
+    hit = {
+        # 32 rows an expert: every expert ends on a tile of 32
+        "even": (expert == token % 4) | (expert == (token + 1) % 4),
+        "an_expert_without_rows": (rng.random((n, 4)) < 0.4) & (expert != 1),
+        # 3 of 4 for every token: the buffer's worst case, all 192 rows
+        "every_token_here": expert != token % 4,
+        # 19, 30, 7 and 23 rows: no expert ends where a tile does
+        "off_every_tile": np.concatenate([
+            token < 19, token < 30, (40 <= token) & (token < 47),
+            token >= 41], axis=1),
+        "nobody_here": np.zeros((n, 4), bool),
+    }[routing]
+    return np.where(hit.sum(1, keepdims=True) > 3, expert != 0, hit)
+
+
+def _dense(x, weight, hit, w_gate, w_up, w_down):
+    """``sum_e weight[n, e] FFN_e(x[n])`` over the hits, every expert
+    over every token."""
+    y = jnp.zeros_like(x)
+    for e in range(hit.shape[1]):
+        h = jax.nn.silu(x @ w_gate[e]) * (x @ w_up[e])
+        y = y + jnp.where(hit[:, e], weight[:, e], 0.0)[:, None] * (
+            h @ w_down[e])
+    return y
+
+
+@pytest.mark.parametrize("poisoned", [False, True])
+@pytest.mark.parametrize("routing", [
+    "even", "an_expert_without_rows", "every_token_here", "off_every_tile",
+    "nobody_here"])
+def test_the_grouped_kernels_equal_every_expert_over_every_token(
+        monkeypatch, grouped, routing, poisoned):
+    """``_grouped_ffn`` through the Pallas kernels against the dense
+    sum, the result and the gradients of ``x``, ``weight`` and the
+    three weights, over routings whose groups end on every tile, on
+    none, leave an expert without rows, fill the buffer to its last
+    row, or send nothing.  ``poisoned``: every row of the buffers that
+    no assignment owns is NaN before the products, and everything
+    comes out finite and as from buffers of zeros, bit for bit."""
+    x, _, experts = _weights(21, 4, **WIDE)
+    hit = _hits(routing)
+    counts = hit.sum(0)
+    assert {"even": (counts == 32).all(),
+            "an_expert_without_rows": counts[1] == 0 < counts[0],
+            "every_token_here": counts.sum() == moe.buffer_rows(64, 3, 4),
+            "off_every_tile": (np.cumsum(counts) % 16 != 0).all(),
+            "nobody_here": counts.sum() == 0}[routing], counts
+    weight = jax.random.uniform(jax.random.PRNGKey(2), hit.shape)
+    target = jax.random.normal(jax.random.PRNGKey(9), x.shape)
+    names = ("w_gate", "w_up", "w_down")
+
+    def ours(x, weight, *w):
+        plan, sizes = moe._plan(jnp.asarray(hit), 3)
+        return moe._grouped_ffn((*sizes, "grouped"), x, weight, plan, *w)
+
+    def run(f):
+        return jax.jit(jax.value_and_grad(
+            lambda *a: jnp.sum(f(*a) * target), (0, 1, 2, 3, 4)))(
+                x, weight, *(experts[k] for k in names))
+
+    assert moe.products_path(x.dtype, 128, 256, 64, 3, 4) == "grouped"
+    got = jax.tree_util.tree_leaves(run(ours))
+    want = jax.tree_util.tree_leaves(run(
+        lambda x, weight, *w: _dense(x, weight, jnp.asarray(hit), *w)))
+    assert len(got) == 6
+    for g, w in zip(got, want):
+        assert np.isfinite(np.asarray(g)).all()
+        if routing == "nobody_here":
+            assert not np.asarray(g).any() and not np.asarray(w).any()
+        else:
+            _close(g, w)
+    if poisoned:
+        monkeypatch.setattr(
+            moe, "_row_buffer", lambda rows, width, dtype, near: jnp.full(
+                (rows, width), jnp.nan, dtype))
+        for g, clean in zip(jax.tree_util.tree_leaves(run(ours)), got):
+            np.testing.assert_array_equal(np.asarray(g), np.asarray(clean))
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+@pytest.mark.parametrize("routing", ["uneven", "one_expert", "all_here",
+                                     "none_here"])
+def test_through_the_kernels_the_layer_equals_the_plain_loop(
+        grouped, routing, dtype):
+    """``dropless_topk_moe`` with its products in the kernels, rigged
+    routers and all, against the plain loop: the result, the rows each
+    expert got, and the gradients of the tokens, the router and the
+    three weights; bfloat16 rows against the plain loop in float32."""
+    first_expert, held, top_k = 8, 4, 3
+    before = moe.metrics.REGISTRY.counter(
+        "hvtpu_moe_products_total").value(path="grouped")
+    x, gate_w, experts = _rigged(routing, held, first_expert, dtype, **WIDE)
+    kwargs = dict(top_k=top_k, first_expert=first_expert)
+    target = jax.random.normal(jax.random.PRNGKey(9), x.shape)
+
+    def run(f, x):
+        return jax.jit(jax.value_and_grad(
+            lambda *a: jnp.sum(f(*a).astype(jnp.float32) * target),
+            (0, 1, 2)))(x, gate_w, experts)
+
+    got = jax.tree_util.tree_leaves(
+        run(lambda *a: layer(*a, **kwargs)[0], x))
+    want = jax.tree_util.tree_leaves(
+        run(lambda *a: plain(*a, **kwargs), x.astype(jnp.float32)))
+    assert moe.metrics.REGISTRY.counter(
+        "hvtpu_moe_products_total").value(path="grouped") == before + 1
+    rows = np.asarray(layer(x, gate_w, experts, **kwargs)[1][
+        "rows_per_expert"])
+    n = WIDE["n"]
+    assert {"uneven": 0 < rows.sum() < n * top_k and rows[-1] == 0,
+            "one_expert": rows[0] == n,
+            "all_here": rows.sum() == n * top_k,
+            "none_here": rows.sum() == 0}[routing]
+    assert len(got) == 6
+    for g, w in zip(got, want):
+        g = np.asarray(g, np.float32)
+        assert np.isfinite(g).all()
+        if routing == "none_here":
+            assert not g.any()
+        else:
+            _close(g, w, rtol=1e-5 if dtype == jnp.float32 else 0.05)
+
+
+@pytest.mark.parametrize("counts", [
+    [0, 0, 0, 0], [5, 0, 70, 0], [16, 16, 16, 16], [0, 0, 0, 96],
+    [1, 1, 1, 93], [31, 33, 0, 17]])
+@pytest.mark.parametrize("empty_groups", [False, True])
+def test_a_walk_visits_every_tile_of_every_group_once(counts, empty_groups):
+    """``ops.grouped_ffn.visits``: group after group, every tile that
+    holds rows of the group and no other; a group without rows is
+    visited only if asked, and then names the tile the walk is at, so
+    that no block is fetched or written for it."""
+    from horovod_tpu.ops import grouped_ffn
+
+    rows, tile_rows = 96, 16
+    walk = grouped_ffn.visits(jnp.asarray(counts, jnp.int32), rows,
+                              tile_rows, empty_groups)
+    assert walk.offsets.tolist() == [0] + np.cumsum(counts).tolist()
+    assert walk.group.shape == walk.tile.shape == (rows // tile_rows + 3,)
+    made = list(zip(walk.group[:int(walk.count)].tolist(),
+                    walk.tile[:int(walk.count)].tolist()))
+    want, at = [], 0
+    for g, count in enumerate(counts):
+        start, end = at, at + count
+        at = end
+        if count:
+            want += [(g, t) for t in range(start // tile_rows,
+                                           (end - 1) // tile_rows + 1)]
+        elif empty_groups:
+            want.append((g, max(start - 1, 0) // tile_rows))
+    assert made == want
+    assert ((0 <= np.asarray(walk.tile))
+            & (np.asarray(walk.tile) < rows // tile_rows)).all()
+
+
+@pytest.mark.parametrize("where, dtype, width, tokens, path", [
+    ("tpu", jnp.bfloat16, 128, 1024, "grouped"),
+    ("tpu", jnp.float32, 512, 32768, "grouped"),
+    # a group's f32 weights and sums, twice over, crowd the tiles out
+    ("tpu", jnp.float32, 2048, 32768, "ragged_dot"),
+    ("tpu", jnp.bfloat16, 64, 1024, "ragged_dot"),      # the rehearsals
+    ("tpu", jnp.float16, 128, 1024, "ragged_dot"),
+    ("tpu", jnp.bfloat16, 128, 8, "ragged_dot"),        # no whole tile
+    ("tpu_without_pallas", jnp.bfloat16, 128, 1024, "ragged_dot"),
+    ("cpu", jnp.bfloat16, 128, 1024, "ragged_dot")])
+def test_how_the_products_run_is_read_off_the_backend_and_the_shapes(
+        monkeypatch, where, dtype, width, tokens, path):
+    from horovod_tpu.ops import pallas_ops
+
+    monkeypatch.delenv("HVTPU_PALLAS_INTERPRET", raising=False)
+    monkeypatch.delenv("HVTPU_PALLAS", raising=False)
+    if where == "tpu_without_pallas":
+        monkeypatch.setenv("HVTPU_PALLAS", "0")
+    monkeypatch.setattr(pallas_ops, "_on_tpu", lambda: where != "cpu")
+    assert moe.products_path(dtype, width, 768, tokens, 8, 16) == path
+    assert moe.products_path(dtype, 2048, width, tokens, 8, 16) == path
