@@ -35,4 +35,5 @@ __all__ = [
     # a submodule, imported when asked for (``from horovod_tpu.models
     # import looped``): the other jobs' set-up is imports first
     "looped",
+    "hybrid_moe",
 ]

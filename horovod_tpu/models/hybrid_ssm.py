@@ -16,11 +16,15 @@ W_in u``; ``logits = RMSNorm(h) E^T / logits_scaling`` (tied).
 
 *The Mamba-2 mixer*: ``[z, xBC, dt] = W_in u``; ``xBC = silu(conv(xBC)
 + b)``, a causal depthwise convolution over time; ``[x, B, C] = xBC``
-(``x`` in heads, ``B`` and ``C`` of the state's size, one group shared
-by all heads); ``delta = softplus(dt + dt_bias)``, ``A = -exp(A_log)``
-a head; ``H_t = exp(delta_t A) H_{t-1} + delta_t x_t (x) B_t``, ``y_t =
-H_t C_t + D x_t``; ``y = RMSNorm(y * silu(z))`` over all inner channels
-(gate first, then norm, one group); ``out = W_out y``.  The recurrence
+(``x`` in heads, ``B`` and ``C`` of the state's size in ``ssm_groups``
+groups, head ``h`` reading group ``h // (heads / groups)``: one group
+shared by all heads in Granite 4.0-H, eight in the ``nemotron_h`` stack
+of ``models/hybrid_moe.py``, which calls this mixer); ``delta =
+softplus(dt + dt_bias)``, ``A = -exp(A_log)`` a head; ``H_t =
+exp(delta_t A) H_{t-1} + delta_t x_t (x) B_t``, ``y_t = H_t C_t + D
+x_t``; ``y = RMSNorm(y * silu(z))`` over each group's inner channels
+(gate first, then norm; with one group over all of them); ``out = W_out
+y``.  The recurrence
 is computed in chunks (``ssd_scan``, the state-space-duality form):
 inside a chunk the masked product ``(L o C B^T)(delta x)`` with ``L_ij
 = exp(sum_{j<k<=i} delta_k A)``, the chunk's final state, and ``C H``
@@ -104,14 +108,15 @@ class HybridSSMConfig:
     logits_scaling: float
     rms_norm_eps: float
     compute_dtype: str = "bfloat16"
+    ssm_groups: int = 1          # B/C groups: head h reads group h // (H / G)
 
     @property
     def ssm_inner(self) -> int:
         return self.ssm_heads * self.ssm_head_dim
 
     @property
-    def conv_channels(self) -> int:      # x, B and C; one group
-        return self.ssm_inner + 2 * self.ssm_state
+    def conv_channels(self) -> int:      # x, and B and C of every group
+        return self.ssm_inner + 2 * self.ssm_groups * self.ssm_state
 
 
 def layer_groups(layer_types) -> List[Tuple[str, int]]:
@@ -125,6 +130,38 @@ def layer_groups(layer_types) -> List[Tuple[str, int]]:
             for kind, run in itertools.groupby(layer_types)]
 
 
+def mixer_start(cfg, n: int, *, in_proj, conv_w, out_proj, delta, a_log,
+                out_scale: float = 1.0, steps=(1e-3, 1e-1, 0.0)) -> Params:
+    """``n`` stacked Mamba-2 mixers' float32 parameters from a key each
+    (the keywords): normal(0.02) projections, the one back to the hidden
+    size times ``out_scale``; and Mamba-2's own start for what a
+    ``config.json`` has no key for: convolution taps ``U[-1/sqrt(K),
+    1/sqrt(K)]`` and a zero bias, ``A_log = log U[1, 16]``, ``dt_bias``
+    the inverse softplus of ``delta ~ logU[steps[0], steps[1]]`` held
+    above ``steps[2]``, ``D = 1``, a unit gate norm.  ``cfg`` brings the
+    mixer's sizes (``HybridSSMConfig``, or ``hybrid_moe``'s)."""
+    d, inner, h = cfg.hidden_size, cfg.ssm_inner, cfg.ssm_heads
+    least, most, floor = steps
+
+    def normal(k, shape, scale=1.0):
+        return 0.02 * scale * jax.random.normal(k, shape, jnp.float32)
+
+    step = jnp.maximum(floor, jnp.exp(jax.random.uniform(
+        delta, (n, h), jnp.float32, jnp.log(least), jnp.log(most))))
+    return dict(
+        in_proj=normal(in_proj, (n, d, inner + cfg.conv_channels + h)),
+        conv_w=jax.random.uniform(
+            conv_w, (n, cfg.conv_width, cfg.conv_channels), jnp.float32,
+            -cfg.conv_width ** -0.5, cfg.conv_width ** -0.5),
+        conv_b=jnp.zeros((n, cfg.conv_channels), jnp.float32),
+        dt_bias=step + jnp.log(-jnp.expm1(-step)),
+        A_log=jnp.log(jax.random.uniform(
+            a_log, (n, h), jnp.float32, 1.0, 16.0)),
+        D=jnp.ones((n, h), jnp.float32),
+        gate_norm=jnp.ones((n, inner), jnp.float32),
+        out_proj=normal(out_proj, (n, inner, d), out_scale))
+
+
 def init_params(key, cfg: HybridSSMConfig) -> Params:
     """Float32 parameters: normal(0.02) matrices, unit norm scales, and
     Mamba-2's own start for what ``config.json`` has no key for:
@@ -136,7 +173,6 @@ def init_params(key, cfg: HybridSSMConfig) -> Params:
     could tell a wrong scan from a right one.)"""
     d, f = cfg.hidden_size, cfg.mlp_width
     n_q, n_kv = cfg.num_heads * cfg.head_dim, cfg.num_kv_heads * cfg.head_dim
-    inner, h = cfg.ssm_inner, cfg.ssm_heads
 
     def normal(k, shape):
         return 0.02 * jax.random.normal(k, shape, jnp.float32)
@@ -153,20 +189,8 @@ def init_params(key, cfg: HybridSSMConfig) -> Params:
                      wv=normal(ks[4], (n, d, n_kv)),
                      wo=normal(ks[5], (n, n_q, d)))
             return p
-        delta = jnp.exp(jax.random.uniform(
-            ks[6], (n, h), jnp.float32, jnp.log(1e-3), jnp.log(1e-1)))
-        p.update(
-            in_proj=normal(ks[2], (n, d, inner + cfg.conv_channels + h)),
-            conv_w=jax.random.uniform(
-                ks[3], (n, cfg.conv_width, cfg.conv_channels), jnp.float32,
-                -cfg.conv_width ** -0.5, cfg.conv_width ** -0.5),
-            conv_b=jnp.zeros((n, cfg.conv_channels), jnp.float32),
-            dt_bias=delta + jnp.log(-jnp.expm1(-delta)),
-            A_log=jnp.log(jax.random.uniform(
-                ks[7], (n, h), jnp.float32, 1.0, 16.0)),
-            D=jnp.ones((n, h), jnp.float32),
-            gate_norm=jnp.ones((n, inner), jnp.float32),
-            out_proj=normal(ks[4], (n, inner, d)))
+        p.update(mixer_start(cfg, n, in_proj=ks[2], conv_w=ks[3],
+                             out_proj=ks[4], delta=ks[6], a_log=ks[7]))
         return p
 
     groups = layer_groups(cfg.layer_types)
@@ -205,31 +229,49 @@ def _chunk(carry, inputs, a_head, d_skip, causal):
     """One chunk of ``ssd_scan`` for every row: ``carry`` is the state
     at the end of the chunk before, f32 ``[B, H, P, N]``, and the
     document its last position belonged to; ``causal`` bool ``[Q, Q]``,
-    true where ``j <= i``."""
+    true where ``j <= i``.  ``b_in`` and ``c_out`` come in groups, ``[B,
+    Q, G, N]``, and the heads are taken as ``[G, H / G]`` (``gr``)
+    wherever they meet one: a reshape that moves nothing.  With one
+    group they come as ``[B, Q, N]`` and there is no such axis at all:
+    the products lose their ``g`` and the program is, to the last
+    instruction, that of a scan that knows no groups."""
     state, seg_before = carry
     x, dt, b_in, c_out, seg = inputs       # [B, Q, ...]
     dtype = x.dtype
+    groups = 1 if b_in.ndim == 3 else b_in.shape[2]
+
+    def by_group(a, axis):      # [..., H, ...] -> [..., G, H / G, ...]
+        return a if groups == 1 else a.reshape(
+            *a.shape[:axis], groups, -1, *a.shape[axis + 1:])
+
+    def product(spec, *operands):
+        return jnp.einsum(spec.replace("g", "") if groups == 1 else spec,
+                          *operands, preferred_element_type=jnp.float32)
+
     # cs_i: the log of the decay from the chunk's start through i
     cs = jnp.cumsum(dt * a_head, axis=1)                     # [B, Q, H]
     cs_h = cs.transpose(0, 2, 1)                             # [B, H, Q]
     seen = ((seg[:, :, None] == seg[:, None, :]) & causal)[:, None]
     log_l = jnp.where(seen, cs_h[..., :, None] - cs_h[..., None, :], 0.0)
     decay = jnp.where(seen, jnp.exp(log_l), 0.0)             # L [B, H, i, j]
-    cb = jnp.einsum("bin,bjn->bij", c_out, b_in,
-                    preferred_element_type=jnp.float32)
+    cb = product("bign,bjgn->bgij", c_out, b_in)
     dtx = x.astype(jnp.float32) * dt[..., None]              # delta x
-    y = jnp.einsum("bhij,bjhp->bihp", (decay * cb[:, None]).astype(dtype),
-                   dtx.astype(dtype), preferred_element_type=jnp.float32)
+    y = product(
+        "bgrij,bjgrp->bigrp",
+        (by_group(decay, 1) * jnp.expand_dims(cb, -3)).astype(dtype),
+        by_group(dtx.astype(dtype), 2)).reshape(x.shape)
     # what the state carried in adds, unless a document started since
     into = jnp.exp(cs) * (seg == seg_before[:, None])[..., None]
-    y = y + into[..., None] * jnp.einsum(
-        "bin,bhpn->bihp", c_out, state.astype(dtype),
-        preferred_element_type=jnp.float32)
+    y = y + into[..., None] * product(
+        "bign,bgrpn->bigrp", c_out, by_group(state.astype(dtype), 1)
+    ).reshape(x.shape)
     # the chunk's own final state, and what is left of the one carried
     last, seg_last = cs[:, -1:], seg[:, -1]
     to_end = jnp.exp(last - cs) * (seg == seg_last[:, None])[..., None]
-    own = jnp.einsum("bjhp,bjn->bhpn", (dtx * to_end[..., None]).astype(dtype),
-                     b_in, preferred_element_type=jnp.float32)
+    own = product(
+        "bjgrp,bjgn->bgrpn",
+        by_group((dtx * to_end[..., None]).astype(dtype), 2), b_in
+    ).reshape(state.shape)
     keep = jnp.exp(last[:, 0]) * (seg_last == seg_before)[:, None]
     state = state * keep[..., None, None] + own
     y = y + d_skip[:, None] * x.astype(jnp.float32)
@@ -241,14 +283,23 @@ def ssd_scan(x, dt, a_head, b_in, c_out, d_skip, segment, chunk: int):
     x_t (x) B_t``, the state zero at every document's first position.
 
     ``x`` ``[B, T, H, P]``, ``dt`` f32 ``[B, T, H]`` (positive), ``a_head``
-    f32 ``[H]`` (negative), ``b_in`` and ``c_out`` ``[B, T, N]``,
-    ``d_skip`` f32 ``[H]``, ``segment`` int ``[B, T]``.  Products take
+    f32 ``[H]`` (negative), ``b_in`` and ``c_out`` ``[B, T, G, N]`` in
+    ``G`` groups, head ``h`` reading group ``h // (H / G)`` (or ``[B, T,
+    N]``: one group), ``d_skip`` f32 ``[H]``, ``segment`` int ``[B, T]``.
+    Products take
     ``x``'s type and add up in f32; decays, cumulative sums and the
     carried state are f32.  The chunks of a row are walked in time, all
     rows at once, each chunk recomputed in the backward pass, so that
     the ``[H, chunk, chunk]`` decays exist for one chunk a row at a
     time."""
     b, t = x.shape[:2]
+    if b_in.ndim == 4 and b_in.shape[2] == 1:    # one group: no axis for it
+        b_in, c_out = b_in[:, :, 0], c_out[:, :, 0]
+    groups = 1 if b_in.ndim == 3 else b_in.shape[2]
+    if x.shape[2] % groups:
+        raise ValueError(f"{x.shape[2]} heads are no whole number a group "
+                         f"of {groups}")
+    metrics.note_ssm_groups(groups)
     chunk = min(chunk, t)
     pad = -t % chunk
     if pad:
@@ -278,7 +329,8 @@ def ssd_scan(x, dt, a_head, b_in, c_out, d_skip, segment, chunk: int):
 def mamba_mixer(cfg: HybridSSMConfig, p: Params, u, segment):
     """The mixer on ``u`` ``[B, T, D]`` (already normed)."""
     b, t, _ = u.shape
-    dtype, inner, n = u.dtype, cfg.ssm_inner, cfg.ssm_state
+    dtype, inner, groups = u.dtype, cfg.ssm_inner, cfg.ssm_groups
+    n = groups * cfg.ssm_state
     with jax.named_scope("hvtpu:ssm.proj"):
         wide = inner + cfg.conv_channels
         z_xbc = u @ p["in_proj"][:, :wide].astype(dtype)
@@ -292,16 +344,20 @@ def mamba_mixer(cfg: HybridSSMConfig, p: Params, u, segment):
         x = xbc[..., :inner].reshape(b, t, cfg.ssm_heads, cfg.ssm_head_dim)
         y = ssd_scan(
             x, jax.nn.softplus(dt + p["dt_bias"]), -jnp.exp(p["A_log"]),
-            xbc[..., inner:inner + n], xbc[..., inner + n:], p["D"],
+            xbc[..., inner:inner + n].reshape(b, t, groups, -1),
+            xbc[..., inner + n:].reshape(b, t, groups, -1), p["D"],
             segment, cfg.chunk_size).reshape(b, t, inner)
         # the gate's gradient comes back in the scan's type: without the
         # barrier the compiler regrouped it into heads in f32 first, a
         # relayout of 64-wide rows at twice the bytes (PERF.md, PR 32)
         y = lax.optimization_barrier(y)
     with jax.named_scope("hvtpu:ssm.gate"):
+        # every group's inner / G channels by their own mean square
         y = rms_norm(
-            y.astype(jnp.float32) * jax.nn.silu(z.astype(jnp.float32)),
-            p["gate_norm"], cfg.rms_norm_eps).astype(dtype)
+            (y.astype(jnp.float32) * jax.nn.silu(z.astype(jnp.float32))
+             ).reshape(b, t, groups, -1),
+            p["gate_norm"].reshape(groups, -1), cfg.rms_norm_eps
+        ).reshape(b, t, inner).astype(dtype)
     with jax.named_scope("hvtpu:ssm.proj"):
         return y @ p["out_proj"].astype(dtype)
 
@@ -524,18 +580,23 @@ def logits_of(params: Params, hidden, cfg: HybridSSMConfig):
             preferred_element_type=jnp.float32) / cfg.logits_scaling
 
 
+def weighted_next_token_cross_entropy(logits, batch):
+    """The mean over the batch's weighted positions of ``w_t CE(logits_t,
+    x_{t+1})``, from f32 ``logits`` ``[B, T, V]``."""
+    with jax.named_scope("hvtpu:lm_head"):
+        label = jnp.roll(batch["x"], -1, axis=1)
+        ce = (jax.nn.logsumexp(logits, axis=-1) - jnp.take_along_axis(
+            logits, label[..., None], axis=-1)[..., 0])
+        w = batch["w"].astype(jnp.float32)
+        return jnp.sum(w * ce) / jnp.sum(w)
+
+
 def next_token_loss(params: Params, batch, cfg: HybridSSMConfig):
     """``batch``: ``x`` int ``[B, T]``, ``segment`` (the document's
     index at every position) and ``w``, the weight of position ``t``'s
     prediction of ``x[t + 1]``: 0 where that token belongs to another
     document or lies past the row's end.  The mean of the weighted
     cross-entropies over the batch's weighted positions."""
-    x = batch["x"]
-    hidden = hidden_states(params, x, cfg, batch["segment"])
-    logits = logits_of(params, hidden, cfg)
-    with jax.named_scope("hvtpu:lm_head"):
-        label = jnp.roll(x, -1, axis=1)
-        ce = (jax.nn.logsumexp(logits, axis=-1) - jnp.take_along_axis(
-            logits, label[..., None], axis=-1)[..., 0])
-        w = batch["w"].astype(jnp.float32)
-        return jnp.sum(w * ce) / jnp.sum(w)
+    hidden = hidden_states(params, batch["x"], cfg, batch["segment"])
+    return weighted_next_token_cross_entropy(
+        logits_of(params, hidden, cfg), batch)

@@ -739,6 +739,27 @@ def note_moe_products(path: str) -> None:
         "Pallas kernels or lax.ragged_dot.").inc(path=path)
 
 
+def note_moe_layer(rule: str, form: str) -> None:
+    """Count one call of ``parallel.moe.dropless_topk_moe`` by its
+    routing rule (``"softmax"``, or ``"sigmoid_bias"``: sigmoid scores,
+    the choice by score plus a bias, the weights without it) and by its
+    experts' form (``"gated"``: three weights, SiLU-gated; ``"relu2"``:
+    two weights with a squared ReLU between).  Called while a program
+    is traced, once a call site and a trace, like
+    ``note_moe_products``."""
+    REGISTRY.counter(
+        "hvtpu_moe_router_total",
+        "Calls of the dropless expert layer, counted when a program is "
+        "traced, by the rule its router was built with: softmax "
+        "probabilities, or sigmoid scores chosen by score plus a "
+        "selection bias.").inc(rule=rule)
+    REGISTRY.counter(
+        "hvtpu_moe_experts_form_total",
+        "Calls of the dropless expert layer, counted when a program is "
+        "traced, by the form of its experts: three SiLU-gated weights, or "
+        "two weights with a squared ReLU between.").inc(form=form)
+
+
 def note_moe_routing(rows_per_expert, buffer_rows=None) -> None:
     """Record what a step's expert layers saw: ``rows_per_expert`` is
     the ``[layers, experts_held]`` (or ``[experts_held]``) count that
@@ -789,6 +810,16 @@ def note_ssm_chunks(chunks: int) -> None:
         "hvtpu_ssm_chunks_total",
         "Chunks the chunked state-space scan walks in one call (rows x "
         "chunks a row), counted when a program is traced.").inc(float(chunks))
+
+
+def note_ssm_groups(groups: int) -> None:
+    """Record the B/C groups of the last ``models.hybrid_ssm.ssd_scan``
+    built: the heads of a group share one ``B`` and one ``C``.  Called
+    while a program is traced, like ``note_ssm_chunks``."""
+    REGISTRY.gauge(
+        "hvtpu_ssm_groups",
+        "B/C groups of the last chunked state-space scan built: 1 where "
+        "every head reads the same B and C.").set(float(groups))
 
 
 def note_packed_batch(segment) -> None:

@@ -317,7 +317,16 @@ def block_heads(heads: int, kv_heads: int, hd: int):
     """``(query heads, key/value heads)`` of a block, from the shapes
     alone: the fewest key/value heads that fill whole 128-lane vectors,
     and more of them, as many as divide ``kv_heads`` evenly, while their
-    query heads stay at or under ``_BLOCK_QUERY_HEADS``."""
+    query heads stay at or under ``_BLOCK_QUERY_HEADS``.  A group is
+    never divided: the fewest key/value heads bring all their query
+    heads, so a group wider than ``_BLOCK_QUERY_HEADS`` (16 query heads
+    on one key/value head of 128: the one-mixer stack of
+    ``models/hybrid_moe.py``) makes a step of more query heads than
+    that, all walked against the one key/value block the step fetched,
+    ``dk`` and ``dv`` summed over them in the step (512 x 512 blocks of
+    16 x 128 lanes fit the VMEM the kernels ask for: in the forward
+    kernel 17 MiB of operands and results in flight, two buffers each,
+    and 12 MiB of f32 sums)."""
     group = heads // kv_heads
     fewest = max(1, _LANES // hd)
     most = max(fewest, _BLOCK_QUERY_HEADS // group)

@@ -18,7 +18,7 @@ from __future__ import annotations
 
 import functools
 import math
-from typing import Any, Callable, NamedTuple, Tuple
+from typing import Any, Callable, NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -366,15 +366,16 @@ def _dot(a, b, dims, precision=None):
 
 
 def products_path(dtype, d: int, f: int, tokens: int, top_k: int,
-                  experts_held: int) -> str:
+                  experts_held: int, form: str = "gated") -> str:
     """How the experts' products of a layer of these shapes run, from
     what the code can see: ``"grouped"``, in the Pallas kernels of
     ``ops/grouped_ffn.py``, where ``ops.pallas_ops`` compiles kernels (a
-    TPU backend, or the interpreter in tests) and the shapes are ones
-    they take; elsewhere ``"ragged_dot"``, the same products over the
-    same buffers as ``lax.ragged_dot``."""
+    TPU backend, or the interpreter in tests), the experts are of the
+    form the kernels multiply (``"gated"``: three weights) and the
+    shapes are ones they take; elsewhere ``"ragged_dot"``, the same
+    products over the same buffers as ``lax.ragged_dot``."""
     use, _ = pallas_ops._pallas_mode()
-    return ("grouped" if use and grouped_ffn.supports(
+    return ("grouped" if use and form == "gated" and grouped_ffn.supports(
         dtype, d, f, buffer_rows(tokens, top_k, experts_held),
         _product_rows(min(_TILE_ROWS, tokens))) else "ragged_dot")
 
@@ -396,51 +397,62 @@ def _ragged(lhs, rhs, counts):
                           preferred_element_type=jnp.float32)
 
 
-def _ragged_forward(xs, wt, counts, w_gate, w_up, w_down):
+def _hidden(pre):
+    """What an expert's first products ``pre`` (f32) make of a row, and
+    its slope by each of them: ``silu(a) * b`` of two (the gated form),
+    ``relu(a) ** 2`` of one."""
+    if len(pre) == 2:
+        a, b = pre
+        sig = jax.nn.sigmoid(a)
+        s = a * sig
+        return s * b, (b * sig * (1.0 + a * (1.0 - sig)), s)
+    r = jax.nn.relu(pre[0])
+    return r * r, (2.0 * r,)
+
+
+def _ragged_forward(xs, wt, counts, *weights):
+    *w_in, w_down = weights
     xs, wt = _nobodys_cleared(counts, xs, wt[:, :1])
-    h = (jax.nn.silu(_ragged(xs, w_gate, counts))
-         * _ragged(xs, w_up, counts)).astype(xs.dtype)
+    h = _hidden([_ragged(xs, w, counts) for w in w_in])[0].astype(xs.dtype)
     return (_ragged(h, w_down, counts) * wt).astype(xs.dtype)
 
 
-def _ragged_backward(xs, gs, wt, counts, w_gate, w_up, w_down):
+def _ragged_backward(xs, gs, wt, counts, *weights):
     def by_group(lhs, rhs):     # lhs[group's rows].T @ rhs[group's rows]
         return lax.ragged_dot_general(
             lhs, rhs, counts, lax.RaggedDotDimensionNumbers(
                 (((0,), (0,)), ((), ())), [0], []),
             preferred_element_type=jnp.float32)
 
+    *w_in, w_down = weights
     lanes = wt.shape[1]
     xs, gs, wt = _nobodys_cleared(counts, xs, gs, wt[:, :1])
-    a = _ragged(xs, w_gate, counts)
-    b = _ragged(xs, w_up, counts)
-    sig = jax.nn.sigmoid(a)
-    s = a * sig
-    h = s * b
+    h, slopes = _hidden([_ragged(xs, w, counts) for w in w_in])
     dh = _ragged(gs, w_down.swapaxes(1, 2), counts)  # before the weighting
     dwt = jnp.sum(dh * h, axis=-1, keepdims=True)
     dh = dh * wt
-    da = (dh * b * sig * (1.0 + a * (1.0 - sig))).astype(xs.dtype)
-    db = (dh * s).astype(xs.dtype)
+    d_pre = [(dh * slope).astype(xs.dtype) for slope in slopes]
     dy = (gs.astype(jnp.float32) * wt).astype(xs.dtype)
-    dx = (_ragged(da, w_gate.swapaxes(1, 2), counts)
-          + _ragged(db, w_up.swapaxes(1, 2), counts)).astype(xs.dtype)
+    dx = sum(_ragged(d, w.swapaxes(1, 2), counts)
+             for d, w in zip(d_pre, w_in)).astype(xs.dtype)
     return (dx, jnp.broadcast_to(dwt, (dwt.shape[0], lanes)),
-            by_group(xs, da), by_group(xs, db),
+            *(by_group(xs, d) for d in d_pre),
             by_group(h.astype(xs.dtype), dy))
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(0,))
-def _grouped_ffn(sizes, x, weight, plan, w_gate, w_up, w_down):
+def _grouped_ffn(sizes, x, weight, plan, weights):
     """``y[n] = sum_e weight[n, e] * FFN_e(x[n])`` over the assignments
     of ``plan``: the rows gathered once into expert order, every product
     one grouped product over all of them with the experts' counts as
     the groups' sizes, so no row is dropped and none is computed that
-    no expert got.  ``sizes`` is the plan's static sizes and how the
-    products run (``products_path``).  A row is weighted where it is
+    no expert got.  ``weights`` are the experts' stacked matrices, the
+    last the one back to ``D``: three of the gated form, two of the
+    ungated (``_hidden``).  ``sizes`` is the plan's static sizes and how
+    the products run (``products_path``).  A row is weighted where it is
     made, so that it travels once each way and the way back only adds.
     """
-    return _grouped_ffn_fwd(sizes, x, weight, plan, w_gate, w_up, w_down)[0]
+    return _grouped_ffn_fwd(sizes, x, weight, plan, weights)[0]
 
 
 def _kernels(path, tile_rows):
@@ -452,33 +464,31 @@ def _kernels(path, tile_rows):
             functools.partial(grouped_ffn.backward, **how))
 
 
-def _grouped_ffn_fwd(sizes, x, weight, plan, w_gate, w_up, w_down):
+def _grouped_ffn_fwd(sizes, x, weight, plan, weights):
     *sizes, path = sizes
     with jax.named_scope("hvtpu:moe.dispatch"):
         xs, wt = _to_experts((x,), weight, plan, sizes)
     with jax.named_scope("hvtpu:moe.experts"):
         forward, _ = _kernels(path, sizes[0])
-        y = forward(xs, wt, plan.counts, w_gate, w_up, w_down)
+        y = forward(xs, wt, plan.counts, *weights)
     with jax.named_scope("hvtpu:moe.combine"):
         out = _to_tokens(y, plan, sizes, x.shape[0])
-    return out, (x, weight, plan, w_gate, w_up, w_down)
+    return out, (x, weight, plan, weights)
 
 
 def _grouped_ffn_bwd(sizes, res, g):
-    x, weight, plan, w_gate, w_up, w_down = res
+    x, weight, plan, weights = res
     *sizes, path = sizes
     with jax.named_scope("hvtpu:moe.dispatch"):
         xs, gs, wt = _to_experts((x, g), weight, plan, sizes)
     with jax.named_scope("hvtpu:moe.experts"):
         _, backward = _kernels(path, sizes[0])
-        dx, dwt, dw_gate, dw_up, dw_down = backward(
-            xs, gs, wt, plan.counts, w_gate, w_up, w_down)
+        dx, dwt, *d_weights = backward(xs, gs, wt, plan.counts, *weights)
     with jax.named_scope("hvtpu:moe.combine"):
         dweight = _to_assignments(dwt, plan, sizes, *weight.shape)
         dx = _to_tokens(dx, plan, sizes, x.shape[0])
     return (dx, dweight.astype(weight.dtype), None,
-            dw_gate.astype(w_gate.dtype), dw_up.astype(w_up.dtype),
-            dw_down.astype(w_down.dtype))
+            tuple(dw.astype(w.dtype) for dw, w in zip(d_weights, weights)))
 
 
 _grouped_ffn.defvjp(_grouped_ffn_fwd, _grouped_ffn_bwd)
@@ -517,20 +527,36 @@ def dropless_topk_moe(
     num_experts: int,
     first_expert: int,
     renormalise: bool,
+    selection_bias: Optional[jax.Array] = None,
+    scale: float = 1.0,
 ) -> Tuple[jax.Array, dict]:
     """The part of a top-k expert layer that the experts held here give.
 
     The layer is told which experts it holds: ``expert_params`` are the
-    SiLU-gated weights ``{"w_gate", "w_up": [E_held, D, F], "w_down":
-    [E_held, F, D]}`` of experts ``first_expert`` to ``first_expert +
-    E_held`` of ``num_experts``.  Every token is routed over all
-    ``num_experts`` in f32 (``gate_w`` is ``[D, num_experts]``), keeps
-    its ``top_k`` largest probabilities (divided by their sum if
-    ``renormalise``), and the assignments whose expert is held here are
+    stacked weights of experts ``first_expert`` to ``first_expert +
+    E_held`` of ``num_experts``, in one of two forms, told apart by
+    their names: *gated*, ``{"w_gate", "w_up": [E_held, D, F], "w_down":
+    [E_held, F, D]}``, an expert ``W_down (silu(W_gate u) * W_up u)``;
+    or *ungated*, ``{"w_up", "w_down"}`` alone, an expert ``W_down
+    relu(W_up u) ** 2``.
+
+    Every token is routed over all ``num_experts`` in f32 (``gate_w`` is
+    ``[D, num_experts]``) by one of two rules.  Without
+    ``selection_bias``: the softmax of the router's logits, a token
+    keeping its ``top_k`` largest probabilities.  With it (f32
+    ``[num_experts]``): the scores are sigmoids of the logits, the
+    ``top_k`` experts are *chosen* by score plus bias and *weighted* by
+    the score without it; the bias is a buffer that steers the load and
+    gets no gradient.  Either way the kept weights are divided by their
+    sum if ``renormalise`` and multiplied by ``scale``.
+
+    The assignments whose expert is held here are
     sorted by expert, gathered, multiplied a group at a time (in the
     kernels of ``ops/grouped_ffn.py`` where they run: ``products_path``;
     ``hvtpu_moe_products_total{path=}`` counts, when a program is traced,
-    which it was) and added back weighted (``_plan`` says how, without a
+    which it was, ``hvtpu_moe_router_total{rule=}`` and
+    ``hvtpu_moe_experts_form_total{form=}`` the rule and the form) and
+    added back weighted (``_plan`` says how, without a
     scatter).  What the experts held elsewhere would add is left
     out: the shares of chips that hold disjoint ranges of experts and
     see the same tokens add up to the whole layer.  Nothing is dropped
@@ -546,7 +572,10 @@ def dropless_topk_moe(
       ``experts`` int32 ``[N, top_k]``, every token's choice among all
       ``num_experts``).
     """
-    e_held = expert_params["w_gate"].shape[0]
+    names = (("w_gate", "w_up", "w_down") if "w_gate" in expert_params
+             else ("w_up", "w_down"))
+    form = "gated" if len(names) == 3 else "relu2"
+    e_held = expert_params["w_up"].shape[0]
     if not 0 <= first_expert <= num_experts - e_held:
         raise ValueError(
             f"experts {first_expert} to {first_expert + e_held} are not "
@@ -554,9 +583,21 @@ def dropless_topk_moe(
     with jax.named_scope("hvtpu:moe.route"):
         logits = jnp.dot(x.astype(jnp.float32), gate_w.astype(jnp.float32),
                          precision=lax.Precision.HIGHEST)
-        top_p, top_i = _top_k(jax.nn.softmax(logits, axis=-1), top_k)
+        if selection_bias is None:
+            top_p, top_i = _top_k(jax.nn.softmax(logits, axis=-1), top_k)
+        else:
+            scores = jax.nn.sigmoid(logits)
+            top_i = lax.top_k(
+                scores + lax.stop_gradient(selection_bias), top_k)[1]
+            # the chosen scores by a 0/1 selection, as ``_top_k``'s
+            # gradient is: a gather's gradient is a scatter of scalars
+            top_p = jnp.sum(jnp.where(
+                top_i[:, :, None] == jnp.arange(num_experts),
+                scores[:, None, :], 0.0), axis=-1)
         if renormalise:
             top_p = top_p / top_p.sum(axis=-1, keepdims=True)
+        if scale != 1.0:
+            top_p = scale * top_p
         held = first_expert + jnp.arange(e_held)
         chosen = top_i[:, :, None] == held                   # [N, k, E_held]
         weight = jnp.sum(jnp.where(chosen, top_p[:, :, None], 0.0), axis=1)
@@ -564,12 +605,13 @@ def dropless_topk_moe(
     with jax.named_scope("hvtpu:moe.dispatch"):
         plan, sizes = _plan(hit, top_k)
     path = products_path(x.dtype, x.shape[1],
-                         expert_params["w_gate"].shape[2], x.shape[0],
-                         top_k, e_held)
+                         expert_params["w_up"].shape[2], x.shape[0],
+                         top_k, e_held, form)
     metrics.note_moe_products(path)
+    metrics.note_moe_layer(
+        "softmax" if selection_bias is None else "sigmoid_bias", form)
     y = _grouped_ffn(
         (*sizes, path), x, weight, plan,
-        *(expert_params[k].astype(x.dtype)
-          for k in ("w_gate", "w_up", "w_down")))
+        tuple(expert_params[k].astype(x.dtype) for k in names))
     return y, {"rows_per_expert": hit.sum(axis=0, dtype=jnp.int32),
                "experts": top_i}

@@ -407,8 +407,25 @@ def test_sixteen_key_value_heads_of_one_query_head_each(
         assert np.array_equal(got, jnp.concatenate(want, axis=2)), name
 
 
+@pytest.mark.parametrize("kv_heads", [1, 2],
+                         ids=["one key/value head", "two key/value heads"])
+@pytest.mark.parametrize("packing", sorted(PACKINGS))
+def test_sixteen_query_heads_on_one_key_value_head(
+        interpreted, monkeypatch, packing, kv_heads):
+    """The one-mixer stack's shape (``models.hybrid_moe``: 32 query heads
+    of 128 on 2 key/value heads, document ids): a group above eight is
+    not divided, so a grid step walks all sixteen query heads of one
+    key/value head against the one key/value block it fetched; ``out``,
+    ``dq`` and ``dk``/``dv`` (summed over the sixteen) against the XLA
+    tiles."""
+    kernels_against_xla_tiles(
+        monkeypatch, 128, 16, packing, skipping=True, kv_heads=kv_heads)
+    assert block_heads_noted() == (16, 1)
+
+
 @pytest.mark.parametrize("heads, kv_heads, hd, block", [
     (4, 1, 128, (4, 1)),        # the transformer cell: as before
+    (32, 2, 128, (16, 1)),      # the one-mixer stack: a group is whole
     (32, 8, 64, (8, 2)),        # the hybrid cell: as before
     (16, 16, 128, (8, 8)),      # the looped cell
     (32, 4, 128, (8, 1)),       # a group of eight fills a step
@@ -420,7 +437,8 @@ def test_a_blocks_heads_are_chosen_from_the_shapes(heads, kv_heads, hd,
                                                    block):
     """The fewest key/value heads that fill whole vectors, and more
     while their query heads number at most eight and they divide the
-    key/value heads evenly; ``lse`` is laid out by it."""
+    key/value heads evenly (a group above eight goes whole, one
+    key/value head a step); ``lse`` is laid out by it."""
     assert flash_attention.block_heads(heads, kv_heads, hd) == block
     query, key_value = block
     assert (key_value * hd) % 128 == 0 and kv_heads % key_value == 0

@@ -520,3 +520,168 @@ def test_the_models_package_does_not_import_the_model():
         env={**os.environ, "JAX_PLATFORMS": "cpu"},
         cwd=os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
     assert out.stdout.strip() == "False", out.stderr[-1000:]
+
+
+# -- B and C in groups (``models/hybrid_moe.py``'s mixers: eight) ------------
+
+from benchmark.reference import nemotron_h as grouped_ref  # noqa: E402
+
+
+def scan_operands(groups, heads=8, head_dim=4, state=8, seq_len=64, seed=0,
+                  group_axis=True):
+    ks = jax.random.split(jax.random.PRNGKey(seed), 6)
+    bc = (2, seq_len, groups, state) if group_axis else (2, seq_len, state)
+    return dict(
+        x=jax.random.normal(ks[0], (2, seq_len, heads, head_dim)),
+        dt=jax.nn.softplus(jax.random.normal(ks[1], (2, seq_len, heads))),
+        a_head=-jnp.exp(jax.random.normal(ks[2], (heads,))),
+        b_in=jax.random.normal(ks[3], bc), c_out=jax.random.normal(ks[4], bc),
+        d_skip=jax.random.normal(ks[5], (heads,)))
+
+
+def scan_of(ops, segment, chunk=16):
+    with jax.default_matmul_precision("highest"):
+        return hs.ssd_scan(ops["x"], ops["dt"], ops["a_head"], ops["b_in"],
+                           ops["c_out"], ops["d_skip"], segment, chunk)
+
+
+def recurrence_of(ops, segment):
+    """``y`` a position at a time, a row at a time, by the plain
+    reference's recurrence (head ``h`` reads group ``h // (H / G)``)."""
+    rows = []
+    for i in range(segment.shape[0]):
+        y = grouped_ref.recurrence(
+            ops["x"][i], ops["dt"][i], ops["a_head"], ops["b_in"][i],
+            ops["c_out"][i], grouped_ref.first_of_a_document(segment[i]))
+        rows.append(y + ops["d_skip"][:, None] * ops["x"][i])
+    return jnp.stack(rows)
+
+
+@pytest.mark.parametrize("packing", sorted(PACKINGS))
+@pytest.mark.parametrize("groups", [2, 4])
+def test_the_scan_with_groups_equals_the_recurrence(groups, packing):
+    """Forward and every operand's gradient, with resets inside a chunk
+    and on a chunk's edge (``PACKINGS``, chunks of 16)."""
+    segment = jnp.asarray(batch_of(PACKINGS[packing], 64)["segment"])
+    ops = scan_operands(groups)
+    target = jax.random.normal(jax.random.PRNGKey(7), ops["x"].shape)
+    got, got_grads = jax.value_and_grad(
+        lambda ops: jnp.sum(scan_of(ops, segment) * target))(ops)
+    want, want_grads = jax.value_and_grad(
+        lambda ops: jnp.sum(recurrence_of(ops, segment) * target))(ops)
+    assert distance(scan_of(ops, segment), recurrence_of(ops, segment)) < RTOL
+    assert abs(float(got) - float(want)) < RTOL * abs(float(want))
+    for name in ops:
+        assert distance(got_grads[name], want_grads[name]) < RTOL, name
+
+
+def test_a_head_reads_its_own_group():
+    """With two groups the second half of the heads reads the second
+    group's ``B`` and ``C``: changing those moves their ``y`` alone."""
+    segment = jnp.zeros((2, 64), jnp.int32)
+    ops = scan_operands(2)
+    moved = dict(ops, b_in=ops["b_in"].at[:, :, 1].multiply(2.0))
+    one, other = scan_of(ops, segment), scan_of(moved, segment)
+    assert np.array_equal(one[:, :, :4], other[:, :, :4])
+    assert distance(other[:, :, 4:], one[:, :, 4:]) > 0.1
+
+
+def _chunk_with_one_group(carry, inputs, a_head, d_skip, causal):
+    """``hybrid_ssm._chunk`` as it was while ``B`` and ``C`` had no group
+    axis (PR 32's, kept here as the oracle of *bit for bit*)."""
+    state, seg_before = carry
+    x, dt, b_in, c_out, seg = inputs
+    dtype = x.dtype
+    cs = jnp.cumsum(dt * a_head, axis=1)
+    cs_h = cs.transpose(0, 2, 1)
+    seen = ((seg[:, :, None] == seg[:, None, :]) & causal)[:, None]
+    log_l = jnp.where(seen, cs_h[..., :, None] - cs_h[..., None, :], 0.0)
+    decay = jnp.where(seen, jnp.exp(log_l), 0.0)
+    cb = jnp.einsum("bin,bjn->bij", c_out, b_in,
+                    preferred_element_type=jnp.float32)
+    dtx = x.astype(jnp.float32) * dt[..., None]
+    y = jnp.einsum("bhij,bjhp->bihp", (decay * cb[:, None]).astype(dtype),
+                   dtx.astype(dtype), preferred_element_type=jnp.float32)
+    into = jnp.exp(cs) * (seg == seg_before[:, None])[..., None]
+    y = y + into[..., None] * jnp.einsum(
+        "bin,bhpn->bihp", c_out, state.astype(dtype),
+        preferred_element_type=jnp.float32)
+    last, seg_last = cs[:, -1:], seg[:, -1]
+    to_end = jnp.exp(last - cs) * (seg == seg_last[:, None])[..., None]
+    own = jnp.einsum("bjhp,bjn->bhpn", (dtx * to_end[..., None]).astype(dtype),
+                     b_in, preferred_element_type=jnp.float32)
+    keep = jnp.exp(last[:, 0]) * (seg_last == seg_before)[:, None]
+    state = state * keep[..., None, None] + own
+    y = y + d_skip[:, None] * x.astype(jnp.float32)
+    return (state, seg_last), y.astype(dtype)
+
+
+@pytest.mark.parametrize("group_axis", [False, True],
+                         ids=["B and C [B, T, N]", "B and C [B, T, 1, N]"])
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+def test_one_group_gives_bit_for_bit_what_the_scan_gave_before(
+        monkeypatch, dtype, group_axis):
+    """``ssd_scan`` with one group, handed ``B`` and ``C`` with the group
+    axis or, as before, without: ``y`` and every operand's gradient equal
+    the chunk PR 32 wrote to the last bit."""
+    segment = jnp.asarray(batch_of(PACKINGS["boundaries_inside_chunks"],
+                                   64)["segment"])
+    ops = scan_operands(1, group_axis=False)
+    ops = {k: v.astype(dtype) if k in ("x", "b_in", "c_out") else v
+           for k, v in ops.items()}
+    target = jax.random.normal(jax.random.PRNGKey(7), ops["x"].shape)
+
+    def run(ops):
+        def loss(ops):
+            y = hs.ssd_scan(ops["x"], ops["dt"], ops["a_head"], ops["b_in"],
+                            ops["c_out"], ops["d_skip"], segment, 16)
+            return jnp.sum(y.astype(jnp.float32) * target), y
+        (_, y), grads = jax.jit(jax.value_and_grad(loss, has_aux=True))(ops)
+        return [y] + [grads[k] for k in sorted(grads)]
+
+    handed = ops if not group_axis else dict(
+        ops, b_in=ops["b_in"][:, :, None], c_out=ops["c_out"][:, :, None])
+    now = run(handed)
+    with monkeypatch.context() as m:
+        m.setattr(hs, "_chunk", _chunk_with_one_group)
+        before = run(ops)
+    for got, want in zip(now, before):
+        assert np.array_equal(np.asarray(got).reshape(want.shape),
+                              np.asarray(want))
+
+
+GROUPED_TOY = dataclasses.replace(TOY, ssm_groups=2)
+
+
+def test_the_gated_norm_is_over_each_groups_channels():
+    """The mixer with two groups against the plain reference's
+    (``benchmark/reference/nemotron_h.py``): each group's 32 of the 64
+    inner channels by its own mean square."""
+    cfg = GROUPED_TOY
+    p = jax.tree_util.tree_map(lambda a: a[0], hs.init_params(
+        jax.random.PRNGKey(2), dataclasses.replace(
+            cfg, layer_types=("mamba",)))["layers"][0])
+    p["in_proj"] = 10.0 * p["in_proj"]
+    p["gate_norm"] = 1.0 + 0.1 * jax.random.normal(
+        jax.random.PRNGKey(3), p["gate_norm"].shape)
+    assert p["conv_w"].shape[1] == cfg.ssm_inner + 2 * 2 * cfg.ssm_state
+    u = jax.random.normal(jax.random.PRNGKey(4), (2, 64, cfg.hidden_size))
+    segment = jnp.asarray(batch_of(PACKINGS["boundaries_inside_chunks"],
+                                   64)["segment"])
+    sizes = grouped_ref.Sizes(
+        pattern="M", num_heads=cfg.num_heads, num_kv_heads=cfg.num_kv_heads,
+        ssm_heads=cfg.ssm_heads, ssm_groups=2, first_expert=0, top_k=1,
+        norm_topk_prob=True, routed_scaling_factor=1.0,
+        rms_norm_eps=cfg.rms_norm_eps)
+    with jax.default_matmul_precision("highest"):
+        got = hs.mamba_mixer(cfg, p, u, segment)
+    want = jnp.stack([grouped_ref.mamba_mixer(p, u[i], segment[i], sizes)
+                      for i in range(2)])
+    assert distance(got, want) < RTOL
+    # a norm over all 64 channels is another function: by more than a
+    # rounding, so the comparison above can tell the two apart
+    gated = jax.random.normal(jax.random.PRNGKey(5), (64,)) * jnp.repeat(
+        jnp.array([1.0, 5.0]), 32)
+    by_group = grouped_ref.rms_norm(gated.reshape(2, 32), 1.0, 1e-5)
+    over_all = grouped_ref.rms_norm(gated, 1.0, 1e-5)
+    assert distance(by_group.reshape(64), over_all) > 0.5

@@ -281,6 +281,52 @@ class TestHooks:
         assert metrics.snapshot()["hvtpu_moe_products_total"][
             "type"] == "counter"
 
+    @pytest.mark.parametrize("rule, form", [
+        ("softmax", "gated"), ("softmax", "relu2"),
+        ("sigmoid_bias", "gated"), ("sigmoid_bias", "relu2")])
+    def test_the_expert_layers_rule_and_form_are_counted_when_traced(
+            self, monkeypatch, rule, form):
+        """``hvtpu_moe_router_total{rule=}`` and
+        ``hvtpu_moe_experts_form_total{form=}``: one each a trace of a
+        call site, by the routing rule (a selection bias or none) and by
+        the weights the experts bring, chosen apart; none when the traced
+        program runs again."""
+        import jax
+        import jax.numpy as jnp
+
+        from horovod_tpu.parallel import moe
+
+        monkeypatch.setattr(moe, "_TILE_ROWS", 16)
+        router = metrics.REGISTRY.counter("hvtpu_moe_router_total")
+        forms = metrics.REGISTRY.counter("hvtpu_moe_experts_form_total")
+        rules, shapes = ("softmax", "sigmoid_bias"), ("gated", "relu2")
+        before = ({r: router.value(rule=r) for r in rules},
+                  {f: forms.value(form=f) for f in shapes})
+        experts = {"w_up": jnp.ones((2, 16, 16)),
+                   "w_down": jnp.ones((2, 16, 16))}
+        if form == "gated":
+            experts["w_gate"] = jnp.ones((2, 16, 16))
+        bias = None if rule == "softmax" else jnp.zeros((4,))
+        layer = jax.jit(lambda x, experts: moe.dropless_topk_moe(
+            x, jnp.ones((16, 4)), experts, top_k=2, num_experts=4,
+            first_expert=0, renormalise=True, selection_bias=bias)[0])
+        layer(jnp.ones((32, 16)), experts)
+        layer(jnp.ones((32, 16)), experts)
+        assert {r: router.value(rule=r) for r in rules} == {
+            **before[0], rule: before[0][rule] + 1}
+        assert {f: forms.value(form=f) for f in shapes} == {
+            **before[1], form: before[1][form] + 1}
+        for name in ("hvtpu_moe_router_total",
+                     "hvtpu_moe_experts_form_total"):
+            assert metrics.snapshot()[name]["type"] == "counter"
+
+    def test_the_scans_groups_are_a_gauge(self):
+        metrics.note_ssm_groups(8)
+        assert metrics.REGISTRY.gauge("hvtpu_ssm_groups").value() == 8.0
+        metrics.note_ssm_groups(1)
+        assert metrics.REGISTRY.gauge("hvtpu_ssm_groups").value() == 1.0
+        assert metrics.snapshot()["hvtpu_ssm_groups"]["type"] == "gauge"
+
     def test_eager_allreduce_counts_ops_and_bytes(self, hvt):
         import jax.numpy as jnp
 

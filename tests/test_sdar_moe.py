@@ -347,7 +347,8 @@ def test_the_grouped_kernels_equal_every_expert_over_every_token(
 
     def ours(x, weight, *w):
         plan, sizes = moe._plan(jnp.asarray(hit), 3)
-        return moe._grouped_ffn((*sizes, "grouped"), x, weight, plan, *w)
+        return moe._grouped_ffn((*sizes, "grouped"), x, weight, plan,
+                                tuple(w))
 
     def run(f):
         return jax.jit(jax.value_and_grad(
@@ -470,3 +471,161 @@ def test_how_the_products_run_is_read_off_the_backend_and_the_shapes(
     monkeypatch.setattr(pallas_ops, "_on_tpu", lambda: where != "cpu")
     assert moe.products_path(dtype, width, 768, tokens, 8, 16) == path
     assert moe.products_path(dtype, 2048, width, tokens, 8, 16) == path
+
+
+# -- the other routing rule and the other form of expert ---------------------
+# (``models/hybrid_moe.py``'s: sigmoid scores chosen by score plus a
+# selection bias; two weights with a squared ReLU between)
+
+SCALE = 2.5
+
+
+def _ungated(seed, held, n=N):
+    x, gate_w, experts = _weights(seed, held, n=n)
+    bias = 0.2 * jax.random.normal(jax.random.PRNGKey(seed + 100), (E,))
+    return x, 0.5 * gate_w, {k: experts[k] for k in ("w_up", "w_down")}, bias
+
+
+def plain_sigmoid(x, gate_w, experts, bias, *, top_k, first_expert,
+                  renormalise=True, scale=SCALE):
+    """Every held expert over every token with a 0/1 choice: the choice
+    by ``s + b``, the weights ``s`` without ``b``."""
+    s = jax.nn.sigmoid(x @ gate_w)
+    order = jnp.argsort(-(s + bias), axis=-1, stable=True)[:, :top_k]
+    w = jnp.sum(jax.nn.one_hot(order, E), axis=1) * s
+    if renormalise:
+        w = w / w.sum(-1, keepdims=True)
+    y = jnp.zeros_like(x)
+    for e in range(experts["w_up"].shape[0]):
+        h = jnp.square(jax.nn.relu(x @ experts["w_up"][e]))
+        y = y + scale * w[:, first_expert + e, None] * (
+            h @ experts["w_down"][e])
+    return y
+
+
+def sigmoid_layer(x, gate_w, experts, bias, *, top_k, first_expert,
+                  renormalise=True, scale=SCALE):
+    return moe.dropless_topk_moe(
+        x, gate_w, experts, top_k=top_k, num_experts=E,
+        first_expert=first_expert, renormalise=renormalise,
+        selection_bias=bias, scale=scale)
+
+
+@pytest.mark.parametrize("tile_rows", [4, 512])
+@pytest.mark.parametrize("first_expert, held, top_k, renormalise", [
+    (0, 4, 3, True), (8, 4, 6, True), (12, 4, 8, False), (0, 16, 2, True)])
+def test_sigmoid_routed_relu2_experts_equal_the_plain_loop(
+        monkeypatch, tile_rows, first_expert, held, top_k, renormalise):
+    """The result, the rows, and the gradients of ``x``, the router and
+    both weights; the selection bias gets exact zeros."""
+    monkeypatch.setattr(moe, "_TILE_ROWS", tile_rows)
+    args = _ungated(first_expert + held, held)
+    kwargs = dict(top_k=top_k, first_expert=first_expert,
+                  renormalise=renormalise)
+    y, routing = jax.jit(lambda *a: sigmoid_layer(*a, **kwargs))(*args)
+    _close(y, plain_sigmoid(*args, **kwargs))
+    top_i = np.asarray(routing["experts"])
+    want_rows = [(top_i == first_expert + e).sum() for e in range(held)]
+    assert routing["rows_per_expert"].tolist() == want_rows
+    target = jax.random.normal(jax.random.PRNGKey(9), (N, D))
+    got = jax.grad(
+        lambda *a: jnp.sum(sigmoid_layer(*a, **kwargs)[0] * target),
+        argnums=(0, 1, 2, 3))(*args)
+    want = jax.grad(
+        lambda *a: jnp.sum(plain_sigmoid(*a, **kwargs) * target),
+        argnums=(0, 1, 2, 3))(*args)
+    assert not np.any(got[3]) and not np.any(want[3])
+    for g, w in zip(jax.tree_util.tree_leaves(got[:3]),
+                    jax.tree_util.tree_leaves(want[:3])):
+        _close(g, w)
+
+
+def test_the_selection_bias_changes_the_choice_and_not_the_weights():
+    """A bias that lifts the held experts into every token's choice:
+    every token now comes here, and a chosen expert's weight is still
+    its score over the chosen scores' sum, times the scale, as if the
+    bias were not there."""
+    first_expert, held, top_k = 8, 4, 4
+    x, gate_w, experts, bias = _ungated(7, held)
+    lifted = bias.at[first_expert:first_expert + held].add(10.0)
+    before = sigmoid_layer(x, gate_w, experts, bias, top_k=top_k,
+                           first_expert=first_expert)[1]
+    y, after = sigmoid_layer(x, gate_w, experts, lifted, top_k=top_k,
+                             first_expert=first_expert)
+    assert before["rows_per_expert"].sum() < N * top_k
+    assert after["rows_per_expert"].tolist() == [N] * held
+    s = jax.nn.sigmoid(x @ gate_w)[:, first_expert:first_expert + held]
+    w = SCALE * s / s.sum(-1, keepdims=True)
+    want = sum(w[:, e, None] * (jnp.square(jax.nn.relu(
+        x @ experts["w_up"][e])) @ experts["w_down"][e])
+        for e in range(held))
+    _close(y, want)
+
+
+def test_the_scale_multiplies_the_routed_part():
+    x, gate_w, experts, bias = _ungated(5, 4)
+    kwargs = dict(top_k=3, first_expert=8)
+    one = sigmoid_layer(x, gate_w, experts, bias, scale=1.0, **kwargs)[0]
+    _close(sigmoid_layer(x, gate_w, experts, bias, **kwargs)[0],
+           SCALE * one)
+
+
+@pytest.mark.parametrize("routing", ["uneven", "one_expert", "all_here",
+                                     "none_here"])
+def test_relu2_experts_on_rigged_routings(monkeypatch, routing):
+    """An expert without rows, one expert with a row of every token,
+    every row here and none, on the ``ragged_dot`` body (the form the
+    grouped kernels do not take), softmax-routed: the form and the rule
+    are chosen apart."""
+    monkeypatch.setattr(moe, "_TILE_ROWS", 8)
+    first_expert, held, top_k = 8, 4, 3
+    x, gate_w, experts = _rigged(routing, held, first_expert, jnp.float32)
+    experts = {k: experts[k] for k in ("w_up", "w_down")}
+    assert moe.products_path(x.dtype, D, F, N, top_k, held,
+                             "relu2") == "ragged_dot"
+
+    def plain_relu2(x, gate_w, experts):
+        top_p, top_i = jax.lax.top_k(jax.nn.softmax(x @ gate_w, -1), top_k)
+        top_p = top_p / top_p.sum(-1, keepdims=True)
+        y = jnp.zeros_like(x)
+        for e in range(held):
+            w = jnp.sum(jnp.where(top_i == first_expert + e, top_p, 0.0), -1)
+            y = y + w[:, None] * (jnp.square(jax.nn.relu(
+                x @ experts["w_up"][e])) @ experts["w_down"][e])
+        return y
+
+    def ours(x, gate_w, experts):
+        return layer(x, gate_w, experts, top_k=top_k,
+                     first_expert=first_expert)
+
+    y, seen = ours(x, gate_w, experts)
+    rows = np.asarray(seen["rows_per_expert"])
+    assert {"uneven": rows[-1] == 0 < rows.sum() < N * top_k,
+            "one_expert": rows[0] == N,
+            "all_here": rows.sum() == N * top_k,
+            "none_here": rows.sum() == 0}[routing]
+    want = plain_relu2(x, gate_w, experts)
+    if routing == "none_here":
+        assert not np.any(y) and not np.any(want)
+    else:
+        _close(y, want)
+    target = jax.random.normal(jax.random.PRNGKey(9), (N, D))
+    got = jax.grad(lambda *a: jnp.sum(ours(*a)[0] * target),
+                   argnums=(0, 1, 2))(x, gate_w, experts)
+    wanted = jax.grad(lambda *a: jnp.sum(plain_relu2(*a) * target),
+                      argnums=(0, 1, 2))(x, gate_w, experts)
+    for g, w in zip(jax.tree_util.tree_leaves(got),
+                    jax.tree_util.tree_leaves(wanted)):
+        if np.any(w):
+            _close(g, w, rtol=2e-5)
+        else:
+            assert not np.any(g)
+
+
+def test_the_grouped_kernels_are_for_the_gated_form_alone(monkeypatch):
+    from horovod_tpu.ops import pallas_ops
+
+    monkeypatch.setattr(pallas_ops, "_pallas_mode", lambda: (True, True))
+    assert moe.products_path(jnp.bfloat16, 128, 256, 64, 3, 4) == "grouped"
+    assert moe.products_path(jnp.bfloat16, 128, 256, 64, 3, 4,
+                             "relu2") == "ragged_dot"

@@ -462,3 +462,58 @@ def test_the_looped_cells_step_fits_and_keeps_one_exits_logits(v5e_2x2):
     assert {"hvtpu:loop.proj", "hvtpu:loop.mlp", "hvtpu:loop.exit",
             "hvtpu:attention", "hvtpu:lm_head"} <= set(
                 scopes.scope_by_instruction(text).values())
+
+
+# -- the stack of one-mixer layers (models/hybrid_moe.py) ---------------------
+
+HYBRID_MOE_CELL = "nemotron-3-nano-30b-a3b-9of52-t8k-b2"
+
+
+def test_the_one_mixer_cells_step_fits_and_its_kernels_keep_their_scope(
+        v5e_2x2):
+    """The whole step of ``nemotron-3-nano-30b-a3b-9of52-t8k-b2`` as
+    ``benchmark/job.py`` builds it, 667 M parameters trained at 12 bytes
+    each and 16,384 tokens a step, for one described chip: by the figure
+    the other cells' cases use it needs 12.6 GB (the allocator on the
+    chip counts 12.3: PERF.md, findings of PR 38).  Its attention, 32
+    query heads on 2 key/value heads of 128 with document ids, runs in
+    the three kernels of ``ops/flash_attention.py`` under
+    ``hvtpu:attention``, the recomputed forward too, none with kernel
+    metadata, sixteen query heads and one key/value head to a grid step
+    (a grid of two blocks of heads: ``lse`` and ``delta`` ``[2, 2, 8192,
+    16]``), which Mosaic fits in the VMEM the kernels ask for.  The
+    ungated experts' products are ``lax.ragged_dot``'s, which XLA builds
+    as kernels of its own under no scope of the program's
+    (``routed_experts_ms_per_step`` finds them by name); the row buffers
+    are the expert layer's own kernels; and the scopes the cell's
+    readers join are there."""
+    import re
+
+    from benchmark import cells, scopes
+
+    compiled = _compiled_step(cells.load_cell(HYBRID_MOE_CELL), v5e_2x2)
+    m = compiled.memory_analysis()
+    assert (m.argument_size_in_bytes + m.output_size_in_bytes
+            + m.temp_size_in_bytes - m.alias_size_in_bytes) <= 12.7e9
+    text = compiled.as_text()
+    assert _attention_kernels_by_scope(text) == {
+        kernel: {"hvtpu:attention"} for kernel in ATTENTION_KERNELS}
+    kernels = re.findall(
+        r"^\s*(?:ROOT\s+)?%hvtpu_\w+(?:\.\d+)? = .*$", text, re.MULTILINE)
+    attention = [line for line in kernels if "%hvtpu_flash_attention" in line]
+    assert len(attention) == 4          # forward, recomputed, dq, dk/dv
+    assert any("%hvtpu_moe_row_buffer" in line for line in kernels)
+    assert not any("kernel_metadata" in line.replace(
+        "kernel_metadata={}", "") for line in kernels)
+    for line in attention:              # a row's statistics, by block
+        shapes = set(re.findall(r"f32\[2,(\d+),(\d+),(\d+)\]", line))
+        assert shapes == ({("2", "16", "8192")} if "_dkv" in line
+                          else {("2", "8192", "16")}), line
+    products = re.findall(r"^\s*%(ragged-dot\S*) = ", text, re.MULTILINE)
+    assert products and not any(
+        name in scopes.scope_by_instruction(text) for name in products)
+    assert {"hvtpu:ssm.proj", "hvtpu:ssm.conv", "hvtpu:ssm.scan",
+            "hvtpu:ssm.gate", "hvtpu:attention", "hvtpu:attn.proj",
+            "hvtpu:moe.route", "hvtpu:moe.dispatch", "hvtpu:moe.experts",
+            "hvtpu:moe.combine", "hvtpu:moe.shared", "hvtpu:lm_head"} <= set(
+                scopes.scope_by_instruction(text).values())
