@@ -1,6 +1,7 @@
-"""A SiLU-gated expert layer's products over rows sorted by expert:
-two Pallas TPU kernels, one a pass, that run every group's rows against
-the group's weights with no loop around them.
+"""An expert layer's products over rows sorted by expert: two Pallas
+TPU kernels, one a pass, that run every group's rows against the
+group's weights with no loop around them, for experts of two forms:
+SiLU-gated, three weights, and ungated with a squared ReLU, two.
 
 What a caller brings (``parallel/moe.py`` does): ``xs`` ``[rows, D]``,
 the rows of every group lying contiguous, group after group, with the
@@ -11,7 +12,8 @@ float32 weights a row's result and holds a row's weight in every lane
 (a ``[rows, 1]`` array takes the room of 128 lanes on the chip all the
 same, in a layout XLA's own loops do not write, so that every pass
 would copy it; PERF.md, findings of PR 37).  The weights are
-``w_gate``, ``w_up`` ``[G, D, F]`` and ``w_down`` ``[G, F, D]``.
+``w_gate``, ``w_up`` ``[G, D, F]`` and ``w_down`` ``[G, F, D]``, or
+``w_up`` and ``w_down`` alone.
 
 *The walk.*  The buffer is cut into tiles of ``tile_rows`` rows whatever
 the groups are, and a kernel's grid is the list of *visits*: a (group,
@@ -45,10 +47,47 @@ three sums: a 0 on one side alone does not make a NaN on the other
 harmless); a row's own results depend on that row alone, and a visit
 stores its own rows only.
 
+*The ungated kernels* (``W_down relu(W_up u) ** 2``) walk the same
+visits.  ``forward``: ``a = xs Wu``, ``h = relu(a) ** 2``, ``y = (h Wd)
+* wt``, two products a visit; ``backward``: ``a`` again, ``dh = gs
+Wd^T``, ``dwt``, ``da = dh * wt * 2 relu(a)``, ``dx = da Wu^T``, ``dWu
+= xs^T da``, ``dWd = h^T (gs * wt)``, five.  Such experts are wide (2688
+x 1856 in the cell that has them: a weight 10 MB in bfloat16, an f32
+sum 20), so a group's weights *and* sums, each twice over, do not fit
+the 128 MiB of VMEM.  Nothing of the ungated form couples two columns
+of the inner width, so both kernels walk it in blocks of ``inner_block
+(F)`` columns (``h[:, blk]`` needs ``Wu[:, blk]`` alone, ``dWu[:, blk]``
+and ``dWd[blk, :]`` that block alone; ``y``, ``dx`` and ``dwt`` add up
+over the blocks in f32 values of one visit): the temporaries are a
+block's, the weights come whole, twice over, through their block
+specs, and the two f32 sums are **once** in VMEM, in the kernel's own
+scratch: a group's last visit sends each block of them off to HBM by a
+copy of its own as soon as the block is summed, and the next group's
+first visit waits for a block's copy just before it clears the block,
+so the copies run beside the products of both visits.  Every visit
+clears the rows that are not its group's and stores its own rows only,
+a whole tile like a shared one: one body, not the gated kernels' two
+(the selects are a hundredth of a visit; with a second path for whole
+tiles the backward kernel took 3.33 ms for 2.33 and was twice the
+code), and the walk over the blocks is a loop the compiler keeps
+(``lax.fori_loop``, the blocks cut at lane offsets that are multiples
+of 128): unrolled, a kernel is three to five times the code for a
+tenth less time, and a step whose expert layers are programs of their
+own holds a copy of each kernel a layer, which its every start loads.
+``F`` is a whole number of 128-lane vectors: a caller fills an odd
+width with zero columns of ``w_up`` and zero rows of ``w_down``
+(``padded_width``), which is exact.  Timed alone on a v5e at that
+cell's shape (8 groups, 6,144 uneven live rows of 98,304; PERF.md,
+findings of PR 39): forward 1.08 ms, backward 2.55 (0.62 and 1.56 at
+the peak; 0.92 and 2.33 unrolled), where the backward pass as two
+kernels (rows with ``da`` and ``h`` to HBM, then the sums through
+double-buffered output blocks) took 4.10 and ``lax.ragged_dot`` over
+the whole buffers 11.9 and 27.5.
+
 *The arithmetic* is the tile loop's that these replace: operands in
 the rows' type, f32 accumulation, ``a`` and ``b`` f32 until ``silu(a) *
-b`` is rounded once, ``dwt`` and the weighting in f32, the weight
-gradients summed in f32.
+b`` (``relu(a) ** 2``) is rounded once, ``dwt`` and the weighting in
+f32, the weight gradients summed in f32.
 
 No ``metadata=`` on the ``pallas_call``s: XLA prints it over three
 lines of the compiled text, where ``benchmark/scopes.py`` cannot follow
@@ -69,25 +108,55 @@ from jax.experimental.pallas import tpu as pltpu
 _LANES = 128
 _NT = (((1,), (1,)), ((), ()))      # a @ b.T
 _TN = (((0,), (0,)), ((), ()))      # a.T @ b
-# the backward kernel holds a group's three f32 [D, F] sums twice over (a
-# block and the one being written back) and its three weights twice
-# (a block and the next group's on its way): 54 MiB of bfloat16 at
+# the gated backward kernel holds a group's three f32 [D, F] sums twice
+# over (a block and the one being written back) and its three weights
+# twice (a block and the next group's on its way): 54 MiB of bfloat16 at
 # 2048 x 768, of the 128 a v5e core has, before a tile or a temporary
 _VMEM_LIMIT = 100 * 1024 * 1024
 _VMEM_FOR_A_GROUP = 64 * 1024 * 1024
+# the ungated one holds its two weights twice and its two sums once,
+# 78.8 MiB of bfloat16 at 2688 x 1920, and its temporaries are a block's
+# of the inner width: at visits of 128 rows and blocks of 384 columns
+# Mosaic fits it in the 100 MiB, at 256 rows or 640 columns not (it
+# wants 120: PERF.md, findings of PR 39)
+_VMEM_FOR_TWO_WEIGHTS = 80 * 1024 * 1024
+_F_BLOCK = 512
 
 
-def supports(dtype, d: int, f: int, rows: int, tile_rows: int) -> bool:
+def supports(dtype, d: int, f: int, rows: int, tile_rows: int,
+             weights: int = 3) -> bool:
     """Shapes the kernels take: operands the MXU takes, widths of whole
     128-lane vectors, a buffer of whole tiles, tiles of whole vector
     tiles (16 sublanes of bfloat16), and a group's weights and sums
-    that leave the tiles room in VMEM."""
+    that leave the tiles room in VMEM.  Of the ungated form (``weights``
+    2) ``f`` may be any: its caller fills it to ``padded_width(f)``."""
+    if weights == 2:
+        f = padded_width(f)
+        # both weights twice (a block and the next group's on its way)
+        # and both f32 sums once, in the kernel's own scratch
+        in_vmem = 2 * d * f * (2 * jnp.dtype(dtype).itemsize + 4)
+        fits = in_vmem <= _VMEM_FOR_TWO_WEIGHTS
+    else:
+        fits = (2 * 3 * d * f * (jnp.dtype(dtype).itemsize + 4)
+                <= _VMEM_FOR_A_GROUP)
     return (jnp.dtype(dtype) in (jnp.bfloat16, jnp.float32)
             and d % _LANES == 0 and f % _LANES == 0
             and tile_rows % 16 == 0 and rows >= tile_rows
-            and rows % tile_rows == 0
-            and 2 * 3 * d * f * (jnp.dtype(dtype).itemsize + 4)
-            <= _VMEM_FOR_A_GROUP)
+            and rows % tile_rows == 0 and fits)
+
+
+def padded_width(f: int) -> int:
+    """The inner width the ungated kernels want of a caller's ``f``:
+    whole 128-lane vectors, the further columns of ``w_up`` and rows of
+    ``w_down`` zeros (``relu(0) ** 2`` meets a zero row: exact)."""
+    return -(-f // _LANES) * _LANES
+
+
+def inner_block(f: int) -> int:
+    """The columns of an ungated expert's inner width a kernel takes at
+    once: the most whole vectors, up to ``_F_BLOCK`` lanes, that divide
+    ``f``."""
+    return max(b for b in range(_LANES, _F_BLOCK + 1, _LANES) if f % b == 0)
 
 
 class Visits(NamedTuple):
@@ -210,11 +279,107 @@ def _backward_kernel(offsets, group, tile, x_ref, g_ref, wt_ref, wg_ref,
         _store_rows(dx_ref, dx, mine)
         _store_rows(dwt_ref, dwt, mine)
 
+def _relu2_forward_kernel(offsets, group, tile, x_ref, wt_ref, wu_ref, wd_ref,
+                          y_ref, *, tile_rows):
+    mine, _, _ = _mine(offsets, group, tile, tile_rows)
+    f_block = inner_block(wu_ref.shape[1])
+    x = x_ref[...]
 
-def _call(kernel, name, walk, tile_rows, ins, outs, interpret):
-    """``ins`` / ``outs``: (array or its shape and type, ``"rows"`` or
-    ``"group"``): cut into this visit's tile of rows, or this visit's
-    group's whole matrix.  The first result is written where the first
+    def block(j, y):
+        at = pl.multiple_of(j * f_block, _LANES)
+        r = jax.nn.relu(_dot(x, wu_ref[:, pl.ds(at, f_block)]))
+        return y + _dot((r * r).astype(x.dtype),
+                        wd_ref[pl.ds(at, f_block), :])
+
+    y = lax.fori_loop(0, wu_ref.shape[1] // f_block, block,
+                      jnp.zeros(y_ref.shape, jnp.float32))
+    _store_rows(y_ref, y * wt_ref[:, :1], mine)
+
+
+def _relu2_backward_kernel(offsets, group, tile, x_ref, g_ref, wt_ref,
+                           wu_ref, wd_ref, dx_ref, dwt_ref, dwu_ref, dwd_ref,
+                           su_ref, sd_ref, sems, *, tile_rows):
+    v = pl.program_id(0)
+    mine, _, _ = _mine(offsets, group, tile, tile_rows)
+    start, end = offsets[group[v]], offsets[group[v] + 1]
+    # the tile holds the group's first row, its last row (a group
+    # without rows is visited once: both)
+    first = start >= tile[v] * tile_rows
+    last = end <= (tile[v] + 1) * tile_rows
+    blocks, _, f_block = su_ref.shape
+
+    def leaving(j):
+        """Block ``j`` of the group's two sums on its way out."""
+        at = pl.multiple_of(j * f_block, _LANES)
+        return (pltpu.make_async_copy(
+                    su_ref.at[j], dwu_ref.at[group[v], :, pl.ds(at, f_block)],
+                    sems.at[0, j]),
+                pltpu.make_async_copy(
+                    sd_ref.at[j], dwd_ref.at[group[v], pl.ds(at, f_block), :],
+                    sems.at[1, j]))
+
+    def clear(j):
+        """A group's first visit clears block ``j`` of the sums, once
+        the group before has let go of it."""
+        @pl.when(first)
+        def _():
+            @pl.when(v > 0)
+            def _():
+                for copy in leaving(j):
+                    copy.wait()
+            su_ref[j] = jnp.zeros_like(su_ref[j])
+            sd_ref[j] = jnp.zeros_like(sd_ref[j])
+
+    def send(j):
+        @pl.when(last)
+        def _():
+            for copy in leaving(j):
+                copy.start()
+
+    def own(rows):
+        # the other rows as zeros give zeros: da, dy, dx, dwt, the sums
+        return jnp.where(mine, rows.astype(jnp.float32), 0.0).astype(
+            rows.dtype)
+
+    x, g, wt = own(x_ref[...]), own(g_ref[...]), own(wt_ref[:, :1])
+    dy = (g.astype(jnp.float32) * wt).astype(x.dtype)
+
+    def block(j, sums):
+        dx, dwt = sums
+        at = pl.multiple_of(j * f_block, _LANES)
+        wu, wd = wu_ref[:, pl.ds(at, f_block)], wd_ref[pl.ds(at, f_block), :]
+        r = jax.nn.relu(_dot(x, wu))
+        h = r * r
+        dh = _dot(g, wd, _NT)                       # before the weighting
+        dwt += jnp.sum(dh * h, axis=-1, keepdims=True)
+        da = (dh * wt * (2.0 * r)).astype(x.dtype)
+        dx += _dot(da, wu, _NT)
+        clear(j)
+        su_ref[j] += _dot(x, da, _TN)
+        sd_ref[j] += _dot(h.astype(x.dtype), dy, _TN)
+        send(j)
+        return dx, dwt
+
+    # a loop the compiler keeps: unrolled, a kernel is five times the
+    # code, and a step holds a copy of it for every expert layer
+    dx, dwt = lax.fori_loop(
+        0, blocks, block, (jnp.zeros(dx_ref.shape, jnp.float32),
+                           jnp.zeros((tile_rows, 1), jnp.float32)))
+    _store_rows(dx_ref, dx, mine)
+    _store_rows(dwt_ref, dwt, mine)
+
+    @pl.when(last & (group[v] == dwu_ref.shape[0] - 1))
+    def _():
+        for j in range(blocks):
+            for copy in leaving(j):
+                copy.wait()
+
+
+def _call(kernel, name, walk, tile_rows, ins, outs, interpret, scratch=()):
+    """``ins`` / ``outs``: (array or its shape and type, ``"rows"``,
+    ``"group"`` or ``"hbm"``): cut into this visit's tile of rows, or
+    this visit's group's whole matrix, or left where it is for the
+    kernel's own copies.  The first result is written where the first
     operand was, tile for tile: a visit has read its tile of the one
     before it writes the other, and a tile visited twice in a row is
     neither fetched nor written in between."""
@@ -223,6 +388,8 @@ def _call(kernel, name, walk, tile_rows, ins, outs, interpret):
             return pl.BlockSpec(
                 (tile_rows, shape[1]),
                 lambda v, offsets, group, tile: (tile[v], 0))
+        if kind == "hbm":
+            return pl.BlockSpec(memory_space=pl.ANY)
         return pl.BlockSpec(
             (None,) + tuple(shape[1:]),
             lambda v, offsets, group, tile: (group[v], 0, 0))
@@ -232,7 +399,8 @@ def _call(kernel, name, walk, tile_rows, ins, outs, interpret):
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=3, grid=(walk.count,),
             in_specs=[spec(a.shape, kind) for a, kind in ins],
-            out_specs=[spec(a.shape, kind) for a, kind in outs]),
+            out_specs=[spec(a.shape, kind) for a, kind in outs],
+            scratch_shapes=list(scratch)),
         out_shape=[a for a, _ in outs], interpret=interpret,
         input_output_aliases={3: 0},
         compiler_params=pltpu.CompilerParams(
@@ -241,31 +409,43 @@ def _call(kernel, name, walk, tile_rows, ins, outs, interpret):
     )(walk.offsets, walk.group, walk.tile, *(a for a, _ in ins))
 
 
-def forward(xs, wt, counts, w_gate, w_up, w_down, *, tile_rows: int,
+def forward(xs, wt, counts, *weights, tile_rows: int,
             interpret: bool = False):
     """``y`` ``[rows, D]`` in ``xs``'s type **in ``xs``'s room**; the
-    rows past the last group's are not written."""
+    rows past the last group's are not written.  ``weights``: ``w_gate,
+    w_up, w_down``, or ``w_up, w_down`` of the ungated form."""
     walk = visits(counts, xs.shape[0], tile_rows, False)
     return _call(
-        _forward_kernel, "hvtpu_grouped_ffn_fwd", walk, tile_rows,
-        [(xs, "rows"), (wt, "rows"), (w_gate, "group"), (w_up, "group"),
-         (w_down, "group")],
+        _forward_kernel if len(weights) == 3 else _relu2_forward_kernel,
+        "hvtpu_grouped_ffn_fwd", walk, tile_rows,
+        [(xs, "rows"), (wt, "rows")] + [(w, "group") for w in weights],
         [(jax.ShapeDtypeStruct(xs.shape, xs.dtype), "rows")], interpret)[0]
 
 
-def backward(xs, gs, wt, counts, w_gate, w_up, w_down, *, tile_rows: int,
+def backward(xs, gs, wt, counts, *weights, tile_rows: int,
              interpret: bool = False):
     """``gs`` is ``y``'s cotangent in the rows' order.  Returns ``dx``
     ``[rows, D]`` in ``xs``'s type **in ``xs``'s room**, ``dwt`` ``[rows,
     lanes]`` float32, a row's value in every lane (of both, the rows
-    past the last group's are not written), and the three weight
-    gradients, float32 ``[G, ., .]``."""
+    past the last group's are not written), and the weights' gradients,
+    float32 ``[G, ., .]``."""
+    walk = visits(counts, xs.shape[0], tile_rows, True)
+    ins = [(xs, "rows"), (gs, "rows"), (wt, "rows")] + [
+        (w, "group") for w in weights]
+    rows = [(jax.ShapeDtypeStruct(xs.shape, xs.dtype), "rows"),
+            (jax.ShapeDtypeStruct(wt.shape, jnp.float32), "rows")]
+    sums = [jax.ShapeDtypeStruct(w.shape, jnp.float32) for w in weights]
+    if len(weights) == 3:
+        return _call(_backward_kernel, "hvtpu_grouped_ffn_bwd", walk,
+                     tile_rows, ins, rows + [(a, "group") for a in sums],
+                     interpret)
+    # the two sums in the kernel's own VMEM, a block of the inner width
+    # after another, and a semaphore for each block's copy out
+    _, d, f = weights[0].shape
+    f_block = inner_block(f)
     return _call(
-        _backward_kernel, "hvtpu_grouped_ffn_bwd",
-        visits(counts, xs.shape[0], tile_rows, True), tile_rows,
-        [(xs, "rows"), (gs, "rows"), (wt, "rows"), (w_gate, "group"),
-         (w_up, "group"), (w_down, "group")],
-        [(jax.ShapeDtypeStruct(xs.shape, xs.dtype), "rows"),
-         (jax.ShapeDtypeStruct(wt.shape, jnp.float32), "rows")]
-        + [(jax.ShapeDtypeStruct(w.shape, jnp.float32), "group")
-           for w in (w_gate, w_up, w_down)], interpret)
+        _relu2_backward_kernel, "hvtpu_grouped_ffn_bwd", walk, tile_rows,
+        ins, rows + [(a, "hbm") for a in sums], interpret,
+        scratch=[pltpu.VMEM((f // f_block, d, f_block), jnp.float32),
+                 pltpu.VMEM((f // f_block, f_block, d), jnp.float32),
+                 pltpu.SemaphoreType.DMA((2, f // f_block))])

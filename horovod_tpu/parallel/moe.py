@@ -370,14 +370,30 @@ def products_path(dtype, d: int, f: int, tokens: int, top_k: int,
     """How the experts' products of a layer of these shapes run, from
     what the code can see: ``"grouped"``, in the Pallas kernels of
     ``ops/grouped_ffn.py``, where ``ops.pallas_ops`` compiles kernels (a
-    TPU backend, or the interpreter in tests), the experts are of the
-    form the kernels multiply (``"gated"``: three weights) and the
-    shapes are ones they take; elsewhere ``"ragged_dot"``, the same
-    products over the same buffers as ``lax.ragged_dot``."""
+    TPU backend, or the interpreter in tests) and the shapes are ones
+    the kernels of the experts' ``form`` take (``"gated"``, three
+    weights: ``d`` and ``f`` whole 128-lane vectors; ``"relu2"``, two:
+    ``d`` alone, the layer fills ``f``; either way rows the MXU takes,
+    whole tiles, and a group's weights and sums in VMEM); elsewhere
+    ``"ragged_dot"``, the same products over the same buffers as
+    ``lax.ragged_dot``."""
     use, _ = pallas_ops._pallas_mode()
-    return ("grouped" if use and form == "gated" and grouped_ffn.supports(
+    return ("grouped" if use and grouped_ffn.supports(
         dtype, d, f, buffer_rows(tokens, top_k, experts_held),
-        _product_rows(min(_TILE_ROWS, tokens))) else "ragged_dot")
+        _product_rows(min(_TILE_ROWS, tokens)),
+        weights=3 if form == "gated" else 2) else "ragged_dot")
+
+
+def _in_whole_vectors(w_up, w_down):
+    """An ungated expert's two matrices as its grouped kernels want
+    them: the inner width filled to whole 128-lane vectors with zero
+    columns of ``w_up`` and zero rows of ``w_down``.  Exact (``relu(0)
+    ** 2`` meets a zero row), made of the copies in the rows' type the
+    layer makes every step anyway, and the gradient of a fill is a
+    slice: the parameters and their gradients keep their shapes."""
+    more = grouped_ffn.padded_width(w_up.shape[2]) - w_up.shape[2]
+    return (jnp.pad(w_up, ((0, 0), (0, 0), (0, more))),
+            jnp.pad(w_down, ((0, 0), (0, more), (0, 0))))
 
 
 def _product_rows(tile_rows):
@@ -552,7 +568,8 @@ def dropless_topk_moe(
 
     The assignments whose expert is held here are
     sorted by expert, gathered, multiplied a group at a time (in the
-    kernels of ``ops/grouped_ffn.py`` where they run: ``products_path``;
+    kernels of ``ops/grouped_ffn.py`` where they run, either form:
+    ``products_path``;
     ``hvtpu_moe_products_total{path=}`` counts, when a program is traced,
     which it was, ``hvtpu_moe_router_total{rule=}`` and
     ``hvtpu_moe_experts_form_total{form=}`` the rule and the form) and
@@ -610,8 +627,9 @@ def dropless_topk_moe(
     metrics.note_moe_products(path)
     metrics.note_moe_layer(
         "softmax" if selection_bias is None else "sigmoid_bias", form)
-    y = _grouped_ffn(
-        (*sizes, path), x, weight, plan,
-        tuple(expert_params[k].astype(x.dtype) for k in names))
+    weights = tuple(expert_params[k].astype(x.dtype) for k in names)
+    if path == "grouped" and form == "relu2":
+        weights = _in_whole_vectors(*weights)
+    y = _grouped_ffn((*sizes, path), x, weight, plan, weights)
     return y, {"rows_per_expert": hit.sum(axis=0, dtype=jnp.int32),
                "experts": top_i}

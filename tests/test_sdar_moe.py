@@ -1,6 +1,8 @@
 """``parallel.moe.dropless_topk_moe``: the chip's share of a top-k
 expert layer, against a plain loop over the experts."""
 
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -570,13 +572,25 @@ def test_the_scale_multiplies_the_routed_part():
            SCALE * one)
 
 
+def _plain_relu2(x, gate_w, experts, *, top_k, first_expert):
+    """Softmax-routed ungated experts, every held one over every token."""
+    top_p, top_i = jax.lax.top_k(jax.nn.softmax(x @ gate_w, -1), top_k)
+    top_p = top_p / top_p.sum(-1, keepdims=True)
+    y = jnp.zeros_like(x)
+    for e in range(experts["w_up"].shape[0]):
+        w = jnp.sum(jnp.where(top_i == first_expert + e, top_p, 0.0), -1)
+        y = y + w[:, None] * (jnp.square(jax.nn.relu(
+            x @ experts["w_up"][e])) @ experts["w_down"][e])
+    return y
+
+
 @pytest.mark.parametrize("routing", ["uneven", "one_expert", "all_here",
                                      "none_here"])
 def test_relu2_experts_on_rigged_routings(monkeypatch, routing):
     """An expert without rows, one expert with a row of every token,
-    every row here and none, on the ``ragged_dot`` body (the form the
-    grouped kernels do not take), softmax-routed: the form and the rule
-    are chosen apart."""
+    every row here and none, on the ``ragged_dot`` body (which the CPU,
+    float16 and widths off the 128 lanes still run), softmax-routed:
+    the form and the rule are chosen apart."""
     monkeypatch.setattr(moe, "_TILE_ROWS", 8)
     first_expert, held, top_k = 8, 4, 3
     x, gate_w, experts = _rigged(routing, held, first_expert, jnp.float32)
@@ -584,15 +598,8 @@ def test_relu2_experts_on_rigged_routings(monkeypatch, routing):
     assert moe.products_path(x.dtype, D, F, N, top_k, held,
                              "relu2") == "ragged_dot"
 
-    def plain_relu2(x, gate_w, experts):
-        top_p, top_i = jax.lax.top_k(jax.nn.softmax(x @ gate_w, -1), top_k)
-        top_p = top_p / top_p.sum(-1, keepdims=True)
-        y = jnp.zeros_like(x)
-        for e in range(held):
-            w = jnp.sum(jnp.where(top_i == first_expert + e, top_p, 0.0), -1)
-            y = y + w[:, None] * (jnp.square(jax.nn.relu(
-                x @ experts["w_up"][e])) @ experts["w_down"][e])
-        return y
+    plain_relu2 = functools.partial(_plain_relu2, top_k=top_k,
+                                    first_expert=first_expert)
 
     def ours(x, gate_w, experts):
         return layer(x, gate_w, experts, top_k=top_k,
@@ -622,10 +629,185 @@ def test_relu2_experts_on_rigged_routings(monkeypatch, routing):
             assert not np.any(g)
 
 
-def test_the_grouped_kernels_are_for_the_gated_form_alone(monkeypatch):
+def test_the_grouped_kernels_take_both_forms(monkeypatch):
+    """... and of the ungated one an inner width that is no whole
+    number of vectors too (the layer fills it), where the gated
+    kernels want whole vectors."""
     from horovod_tpu.ops import pallas_ops
 
     monkeypatch.setattr(pallas_ops, "_pallas_mode", lambda: (True, True))
-    assert moe.products_path(jnp.bfloat16, 128, 256, 64, 3, 4) == "grouped"
-    assert moe.products_path(jnp.bfloat16, 128, 256, 64, 3, 4,
+    for form in ("gated", "relu2"):
+        assert moe.products_path(jnp.bfloat16, 128, 256, 64, 3, 4,
+                                 form) == "grouped"
+    assert moe.products_path(jnp.bfloat16, 128, 320, 64, 3, 4,
+                             "relu2") == "grouped"
+    assert moe.products_path(jnp.bfloat16, 128, 320, 64, 3, 4,
+                             "gated") == "ragged_dot"
+    assert moe.products_path(jnp.bfloat16, 192, 256, 64, 3, 4,
                              "relu2") == "ragged_dot"
+
+
+# -- the ungated experts in the grouped kernels, interpreted ------------------
+
+@pytest.fixture
+def inner_blocks_of_128(monkeypatch):
+    """An inner width of 256 is two blocks, one of 320 is filled to 384
+    and is three."""
+    from horovod_tpu.ops import grouped_ffn
+
+    monkeypatch.setattr(grouped_ffn, "_F_BLOCK", 128)
+    assert grouped_ffn.inner_block(grouped_ffn.padded_width(320)) == 128
+
+
+def _dense_relu2(x, weight, hit, w_up, w_down):
+    y = jnp.zeros_like(x)
+    for e in range(hit.shape[1]):
+        h = jnp.square(jax.nn.relu(x @ w_up[e]))
+        y = y + jnp.where(hit[:, e], weight[:, e], 0.0)[:, None] * (
+            h @ w_down[e])
+    return y
+
+
+@pytest.mark.parametrize("f", [256, 320])
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+@pytest.mark.parametrize("routing", [
+    "even", "an_expert_without_rows", "every_token_here", "off_every_tile",
+    "nobody_here"])
+def test_the_ungated_kernels_equal_every_expert_over_every_token(
+        monkeypatch, grouped, inner_blocks_of_128, routing, dtype, f):
+    """``_grouped_ffn`` through the two-weight kernels against the
+    dense sum: the result and the gradients of ``x``, ``weight`` and
+    both weights, the inner width walked in two blocks (256) or filled
+    from 320 to 384 and walked in three, where the filled columns'
+    gradients are exact zeros; bfloat16 rows against the dense sum in
+    float32.  Then again with every row of the buffers that no
+    assignment owns NaN before the products: everything comes out
+    finite and as from buffers of zeros, bit for bit."""
+    x, _, experts = _weights(21, 4, n=64, d=128, f=f)
+    hit = _hits(routing)
+    weight = jax.random.uniform(jax.random.PRNGKey(2), hit.shape)
+    target = jax.random.normal(jax.random.PRNGKey(9), x.shape)
+    w_up, w_down = moe._in_whole_vectors(experts["w_up"], experts["w_down"])
+    assert w_up.shape[2] == w_down.shape[1] == {256: 256, 320: 384}[f]
+
+    def ours(x, weight, *w):
+        plan, sizes = moe._plan(jnp.asarray(hit), 3)
+        return moe._grouped_ffn((*sizes, "grouped"), x.astype(dtype), weight,
+                                plan, tuple(a.astype(dtype) for a in w))
+
+    def run(f):
+        return jax.jit(jax.value_and_grad(
+            lambda *a: jnp.sum(f(*a).astype(jnp.float32) * target),
+            (0, 1, 2, 3)))(x, weight, w_up, w_down)
+
+    assert moe.products_path(dtype, 128, f, 64, 3, 4, "relu2") == "grouped"
+    got = jax.tree_util.tree_leaves(run(ours))
+    want = jax.tree_util.tree_leaves(run(
+        lambda x, weight, *w: _dense_relu2(x, weight, jnp.asarray(hit), *w)))
+    assert len(got) == 5
+    for g, w in zip(got, want):
+        assert np.isfinite(np.asarray(g)).all()
+        if routing == "nobody_here":
+            assert not np.asarray(g).any() and not np.asarray(w).any()
+        else:               # the loss is a sum that cancels
+            _close(g, w, rtol=(1e-5 if dtype == jnp.float32 else 0.05)
+                   * (1 if np.ndim(g) else 10))
+    assert not np.asarray(got[3])[:, :, f:].any()
+    assert not np.asarray(got[4])[:, f:, :].any()
+    monkeypatch.setattr(
+        moe, "_row_buffer", lambda rows, width, dtype, near: jnp.full(
+            (rows, width), jnp.nan, dtype))
+    for g, clean in zip(jax.tree_util.tree_leaves(run(ours)), got):
+        np.testing.assert_array_equal(np.asarray(g), np.asarray(clean))
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+@pytest.mark.parametrize("routing", ["uneven", "one_expert", "all_here",
+                                     "none_here"])
+def test_through_the_kernels_the_ungated_layer_equals_the_plain_loop(
+        monkeypatch, grouped, inner_blocks_of_128, routing, dtype):
+    """``dropless_topk_moe`` with two-weight experts 320 wide, its
+    products in the kernels (the layer fills the width to 384 and the
+    gradients come back 320 wide), rigged routers and all, against the
+    plain loop: the result, the rows each expert got and the gradients
+    of the tokens, the router and both weights; and against the same
+    layer on the ``ragged_dot`` body, whose arithmetic the kernels
+    keep (in bfloat16 a router rigged to one expert has a gradient of
+    differences that cancel: both bodies are a fifth off the float32
+    loop there, and a part in a hundred thousand off each other)."""
+    first_expert, held, top_k = 8, 4, 3
+    counters = {
+        name: moe.metrics.REGISTRY.counter(name).value(**label)
+        for name, label in (("hvtpu_moe_products_total", {"path": "grouped"}),
+                            ("hvtpu_moe_experts_form_total",
+                             {"form": "relu2"}))}
+    x, gate_w, experts = _rigged(routing, held, first_expert, dtype,
+                                 n=64, d=128, f=320)
+    experts = {k: experts[k] for k in ("w_up", "w_down")}
+    kwargs = dict(top_k=top_k, first_expert=first_expert)
+    target = jax.random.normal(jax.random.PRNGKey(9), x.shape)
+
+    plain_relu2 = functools.partial(_plain_relu2, **kwargs)
+
+    def run(f, x):
+        return jax.jit(jax.value_and_grad(
+            lambda *a: jnp.sum(f(*a).astype(jnp.float32) * target),
+            (0, 1, 2)))(x, gate_w, experts)
+
+    got = jax.tree_util.tree_leaves(
+        run(lambda *a: layer(*a, **kwargs)[0], x))
+    want = jax.tree_util.tree_leaves(
+        run(plain_relu2, x.astype(jnp.float32)))
+    assert moe.metrics.REGISTRY.counter("hvtpu_moe_products_total").value(
+        path="grouped") == counters["hvtpu_moe_products_total"] + 1
+    assert moe.metrics.REGISTRY.counter(
+        "hvtpu_moe_experts_form_total").value(
+            form="relu2") == counters["hvtpu_moe_experts_form_total"] + 1
+    rows = np.asarray(layer(x, gate_w, experts, **kwargs)[1][
+        "rows_per_expert"])
+    assert {"uneven": 0 < rows.sum() < 64 * top_k and rows[-1] == 0,
+            "one_expert": rows[0] == 64,
+            "all_here": rows.sum() == 64 * top_k,
+            "none_here": rows.sum() == 0}[routing]
+    assert [g.shape for g in got[3:]] == [(held, 320, 128), (held, 128, 320)]
+    assert len(got) == 5
+    monkeypatch.setenv("HVTPU_PALLAS", "0")
+    assert moe.products_path(dtype, 128, 320, 64, top_k, held,
+                             "relu2") == "ragged_dot"
+    ragged = jax.tree_util.tree_leaves(
+        run(lambda *a: layer(*a, **kwargs)[0], x))
+    for i, (g, w, r) in enumerate(zip(got, want, ragged)):
+        g = np.asarray(g, np.float32)
+        assert np.isfinite(g).all()
+        if routing == "none_here":
+            assert not g.any()
+            continue
+        _close(g, np.asarray(r, np.float32), rtol=1e-4)
+        rigged_router = (i == 2 and dtype == jnp.bfloat16
+                         and routing == "one_expert")
+        _close(g, w, rtol=1e-5 if dtype == jnp.float32
+               else 0.3 if rigged_router else 0.05)
+
+
+@pytest.mark.parametrize("where, dtype, d, f, path", [
+    ("tpu", jnp.bfloat16, 2688, 1856, "grouped"),       # the one-mixer cell
+    ("tpu", jnp.bfloat16, 128, 320, "grouped"),
+    ("tpu", jnp.float32, 1024, 1024, "grouped"),
+    # both f32 weights twice and both sums crowd the tiles out
+    ("tpu", jnp.float32, 2688, 1856, "ragged_dot"),
+    ("tpu", jnp.bfloat16, 4096, 4096, "ragged_dot"),
+    ("tpu", jnp.float16, 2688, 1856, "ragged_dot"),
+    ("tpu", jnp.bfloat16, 64, 32, "ragged_dot"),        # the rehearsals
+    ("tpu", jnp.bfloat16, 192, 256, "ragged_dot"),      # D off the lanes
+    ("tpu_without_pallas", jnp.bfloat16, 2688, 1856, "ragged_dot"),
+    ("cpu", jnp.bfloat16, 2688, 1856, "ragged_dot")])
+def test_how_the_ungated_products_run_is_read_off_the_backend_and_the_shapes(
+        monkeypatch, where, dtype, d, f, path):
+    from horovod_tpu.ops import pallas_ops
+
+    monkeypatch.delenv("HVTPU_PALLAS_INTERPRET", raising=False)
+    monkeypatch.delenv("HVTPU_PALLAS", raising=False)
+    if where == "tpu_without_pallas":
+        monkeypatch.setenv("HVTPU_PALLAS", "0")
+    monkeypatch.setattr(pallas_ops, "_on_tpu", lambda: where != "cpu")
+    assert moe.products_path(dtype, d, f, 16384, 6, 8, "relu2") == path

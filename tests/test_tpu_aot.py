@@ -189,11 +189,11 @@ def test_the_transformer_cells_step_fits_and_its_kernels_keep_their_scope(
     _the_guards_branch_runs_under_the_updates_scope(text)
 
 
-def _the_experts_products_are_the_grouped_kernels(text):
+def _the_experts_products_are_the_grouped_kernels(text, layers=1):
     """Under ``hvtpu:moe.experts`` the step holds the two kernels of
-    ``ops/grouped_ffn.py`` once each (the forward pass a layer's
-    ``checkpoint`` makes again feeds nothing and is dropped) and no
-    loop: nothing of that scope lies inside a loop of a layer, and no
+    ``ops/grouped_ffn.py`` once each for every expert layer that is a
+    program of its own (the forward pass a layer's ``checkpoint`` makes
+    again feeds nothing and is dropped) and no loop: nothing of that scope lies inside a loop of a layer, and no
     loop updates a slice of an ``f32[16, ., .]`` sum of weight
     gradients a tile at a time.  The kernels carry no kernel metadata,
     which XLA would print over several lines where ``benchmark/scopes
@@ -205,8 +205,9 @@ def _the_experts_products_are_the_grouped_kernels(text):
     by_instruction = scopes.scope_by_instruction(text)
     kernels = {name: scope for name, scope in by_instruction.items()
                if name.startswith("hvtpu_grouped_ffn")}
-    assert sorted(re.sub(r"\.\d+$", "", name) for name in kernels) == [
-        "hvtpu_grouped_ffn_bwd", "hvtpu_grouped_ffn_fwd"]
+    assert sorted(re.sub(r"\.\d+$", "", name) for name in kernels) == (
+        ["hvtpu_grouped_ffn_bwd"] * layers
+        + ["hvtpu_grouped_ffn_fwd"] * layers)
     assert set(kernels.values()) == {"hvtpu:moe.experts"}
     for line in re.findall(r"^.*%hvtpu_grouped_ffn\S* = .*$", text,
                            re.MULTILINE):
@@ -245,9 +246,10 @@ def _the_guards_branch_runs_under_the_updates_scope(text):
         assert scope in text, scope
 
 
-def _no_pass_over_a_whole_row_buffer(text, cell):
+def _no_pass_over_a_whole_row_buffer(text, cell, rows=None):
     """The expert layer's buffers in expert order (262,144 rows of
-    2,048 in this cell, of which an even routing uses an eighth) are
+    2,048 in the transformer cell, of which an even routing uses an
+    eighth; ``rows`` where a cell's are counted otherwise) are
     allocated, written a tile at a time by the gathers and in place by
     the grouped kernels: nothing fills one, and nothing copies one
     (which is what XLA does in every layer with an ``AllocateBuffer``
@@ -257,7 +259,7 @@ def _no_pass_over_a_whole_row_buffer(text, cell):
 
     from horovod_tpu.parallel import moe
 
-    rows = moe.buffer_rows(
+    rows = rows or moe.buffer_rows(
         cell.traffic["batch_per_chip"] * 2 * cell.traffic["sequence_length"],
         cell.config["num_experts_per_tok"], cell.config["num_experts"])
     made = {}
@@ -482,16 +484,24 @@ def test_the_one_mixer_cells_step_fits_and_its_kernels_keep_their_scope(
     metadata, sixteen query heads and one key/value head to a grid step
     (a grid of two blocks of heads: ``lse`` and ``delta`` ``[2, 2, 8192,
     16]``), which Mosaic fits in the VMEM the kernels ask for.  The
-    ungated experts' products are ``lax.ragged_dot``'s, which XLA builds
-    as kernels of its own under no scope of the program's
-    (``routed_experts_ms_per_step`` finds them by name); the row buffers
-    are the expert layer's own kernels; and the scopes the cell's
-    readers join are there."""
+    ungated experts' products are the two-weight kernels of
+    ``ops/grouped_ffn.py``, a forward and a backward one for each of
+    the four expert layers, under ``hvtpu:moe.experts``: no
+    ``ragged-dot`` kernel of XLA's is left, and nothing outside the
+    kernels fills, copies or passes over a ``[98304, .]`` buffer (six
+    rows a token, of which an even routing uses a sixteenth); the
+    experts' weights are filled from 1,856 to 1,920 columns in
+    bfloat16 and their float32 gradients keep the parameters' shapes;
+    the row buffers are the expert layer's own kernels; and the scopes
+    the cell's readers join are there."""
     import re
 
     from benchmark import cells, scopes
 
-    compiled = _compiled_step(cells.load_cell(HYBRID_MOE_CELL), v5e_2x2)
+    from horovod_tpu.parallel import moe
+
+    cell = cells.load_cell(HYBRID_MOE_CELL)
+    compiled = _compiled_step(cell, v5e_2x2)
     m = compiled.memory_analysis()
     assert (m.argument_size_in_bytes + m.output_size_in_bytes
             + m.temp_size_in_bytes - m.alias_size_in_bytes) <= 12.7e9
@@ -509,9 +519,15 @@ def test_the_one_mixer_cells_step_fits_and_its_kernels_keep_their_scope(
         shapes = set(re.findall(r"f32\[2,(\d+),(\d+),(\d+)\]", line))
         assert shapes == ({("2", "16", "8192")} if "_dkv" in line
                           else {("2", "8192", "16")}), line
-    products = re.findall(r"^\s*%(ragged-dot\S*) = ", text, re.MULTILINE)
-    assert products and not any(
-        name in scopes.scope_by_instruction(text) for name in products)
+    assert not re.findall(r"^\s*%(ragged-dot\S*) = ", text, re.MULTILINE)
+    _the_experts_products_are_the_grouped_kernels(text, layers=4)
+    rows = moe.buffer_rows(
+        cell.traffic["batch_per_chip"] * cell.traffic["sequence_length"],
+        cell.config["num_experts_per_tok"], cell.config["n_routed_experts"])
+    assert rows == 98304
+    _no_pass_over_a_whole_row_buffer(text, cell, rows)
+    assert re.search(r"bf16\[8,2688,1920\]", text)
+    assert not re.search(r"f32\[\d+,1920\]|f32\[\d+,1856\]", text)
     assert {"hvtpu:ssm.proj", "hvtpu:ssm.conv", "hvtpu:ssm.scan",
             "hvtpu:ssm.gate", "hvtpu:attention", "hvtpu:attn.proj",
             "hvtpu:moe.route", "hvtpu:moe.dispatch", "hvtpu:moe.experts",
