@@ -36,4 +36,5 @@ __all__ = [
     # import looped``): the other jobs' set-up is imports first
     "looped",
     "hybrid_moe",
+    "kimi_linear",
 ]

@@ -449,11 +449,15 @@ _flash.defvjp(_flash_fwd, _flash_bwd)
 
 
 def causal_document_attention(q, k, v, segment, *, scale: float, tile: int):
-    """Attention of ``q`` ``[B, T, H, hd]`` over ``k``, ``v`` ``[B, T, G,
-    hd]``, every query head reading the key/value head of its group: a
-    query sees the keys at or before it in its own document.  Scores
-    and softmax in f32; the products take ``q``'s type.  Everything in
-    it runs under the scope ``hvtpu:attention``.
+    """Attention of ``q`` ``[B, T, H, hd]`` over ``k`` ``[B, T, G, hd]``
+    and ``v`` ``[B, T, G, hdv]``, every query head reading the key/value
+    head of its group: a query sees the keys at or before it in its own
+    document, and the result is ``[B, T, H, hdv]``.  A head has two
+    widths: ``hd`` of its queries and keys, ``hdv`` of its values and
+    results, which need not be equal (latent attention: keys of 128 +
+    64 against values of 128).  Scores and softmax in f32; the products
+    take ``q``'s type.  Everything in it runs under the scope
+    ``hvtpu:attention``.
 
     Which implementation runs is observed, not set, as in
     ``block_diffusion.tiled_attention``.  Where ``ops.pallas_ops``
@@ -468,11 +472,11 @@ def causal_document_attention(q, k, v, segment, *, scale: float, tile: int):
     ``hvtpu_attention_calls_total{path=}`` counts, when a program is
     traced, which it was."""
     b, t, heads, hd = q.shape
-    groups = k.shape[2]
+    groups, hdv = k.shape[2], v.shape[3]
     use, interpret = pallas_ops._pallas_mode()
     blocks = _flash_blocks(t)
     if use and q.dtype == k.dtype == v.dtype and flash_attention.supports(
-            hd, q.dtype, t, *blocks, groups):
+            hd, q.dtype, t, *blocks, groups, hdv):
         metrics.note_attention_path("pallas")
         return _flash(q, k, v, segment.astype(jnp.int32),
                       (scale, *blocks, interpret))
@@ -503,7 +507,7 @@ def causal_document_attention(q, k, v, segment, *, scale: float, tile: int):
         out = [rows(q[:, a:a + tile], k[:, :a + tile], v[:, :a + tile],
                     segment[:, a:a + tile], segment[:, :a + tile], a)
                for a in range(0, t, tile)]
-        return jnp.concatenate(out, axis=1).reshape(b, t, heads, hd)
+        return jnp.concatenate(out, axis=1).reshape(b, t, heads, hdv)
 
 
 def attention_mixer(cfg: HybridSSMConfig, p: Params, u, segment):

@@ -707,6 +707,21 @@ def note_attention_block(query_heads: int, kv_heads: int) -> None:
     heads.set(float(kv_heads), kind="key_value")
 
 
+def note_attention_head_width(key: int, value: int) -> None:
+    """Record a head's two widths in the last attention the Pallas
+    kernels were built for: of its queries and keys, and of its values
+    and results (192 against 128 in latent attention; the same number
+    twice elsewhere).  Called while a program is traced, like
+    ``note_attention_block``."""
+    width = REGISTRY.gauge(
+        "hvtpu_attention_head_width",
+        "Width of a head in the last attention built for the Pallas "
+        "kernels, by kind: of its queries and keys, and of its values and "
+        "results.")
+    width.set(float(key), kind="key")
+    width.set(float(value), kind="value")
+
+
 def note_attention_pairs(run: int, skipped: int) -> None:
     """Count the block pairs the attention kernels ran and skipped on
     the batches noted: a pair whose blocks share no document is decided
@@ -820,6 +835,22 @@ def note_ssm_groups(groups: int) -> None:
         "hvtpu_ssm_groups",
         "B/C groups of the last chunked state-space scan built: 1 where "
         "every head reads the same B and C.").set(float(groups))
+
+
+def note_kda_chunks(chunks: int, chunk_size: int) -> None:
+    """Count the chunks one call of ``models.kimi_linear.chunked_delta_
+    rule`` solves and walks (rows x chunks a row), and record their
+    size.  Called while a program is traced, once a call site and a
+    trace, like ``note_ssm_chunks``."""
+    REGISTRY.counter(
+        "hvtpu_kda_chunks_total",
+        "Chunks the chunked delta rule walks in one call (rows x chunks a "
+        "row), counted when a program is traced.").inc(float(chunks))
+    REGISTRY.gauge(
+        "hvtpu_kda_chunk_size",
+        "Positions of a chunk of the last chunked delta rule built: the "
+        "size of the triangular system a chunk and a head solve.").set(
+            float(chunk_size))
 
 
 def note_packed_batch(segment) -> None:

@@ -2,10 +2,12 @@
 Pallas TPU kernels (forward, ``dq``, ``dk``/``dv``) that keep a tile
 pair's scores in VMEM between the two products.
 
-What a caller brings: ``q`` ``[B, P, H, hd]`` and ``k``, ``v`` ``[B, P,
-G, hd]`` as the projections leave them (head-minor, the positions not
-split into tiles: the kernels' blocks are cut by their index maps, so
-nothing is transposed on the way in or out), a ``PairSchedule`` that
+What a caller brings: ``q`` ``[B, P, H, hd]``, ``k`` ``[B, P, G, hd]``
+and ``v`` ``[B, P, G, hdv]`` as the projections leave them (a head has
+two widths, of its queries and keys and of its values and results, which
+need not be equal: latent attention's 192 against 128; head-minor, the
+positions not split into tiles: the kernels' blocks are cut by their
+index maps, so nothing is transposed on the way in or out), a ``PairSchedule`` that
 lists the (query tile, key tile) pairs that hold work and says of each
 whether every query sees every key, and ``seen(q_pos, k_pos)``, the
 mask itself, which a kernel evaluates on iotas in the pairs that are
@@ -67,6 +69,7 @@ type with f32 accumulation, a masked score ``mask_value``.
 from __future__ import annotations
 
 import functools
+import math
 from typing import Callable, NamedTuple, Optional
 
 import jax
@@ -120,13 +123,20 @@ def pair_schedule(work: np.ndarray, block_q: int, block_kv: int):
 
 
 def supports(head_dim: int, dtype, positions: int, block_q: int,
-             block_kv: int, kv_heads: int) -> bool:
-    """Shapes the kernels take: heads of whole 128-lane vectors, or of
-    half of one where the key/value heads pair up into whole ones;
-    blocks of whole vector tiles that divide the positions; operands
-    the MXU takes."""
-    return ((head_dim % _LANES == 0
-             or head_dim == _LANES // 2 and kv_heads % 2 == 0)
+             block_kv: int, kv_heads: int,
+             value_dim: Optional[int] = None) -> bool:
+    """Shapes the kernels take: a head's two widths, ``head_dim`` of its
+    queries and keys and ``value_dim`` of its values and results (the
+    same without one), each of whole 128-lane vectors, or of whole
+    halves of one (64, or 192: latent attention's keys of 128 + 64
+    against values of 128) where the key/value heads pair up into whole
+    vectors; blocks of whole vector tiles that divide the positions;
+    operands the MXU takes."""
+    half = _LANES // 2
+    widths = {head_dim, head_dim if value_dim is None else value_dim}
+    return (all(width % _LANES == 0
+                or width % half == 0 and kv_heads % 2 == 0
+                for width in widths)
             and jnp.dtype(dtype) in (jnp.bfloat16, jnp.float32)
             and block_q % _LANES == 0 and block_kv % _LANES == 0
             and positions % block_q == 0 and positions % block_kv == 0)
@@ -182,15 +192,34 @@ def _seen_tile(seen, first_query, first_key, shape, query_axis: int,
         *(ref[...] for ref in id_refs))
 
 
-def _heads(k_ref, v_ref, heads, group, hd):
-    """For every query head of a block: its index, its columns in a
-    wide block, its key/value head's in a narrow one, and that head's
-    keys and values, loaded once a pair."""
+class _Columns(NamedTuple):
+    """A query head's columns in the blocks of a step: ``q`` in the
+    queries' (and ``dq``'s), ``out`` in the results' (and ``d_out``'s),
+    ``k`` and ``v`` its key/value head's in the keys' and the values'.
+    With one width a head (``hd == hdv``) ``q`` and ``out`` are one
+    slice, and ``k`` and ``v``."""
+    q: slice
+    out: slice
+    k: slice
+    v: slice
+
+
+def _columns(r, group, hd, hdv):
+    def at(i, width):
+        return slice(i * width, (i + 1) * width)
+    return _Columns(at(r, hd), at(r, hdv), at(r // group, hd),
+                    at(r // group, hdv))
+
+
+def _heads(k_ref, v_ref, heads, group, hd, hdv):
+    """For every query head of a block: its index, its columns
+    (``_Columns``), and its key/value head's keys and values, loaded
+    once a pair."""
     for r in range(heads):
-        own = slice(r // group * hd, (r // group + 1) * hd)
+        cols = _columns(r, group, hd, hdv)
         if r % group == 0:
-            k, v = k_ref[:, own], v_ref[:, own]
-        yield r, slice(r * hd, (r + 1) * hd), own, k, v
+            k, v = k_ref[:, cols.k], v_ref[:, cols.v]
+        yield r, cols, k, v
 
 
 def _scores(a, b, scale, keep, mask_value):
@@ -199,8 +228,8 @@ def _scores(a, b, scale, keep, mask_value):
     return s if keep is None else jnp.where(keep, s, mask_value)
 
 
-def _fwd_kernel(table_ref, *refs, heads, group, hd, scale, seen, mask_value,
-                live, ids):
+def _fwd_kernel(table_ref, *refs, heads, group, hd, hdv, scale, seen,
+                mask_value, live, ids):
     live_ref, id_refs, (q_ref, k_ref, v_ref, out_ref, lse_ref, *rest) = (
         _optional(refs, live, ids))
     # an operand narrower than f32 brings one more result: ``out``
@@ -218,33 +247,34 @@ def _fwd_kernel(table_ref, *refs, heads, group, hd, scale, seen, mask_value,
     def pair(masked):
         keep = _seen_tile(seen, qi * bq, kj * bkv, (bq, bkv), 0, id_refs) \
             if masked else None
-        for r, cols, _, k, v in _heads(k_ref, v_ref, heads, group, hd):
-            s = _scores(q_ref[:, cols], k, scale, keep, mask_value)
+        for r, cols, k, v in _heads(k_ref, v_ref, heads, group, hd, hdv):
+            s = _scores(q_ref[:, cols.q], k, scale, keep, mask_value)
             m_old = m_scr[r]
             m_new = jnp.maximum(m_old, s.max(axis=-1, keepdims=True))
             alpha = jnp.exp(m_old - m_new)
             p = jnp.exp(s - _lanes(m_new, bkv))
             m_scr[r] = m_new
             l_scr[r] = alpha * l_scr[r] + p.sum(axis=-1, keepdims=True)
-            acc_scr[:, cols] = _lanes(alpha, hd) * acc_scr[:, cols] + jnp.dot(
-                p.astype(v.dtype), v, preferred_element_type=jnp.float32)
+            acc_scr[:, cols.out] = (
+                _lanes(alpha, hdv) * acc_scr[:, cols.out] + jnp.dot(
+                    p.astype(v.dtype), v, preferred_element_type=jnp.float32))
 
     _by_kind(kind, pair)
 
     @pl.when(last)
     def _():
         for r in range(heads):
-            cols = slice(r * hd, (r + 1) * hd)
+            cols = _columns(r, group, hd, hdv).out
             l = l_scr[r]
-            out = acc_scr[:, cols] / _lanes(l, hd)
+            out = acc_scr[:, cols] / _lanes(l, hdv)
             out_ref[:, cols] = out.astype(out_ref.dtype)
             for ref in exact_ref:
                 ref[:, cols] = out
             lse_ref[:, r:r + 1] = (m_scr[r] + jnp.log(l))[:, :1]
 
 
-def _dq_kernel(table_ref, *refs, heads, group, hd, scale, seen, mask_value,
-               live, ids):
+def _dq_kernel(table_ref, *refs, heads, group, hd, hdv, scale, seen,
+               mask_value, live, ids):
     live_ref, id_refs, (q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                         dq_ref, dq_scr) = _optional(refs, live, ids)
     bq, bkv = q_ref.shape[0], k_ref.shape[0]
@@ -257,14 +287,14 @@ def _dq_kernel(table_ref, *refs, heads, group, hd, scale, seen, mask_value,
     def pair(masked):
         keep = _seen_tile(seen, qi * bq, kj * bkv, (bq, bkv), 0, id_refs) \
             if masked else None
-        for r, cols, _, k, v in _heads(k_ref, v_ref, heads, group, hd):
-            q = q_ref[:, cols]
+        for r, cols, k, v in _heads(k_ref, v_ref, heads, group, hd, hdv):
+            q = q_ref[:, cols.q]
             p = jnp.exp(_scores(q, k, scale, keep, mask_value)
                         - lse_ref[:, r:r + 1])
-            dp = lax.dot_general(do_ref[:, cols], v, _NT,
+            dp = lax.dot_general(do_ref[:, cols.out], v, _NT,
                                  preferred_element_type=jnp.float32)
             ds = (p * (dp - delta_ref[:, r:r + 1]) * scale).astype(q.dtype)
-            dq_scr[:, cols] += jnp.dot(
+            dq_scr[:, cols.q] += jnp.dot(
                 ds, k, preferred_element_type=jnp.float32)
 
     _by_kind(kind, pair)
@@ -274,8 +304,8 @@ def _dq_kernel(table_ref, *refs, heads, group, hd, scale, seen, mask_value,
         dq_ref[...] = dq_scr[...].astype(dq_ref.dtype)
 
 
-def _dkv_kernel(table_ref, *refs, heads, group, hd, scale, seen, mask_value,
-                live, ids):
+def _dkv_kernel(table_ref, *refs, heads, group, hd, hdv, scale, seen,
+                mask_value, live, ids):
     """Scores transposed, ``[keys, queries]``: every product is then a
     plain or a last-axes one, and ``lse``, ``delta`` and the queries'
     ids lie along the lanes."""
@@ -293,16 +323,16 @@ def _dkv_kernel(table_ref, *refs, heads, group, hd, scale, seen, mask_value,
     def pair(masked):
         keep = _seen_tile(seen, qi * bq, kj * bkv, (bkv, bq), 1, id_refs) \
             if masked else None
-        for r, cols, own, k, v in _heads(k_ref, v_ref, heads, group, hd):
-            q, do = q_ref[:, cols], do_ref[:, cols]
+        for r, cols, k, v in _heads(k_ref, v_ref, heads, group, hd, hdv):
+            q, do = q_ref[:, cols.q], do_ref[:, cols.out]
             p = jnp.exp(_scores(k, q, scale, keep, mask_value)
                         - lse_ref[r:r + 1, :])
-            dv_scr[:, own] += jnp.dot(p.astype(do.dtype), do,
-                                      preferred_element_type=jnp.float32)
+            dv_scr[:, cols.v] += jnp.dot(p.astype(do.dtype), do,
+                                         preferred_element_type=jnp.float32)
             dp = lax.dot_general(v, do, _NT,
                                  preferred_element_type=jnp.float32)
             ds = (p * (dp - delta_ref[r:r + 1, :]) * scale).astype(q.dtype)
-            dk_scr[:, own] += jnp.dot(
+            dk_scr[:, cols.k] += jnp.dot(
                 ds, q, preferred_element_type=jnp.float32)
 
     _by_kind(kind, pair)
@@ -313,12 +343,14 @@ def _dkv_kernel(table_ref, *refs, heads, group, hd, scale, seen, mask_value,
         dv_ref[...] = dv_scr[...].astype(dv_ref.dtype)
 
 
-def block_heads(heads: int, kv_heads: int, hd: int):
+def block_heads(heads: int, kv_heads: int, hd: int,
+                hdv: Optional[int] = None):
     """``(query heads, key/value heads)`` of a block, from the shapes
-    alone: the fewest key/value heads that fill whole 128-lane vectors,
-    and more of them, as many as divide ``kv_heads`` evenly, while their
-    query heads stay at or under ``_BLOCK_QUERY_HEADS``.  A group is
-    never divided: the fewest key/value heads bring all their query
+    alone: the fewest key/value heads that fill whole 128-lane vectors
+    at both of a head's widths (``hd`` of its keys, ``hdv`` of its
+    values: two of 64, and two of 192 against 128), and more of them,
+    as many as divide ``kv_heads`` evenly, while their query heads stay
+    at or under ``_BLOCK_QUERY_HEADS``.  A group is never divided: the fewest key/value heads bring all their query
     heads, so a group wider than ``_BLOCK_QUERY_HEADS`` (16 query heads
     on one key/value head of 128: the one-mixer stack of
     ``models/hybrid_moe.py``) makes a step of more query heads than
@@ -328,7 +360,8 @@ def block_heads(heads: int, kv_heads: int, hd: int):
     kernel 17 MiB of operands and results in flight, two buffers each,
     and 12 MiB of f32 sums)."""
     group = heads // kv_heads
-    fewest = max(1, _LANES // hd)
+    fewest = max(_LANES // math.gcd(width, _LANES)
+                 for width in (hd, hd if hdv is None else hdv))
     most = max(fewest, _BLOCK_QUERY_HEADS // group)
     kv = max(n for n in range(fewest, most + 1, fewest) if kv_heads % n == 0)
     return kv * group, kv
@@ -352,7 +385,8 @@ class _Kernels(NamedTuple):
     groups: int     # blocks of heads (``block_heads``) the heads make
     heads: int      # query heads of a block
     group: int      # query heads of a key/value head
-    hd: int
+    hd: int         # a head's width in q and k
+    hdv: int        # ... and in v and the result
     schedule: PairSchedule
     static: dict    # scale, mask_value, seen: the kernel bodies' keywords
     interpret: bool
@@ -360,24 +394,27 @@ class _Kernels(NamedTuple):
     live: Optional[jax.Array]
 
     @classmethod
-    def of(cls, q, k, schedule, seen, scale, mask_value, interpret, ids,
+    def of(cls, q, k, v, schedule, seen, scale, mask_value, interpret, ids,
            live):
         batch, positions, all_heads, hd = q.shape
-        heads, kv_heads = block_heads(all_heads, k.shape[2], hd)
+        hdv = v.shape[3]
+        heads, kv_heads = block_heads(all_heads, k.shape[2], hd, hdv)
         return cls(batch, positions, k.shape[2] // kv_heads, heads,
-                   all_heads // k.shape[2], hd, schedule,
+                   all_heads // k.shape[2], hd, hdv, schedule,
                    dict(scale=scale, mask_value=mask_value, seen=seen),
                    interpret, ids, live)
 
     @property
-    def kv_lanes(self) -> int:
-        """The key/value heads of a block, side by side."""
-        return self.hd * self.heads // self.group
+    def kv_heads(self) -> int:
+        """The key/value heads of a block."""
+        return self.heads // self.group
 
     def operand(self, kind: str, own: int = 0):
         """An operand's shape and how its blocks are cut, by its kind:
-        ``wide`` (a block's query heads of a query tile), ``narrow`` (its
-        key/value heads of a key tile), ``column`` and ``row`` (a
+        ``wide`` (a block's query heads of a query tile, as wide as ``q``;
+        ``wide_v`` as wide as the result), ``narrow`` (its key/value
+        heads of a key tile, as wide as ``k``; ``narrow_v`` as ``v``),
+        ``column`` and ``row`` (a
         query tile's f32 statistics down the sublanes or along the
         lanes), ``query_ids`` and ``key_ids`` down the sublanes or, with
         ``_row``, along the lanes.  An index map reads the pair's tiles
@@ -388,7 +425,7 @@ class _Kernels(NamedTuple):
         pipeline has and nothing is copied for it."""
         bq, bkv = self.schedule.block_q, self.schedule.block_kv
         b, p, g, h = self[:4]
-        hd, kv = self.hd, self.kv_lanes
+        kv = self.kv_heads
 
         def tile(row):
             through_held = self.live is not None and row != own
@@ -398,11 +435,19 @@ class _Kernels(NamedTuple):
             return of
 
         tq, tk = tile(0), tile(1)
+
+        def wide(hd):
+            return ((b, p, g * h * hd), pl.BlockSpec(
+                (None, bq, h * hd), lambda b, g, i, *t: (b, tq(b, i, *t), g)))
+
+        def narrow(hd):
+            return ((b, p, g * kv * hd), pl.BlockSpec(
+                (None, bkv, kv * hd),
+                lambda b, g, i, *t: (b, tk(b, i, *t), g)))
+
         return {
-            "wide": ((b, p, g * h * hd), pl.BlockSpec(
-                (None, bq, h * hd), lambda b, g, i, *t: (b, tq(b, i, *t), g))),
-            "narrow": ((b, p, g * kv), pl.BlockSpec(
-                (None, bkv, kv), lambda b, g, i, *t: (b, tk(b, i, *t), g))),
+            "wide": wide(self.hd), "wide_v": wide(self.hdv),
+            "narrow": narrow(self.hd), "narrow_v": narrow(self.hdv),
             "column": ((b, g, p, h), pl.BlockSpec(
                 (None, None, bq, h),
                 lambda b, g, i, *t: (b, g, tq(b, i, *t), 0))),
@@ -444,7 +489,8 @@ class _Kernels(NamedTuple):
         # follow
         return pl.pallas_call(
             functools.partial(body, heads=self.heads, group=self.group,
-                              hd=self.hd, live=self.live is not None,
+                              hd=self.hd, hdv=self.hdv,
+                              live=self.live is not None,
                               ids=self.ids is not None, **self.static),
             name=name,
             grid_spec=pltpu.PrefetchScalarGridSpec(
@@ -470,7 +516,8 @@ def forward(q, k, v, schedule: PairSchedule, seen: Callable, *,
             scale: float, mask_value: float, interpret: bool = False,
             ids: Optional[jax.Array] = None,
             live: Optional[jax.Array] = None):
-    """``(out, lse, exact)``: ``out`` like ``q``; every row's
+    """``(out, lse, exact)``: ``out`` like ``q`` with ``v``'s last axis
+    (``k`` is as wide as ``q``; ``v`` may be of another width); every row's
     log-sum-exp, f32 ``[B, blocks of heads, P, query heads of a block]``
     (``block_heads``), which only ``backward`` reads; and ``out``
     in f32 as it was before it was rounded to ``q``'s type (``out``
@@ -481,21 +528,23 @@ def forward(q, k, v, schedule: PairSchedule, seen: Callable, *,
     key tiles]``: 0 where row ``b`` need not run a pair of the schedule
     because ``seen`` is false all over it; every query tile keeps a pair
     (one with none would divide by a sum of nothing)."""
-    ks = _Kernels.of(q, k, schedule, seen, scale, mask_value, interpret,
+    ks = _Kernels.of(q, k, v, schedule, seen, scale, mask_value, interpret,
                      ids, live)
-    metrics.note_attention_block(ks.heads, ks.heads // ks.group)
+    metrics.note_attention_block(ks.heads, ks.kv_heads)
+    metrics.note_attention_head_width(ks.hd, ks.hdv)
     f32, bq = jnp.float32, schedule.block_q
-    outs = (("wide", q.dtype), ("column", f32)) + (
-        () if q.dtype == f32 else (("wide", f32),))
+    outs = (("wide_v", q.dtype), ("column", f32)) + (
+        () if q.dtype == f32 else (("wide_v", f32),))
     out, lse, *exact = ks.call(
         _fwd_kernel, "hvtpu_flash_attention_fwd", 0,
-        ("wide", "narrow", "narrow"), outs,
+        ("wide", "narrow", "narrow_v"), outs,
         [pltpu.VMEM((ks.heads, bq, _LANES), f32),
          pltpu.VMEM((ks.heads, bq, _LANES), f32),
-         pltpu.VMEM((bq, ks.heads * ks.hd), f32)],
+         pltpu.VMEM((bq, ks.heads * ks.hdv), f32)],
         _flat(q), _flat(k), _flat(v))
-    out = out.reshape(q.shape)
-    return out, lse, exact[0].reshape(q.shape) if exact else out
+    shape = (*q.shape[:3], ks.hdv)
+    out = out.reshape(shape)
+    return out, lse, exact[0].reshape(shape) if exact else out
 
 
 def backward(q, k, v, out, lse, d_out, schedule: PairSchedule,
@@ -513,13 +562,13 @@ def backward(q, k, v, out, lse, d_out, schedule: PairSchedule,
     findings of PR 28).  XLA gives its own tiles the same: it drops a
     rounding between two of its own fusions (excess precision), which
     it cannot do across a kernel's boundary."""
-    ks = _Kernels.of(q, k, schedule, seen, scale, mask_value, interpret,
+    ks = _Kernels.of(q, k, v, schedule, seen, scale, mask_value, interpret,
                      ids, live)
     f32 = jnp.float32
     delta = jnp.sum(d_out.astype(f32) * out.astype(f32), axis=-1).reshape(
         ks.batch, ks.positions, ks.groups, ks.heads).transpose(0, 2, 1, 3)
     operands = (_flat(q), _flat(k), _flat(v), _flat(d_out))
-    ins = ("wide", "narrow", "narrow", "wide")
+    ins = ("wide", "narrow", "narrow_v", "wide_v")
     dq, = ks.call(
         _dq_kernel, "hvtpu_flash_attention_dq", 0,
         ins + ("column", "column"), (("wide", q.dtype),),
@@ -527,7 +576,8 @@ def backward(q, k, v, out, lse, d_out, schedule: PairSchedule,
         *operands, lse, delta)
     dk, dv = ks.call(
         _dkv_kernel, "hvtpu_flash_attention_dkv", 1,
-        ins + ("row", "row"), (("narrow", k.dtype), ("narrow", v.dtype)),
-        [pltpu.VMEM((schedule.block_kv, ks.kv_lanes), f32)] * 2,
+        ins + ("row", "row"), (("narrow", k.dtype), ("narrow_v", v.dtype)),
+        [pltpu.VMEM((schedule.block_kv, ks.kv_heads * width), f32)
+         for width in (ks.hd, ks.hdv)],
         *operands, lse.swapaxes(2, 3), delta.swapaxes(2, 3))
     return dq.reshape(q.shape), dk.reshape(k.shape), dv.reshape(v.shape)

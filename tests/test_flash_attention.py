@@ -318,16 +318,21 @@ def segments(boundaries, positions):
     return segment
 
 
-def document_operands(head_dim, group, positions=512, kv_heads=2, seed=11):
+def document_operands(head_dim, group, positions=512, kv_heads=2, seed=11,
+                      value_dim=None):
+    """``q``, ``k``, ``v`` and a target like the result: ``v`` and the
+    result ``value_dim`` wide, where that is given."""
     ks = jax.random.split(jax.random.PRNGKey(seed), 4)
-    shape = (2, positions, group * kv_heads, head_dim)
-    kv = (2, positions, kv_heads, head_dim)
-    return (jax.random.normal(ks[0], shape), jax.random.normal(ks[1], kv),
-            jax.random.normal(ks[2], kv), jax.random.normal(ks[3], shape))
+    value_dim = value_dim or head_dim
+    heads = group * kv_heads
+    return (jax.random.normal(ks[0], (2, positions, heads, head_dim)),
+            jax.random.normal(ks[1], (2, positions, kv_heads, head_dim)),
+            jax.random.normal(ks[2], (2, positions, kv_heads, value_dim)),
+            jax.random.normal(ks[3], (2, positions, heads, value_dim)))
 
 
 def kernels_against_xla_tiles(monkeypatch, head_dim, group, packing,
-                              skipping, kv_heads=2):
+                              skipping, kv_heads=2, value_dim=None):
     monkeypatch.setattr(hs, "_FLASH_BLOCK_Q", 128)
     monkeypatch.setattr(hs, "_FLASH_BLOCK_KV", 128)
     if not skipping:
@@ -335,7 +340,8 @@ def kernels_against_xla_tiles(monkeypatch, head_dim, group, packing,
         monkeypatch.setattr(
             hs, "live_pairs", lambda *a: jnp.ones_like(live(*a)))
     segment = jnp.asarray(segments(PACKINGS[packing], 512))
-    q, k, v, target = document_operands(head_dim, group, kv_heads=kv_heads)
+    q, k, v, target = document_operands(head_dim, group, kv_heads=kv_heads,
+                                        value_dim=value_dim)
 
     def layer(q, k, v):
         return hs.causal_document_attention(
@@ -423,6 +429,51 @@ def test_sixteen_query_heads_on_one_key_value_head(
     assert block_heads_noted() == (16, 1)
 
 
+def head_widths_noted():
+    noted = metrics.REGISTRY.gauge("hvtpu_attention_head_width")
+    return noted.value(kind="key"), noted.value(kind="value")
+
+
+@pytest.mark.parametrize("packing", sorted(PACKINGS))
+@pytest.mark.parametrize("widths, kv_heads, group, block", [
+    ((192, 128), 8, 1, (8, 8)), ((192, 128), 2, 2, (4, 2)),
+    ((256, 128), 3, 1, (3, 3)), ((64, 128), 2, 1, (2, 2)),
+    ((128, 64), 4, 2, (8, 4))],
+    ids=lambda case: str(case).replace(" ", ""))
+def test_a_key_width_apart_from_the_value_width(
+        interpreted, monkeypatch, packing, widths, kv_heads, group, block):
+    """Latent attention's shape (``models.kimi_linear``: keys of 128 +
+    64 = 192, values of 128, a key/value head a query head), and other
+    pairs of widths: ``q`` and ``k`` are cut by the one width, ``v``, the
+    result and its cotangent by the other; a head of 192 is three halves
+    of a 128-lane vector, so the key/value heads go two to a block at
+    least.  ``out`` and the three gradients against the XLA tiles, and
+    what the kernels note of their shapes."""
+    hd, hdv = widths
+    kernels_against_xla_tiles(
+        monkeypatch, hd, group, packing, skipping=True, kv_heads=kv_heads,
+        value_dim=hdv)
+    assert block_heads_noted() == block
+    assert head_widths_noted() == widths
+
+
+def test_the_widths_the_kernels_take():
+    takes = flash_attention.supports
+    assert takes(192, jnp.bfloat16, 256, 128, 128, 32, 128)
+    # an odd number of key/value heads of 192 fills no whole block
+    assert not takes(192, jnp.bfloat16, 256, 128, 128, 3, 128)
+    assert takes(256, jnp.float32, 256, 128, 128, 3, 128)
+    assert not takes(96, jnp.float32, 256, 128, 128, 4, 128)
+    assert not takes(128, jnp.float32, 256, 128, 128, 4, 32)
+    # one width a head, given once or twice, is one answer
+    for hd, kv_heads in ((64, 2), (64, 3), (128, 3), (32, 4)):
+        assert takes(hd, jnp.float32, 256, 128, 128, kv_heads) == takes(
+            hd, jnp.float32, 256, 128, 128, kv_heads, hd)
+    assert flash_attention.block_heads(32, 32, 192, 128) == (8, 8)
+    assert flash_attention.block_heads(32, 32, 128, 128) == (
+        flash_attention.block_heads(32, 32, 128))
+
+
 @pytest.mark.parametrize("heads, kv_heads, hd, block", [
     (4, 1, 128, (4, 1)),        # the transformer cell: as before
     (32, 2, 128, (16, 1)),      # the one-mixer stack: a group is whole
@@ -487,7 +538,7 @@ def test_a_skipped_pair_holds_the_last_live_pairs_blocks(packing):
             assert 1 + np.count_nonzero(np.diff(row_held)) == row_flags.sum()
         q = jnp.zeros((2, 512, 4, 128))
         ks = flash_attention._Kernels.of(
-            q, q, hs._flash_schedule(512, 128, 128), hs._seen, 1.0, -1e30,
+            q, q, q, hs._flash_schedule(512, 128, 128), hs._seen, 1.0, -1e30,
             True, jnp.zeros((2, 512), jnp.int32), jnp.asarray(flags))
         through_held = {
             0: {"narrow", "key_ids", "key_ids_row"},

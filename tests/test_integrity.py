@@ -21,6 +21,20 @@ from horovod_tpu.native import core as ncore
 from horovod_tpu.native import fallback, wire
 from horovod_tpu.runner import run
 
+
+@pytest.fixture(autouse=True)
+def _leave_horovod_shut_down():
+    """Several tests here call ``horovod_tpu.init()`` in this process.
+    ``init()`` on an initialized process returns the state as it is and
+    reads no environment, so a test of another file that ran after one
+    of these on the same worker and set ``HVTPU_TRACE`` (or any other
+    ``HVTPU_*`` knob) before its own ``init()`` found it ignored
+    (``tests/test_tracing.py::TestLifecycle::test_shutdown_flushes_trace``
+    failed whenever ``--dist loadfile`` gave its worker this file
+    first).  Every test leaves the process shut down."""
+    yield
+    horovod_tpu.shutdown()
+
 NATIVE = ncore.available()
 
 _REPO_ROOT = os.path.dirname(os.path.dirname(horovod_tpu.__file__))

@@ -327,6 +327,25 @@ class TestHooks:
         assert metrics.REGISTRY.gauge("hvtpu_ssm_groups").value() == 1.0
         assert metrics.snapshot()["hvtpu_ssm_groups"]["type"] == "gauge"
 
+    def test_the_delta_rules_chunks_and_their_size(self):
+        chunks = metrics.REGISTRY.counter("hvtpu_kda_chunks_total")
+        before = chunks.value()
+        metrics.note_kda_chunks(256, 64)
+        metrics.note_kda_chunks(4, 16)
+        assert chunks.value() == before + 260
+        assert metrics.REGISTRY.gauge("hvtpu_kda_chunk_size").value() == 16.0
+        snapshot = metrics.snapshot()
+        assert snapshot["hvtpu_kda_chunks_total"]["type"] == "counter"
+        assert snapshot["hvtpu_kda_chunk_size"]["type"] == "gauge"
+
+    @pytest.mark.parametrize("widths", [(192, 128), (64, 64), (128, 128)])
+    def test_a_heads_two_widths_are_a_gauge(self, widths):
+        metrics.note_attention_head_width(*widths)
+        noted = metrics.REGISTRY.gauge("hvtpu_attention_head_width")
+        assert (noted.value(kind="key"), noted.value(kind="value")) == widths
+        assert metrics.snapshot()["hvtpu_attention_head_width"][
+            "type"] == "gauge"
+
     def test_eager_allreduce_counts_ops_and_bytes(self, hvt):
         import jax.numpy as jnp
 

@@ -533,3 +533,73 @@ def test_the_one_mixer_cells_step_fits_and_its_kernels_keep_their_scope(
             "hvtpu:moe.route", "hvtpu:moe.dispatch", "hvtpu:moe.experts",
             "hvtpu:moe.combine", "hvtpu:moe.shared", "hvtpu:lm_head"} <= set(
                 scopes.scope_by_instruction(text).values())
+
+
+KIMI_LINEAR_CELL = "kimi-linear-48b-a3b-5of27-t8k-b2"
+
+
+def test_the_kimi_linear_cells_step_fits_and_says_which_paths_it_took(
+        v5e_2x2):
+    """The whole step of ``kimi-linear-48b-a3b-5of27-t8k-b2`` as
+    ``benchmark/job.py`` builds it, 602 M parameters trained at 12 bytes
+    each and 16,384 tokens a step, for one described chip: XLA:TPU fits
+    it in the 15.75 GiB it has (it refuses a program that does not; the
+    allocator on the chip counts 15.9 GB at the peak: PERF.md, findings
+    of PR 40), 4.8 GB of it parameters and momentum that the step
+    updates in place.  Its latent attention, 32 heads with keys of 192
+    on values of 128 and document ids, runs in the three kernels of
+    ``ops/flash_attention.py`` under ``hvtpu:attention``, the recomputed
+    forward too, none with kernel metadata, eight heads to a grid step
+    (``lse`` and ``delta`` ``[2, 4, 8192, 8]``): ``q``, ``k``, ``dq``
+    and ``dk`` are ``32 x 192 = 6144`` wide, ``v``, the result and
+    ``dv`` ``32 x 128 = 4096``, and nothing is filled to 256.  The gated
+    experts' products at 2304 x 1024 do not fit the grouped kernels'
+    VMEM rule, so they are XLA's own ``ragged-dot`` kernels (the reader
+    of ``gated_experts_ms_per_step`` finds them by name); the delta rule
+    is plain XLA under ``hvtpu:kda.delta``; and the scopes the cell's
+    readers join are there."""
+    import re
+
+    from benchmark import cells, scopes
+
+    from horovod_tpu.ops import grouped_ffn
+    from horovod_tpu.parallel import moe
+
+    cell = cells.load_cell(KIMI_LINEAR_CELL)
+    compiled = _compiled_step(cell, v5e_2x2)
+    m = compiled.memory_analysis()
+    assert 4.8e9 <= m.argument_size_in_bytes <= 4.83e9
+    assert m.alias_size_in_bytes >= 4.8e9
+    text = compiled.as_text()
+    assert _attention_kernels_by_scope(text) == {
+        kernel: {"hvtpu:attention"} for kernel in ATTENTION_KERNELS}
+    kernels = re.findall(
+        r"^\s*(?:ROOT\s+)?%hvtpu_\w+(?:\.\d+)? = .*$", text, re.MULTILINE)
+    attention = [line for line in kernels if "%hvtpu_flash_attention" in line]
+    assert len(attention) == 4          # forward, recomputed, dq, dk/dv
+    assert not any("kernel_metadata" in line.replace(
+        "kernel_metadata={}", "") for line in kernels)
+    for line in attention:
+        assert set(re.findall(r"bf16\[2,8192,(\d+)\]", line)) == {
+            "4096", "6144"}, line
+        assert not re.search(r"bf16\[2,8192,8192\]", line)    # 32 x 256
+        shapes = set(re.findall(r"f32\[2,(\d+),(\d+),(\d+)\]", line))
+        assert shapes == ({("4", "8", "8192")} if "_dkv" in line
+                          else {("4", "8192", "8")}), line
+    for kernel, result in (("_dq", "bf16[2,8192,6144]"),
+                           ("_dkv", "(bf16[2,8192,6144]")):
+        line, = (line for line in attention if kernel in line)
+        assert line.split(" = ")[1].startswith(result), line
+    tokens = cell.traffic["batch_per_chip"] * cell.traffic["sequence_length"]
+    assert not grouped_ffn.supports(jnp.bfloat16, 2304, 1024, moe.buffer_rows(
+        tokens, 8, 8), 128)
+    assert moe.products_path(
+        jnp.bfloat16, 2304, 1024, tokens, 8, 8, "gated") == "ragged_dot"
+    assert re.findall(r"^\s*%(ragged-dot\S*) = ", text, re.MULTILINE)
+    assert not re.findall(r"%hvtpu_grouped_ffn", text)
+    assert {"hvtpu:kda.proj", "hvtpu:kda.conv", "hvtpu:kda.gate",
+            "hvtpu:kda.delta", "hvtpu:mla.proj", "hvtpu:attention",
+            "hvtpu:mlp", "hvtpu:moe.route", "hvtpu:moe.dispatch",
+            "hvtpu:moe.experts", "hvtpu:moe.combine", "hvtpu:moe.shared",
+            "hvtpu:lm_head"} <= set(
+                scopes.scope_by_instruction(text).values())

@@ -199,6 +199,11 @@ class TestLifecycle:
         the atexit hook's path) flushes a strictly-valid JSON file."""
         import jax.numpy as jnp
 
+        # init() on an initialized process reads no environment: a test
+        # of another file that left this worker initialized would have
+        # HVTPU_TRACE ignored here.  Start from a process shut down.
+        horovod_tpu.shutdown()
+        assert tracing.ACTIVE is False and tracing.get_tracer() is None
         monkeypatch.setenv("HVTPU_TRACE", str(tmp_path))
         horovod_tpu.init()
         try:
