@@ -74,7 +74,11 @@ take the compute type and add up in f32.  Everything up to ``U_v = (I +
 A)^-1 beta V`` and ``W = (I + A)^-1 beta exp(G) K`` is made for all
 chunks at once; the chunks are then walked in time by a ``lax.scan`` that
 carries the state and is differentiated through, each step recomputed in
-the backward pass.
+the backward pass.  That is the XLA form, which runs on the CPU, with
+``HVTPU_PALLAS=0`` and for shapes the kernels do not take; on a TPU the
+same arithmetic runs in the two Pallas kernels of ``ops/delta_rule.py``,
+a chunk's products, system, inverse and state in VMEM
+(``hvtpu_kda_calls_total{path=}`` says which was built in).
 
 *Documents* and the loss are ``hybrid_ssm``'s: ``segment`` gives the
 document's index at every position; state, convolution and attention
@@ -98,6 +102,7 @@ import jax.numpy as jnp
 from jax import lax
 
 from ..obs import metrics
+from ..ops import delta_rule, pallas_ops
 from ..parallel.moe import dropless_topk_moe
 from . import hybrid_ssm
 from .block_diffusion import rms_norm
@@ -395,7 +400,13 @@ def chunked_delta_rule(q, k, v, g, beta, segment, chunk: int):
     H, V]``, ``g`` f32 ``[B, T, H, K]`` (a log-decay a channel, at most
     0), ``beta`` f32 ``[B, T, H]``, ``segment`` int ``[B, T]``; ``chunk``
     a power of two.  The result is ``[B, T, H, V]`` in ``q``'s type.  The
-    module's docstring has the form and what is kept in f32."""
+    module's docstring has the form and what is kept in f32.
+
+    Which implementation runs is observed, not set, as in
+    ``hybrid_ssm.causal_document_attention``: the Pallas kernels of
+    ``ops/delta_rule.py`` where ``ops.pallas_ops`` compiles kernels and
+    ``delta_rule.supports`` the widths, the chunk and the type; the XLA
+    form below everywhere else."""
     b, t, h, dk = q.shape
     dtype, f32 = q.dtype, jnp.float32
     chunk = min(chunk, 1 << (t - 1).bit_length())
@@ -408,6 +419,13 @@ def chunked_delta_rule(q, k, v, g, beta, segment, chunk: int):
         segment = jnp.pad(segment, ((0, 0), (0, pad)), constant_values=-1)
     n = (t + pad) // chunk
     metrics.note_kda_chunks(b * n, chunk)
+    use, interpret = pallas_ops._pallas_mode()
+    if use and q.dtype == k.dtype == v.dtype and delta_rule.supports(
+            dk, v.shape[-1], chunk, q.dtype):
+        metrics.note_kda_path("pallas")
+        return delta_rule.chunked_delta_rule(
+            q, k, v, g, beta, segment, chunk, interpret=interpret)[:, :t]
+    metrics.note_kda_path("xla")
 
     def chunks(a):      # [B, n * C, H, ...] -> [B, H, n, C, ...]
         return jnp.moveaxis(

@@ -853,6 +853,20 @@ def note_kda_chunks(chunks: int, chunk_size: int) -> None:
             float(chunk_size))
 
 
+def note_kda_path(path: str) -> None:
+    """Count one call of ``models.kimi_linear.chunked_delta_rule`` by the
+    implementation it took: ``"pallas"`` (the kernels of
+    ``ops/delta_rule.py``) or ``"xla"``.  Called while a program is
+    traced, once a call site and a trace, like ``note_attention_path``:
+    a step that scans its layers counts one call however many layers run
+    it."""
+    REGISTRY.counter(
+        "hvtpu_kda_calls_total",
+        "Calls of the chunked delta rule, counted when a program is "
+        "traced, by the implementation that was built in: the Pallas "
+        "kernels or XLA.").inc(path=path)
+
+
 def note_packed_batch(segment) -> None:
     """Record what a packed batch holds: ``segment`` is the int ``[rows,
     T]`` array of the document's index at every position, as the batch

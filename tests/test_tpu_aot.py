@@ -556,12 +556,18 @@ def test_the_kimi_linear_cells_step_fits_and_says_which_paths_it_took(
     experts' products at 2304 x 1024 do not fit the grouped kernels'
     VMEM rule, so they are XLA's own ``ragged-dot`` kernels (the reader
     of ``gated_experts_ms_per_step`` finds them by name); the delta rule
-    is plain XLA under ``hvtpu:kda.delta``; and the scopes the cell's
-    readers join are there."""
+    runs in the two kernels of ``ops/delta_rule.py`` under
+    ``hvtpu:kda.delta``, the forward one twice a run of KDA layers
+    (forward, and recomputed for the backward one), and no f32 array of
+    the rule's chunks (``[2, 32, 128, 64, 128]``, the XLA form's) is left
+    under that scope; and the scopes the cell's readers join are
+    there."""
     import re
 
     from benchmark import cells, scopes
 
+    from benchmark.builders import kimi_linear_lm
+    from horovod_tpu.models import kimi_linear as kl
     from horovod_tpu.ops import grouped_ffn
     from horovod_tpu.parallel import moe
 
@@ -597,6 +603,19 @@ def test_the_kimi_linear_cells_step_fits_and_says_which_paths_it_took(
         jnp.bfloat16, 2304, 1024, tokens, 8, 8, "gated") == "ragged_dot"
     assert re.findall(r"^\s*%(ragged-dot\S*) = ", text, re.MULTILINE)
     assert not re.findall(r"%hvtpu_grouped_ffn", text)
+    delta = [re.search(r"%(hvtpu_delta_rule_\w+?)(?:\.\d+)? =", line)[1]
+             for line in kernels if "%hvtpu_delta_rule" in line]
+    groups = sum(mixer == "kda" for mixer, _, _ in kl.layer_groups(
+        *kimi_linear_lm.layer_kinds(cell.config)))
+    assert sorted(delta) == (["hvtpu_delta_rule_bwd"] * groups
+                             + ["hvtpu_delta_rule_fwd"] * 2 * groups)
+    by_instruction = scopes.scope_by_instruction(text)
+    assert {scope for name, scope in by_instruction.items()
+            if name.startswith("hvtpu_delta_rule")} == {"hvtpu:kda.delta"}
+    assert not [name for name, scope in by_instruction.items()
+                if scope == "hvtpu:kda.delta" and re.search(
+                    r"^\s*(?:ROOT\s+)?%" + re.escape(name)
+                    + r" = f32\[2,32,128,64,128\]", text, re.MULTILINE)]
     assert {"hvtpu:kda.proj", "hvtpu:kda.conv", "hvtpu:kda.gate",
             "hvtpu:kda.delta", "hvtpu:mla.proj", "hvtpu:attention",
             "hvtpu:mlp", "hvtpu:moe.route", "hvtpu:moe.dispatch",
