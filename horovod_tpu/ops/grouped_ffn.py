@@ -45,42 +45,54 @@ not all one group's the other rows of ``xs``, ``gs`` and ``wt`` are set
 to 0 *before* any product (zeros in give zeros in every operand of the
 three sums: a 0 on one side alone does not make a NaN on the other
 harmless); a row's own results depend on that row alone, and a visit
-stores its own rows only.
+stores its own rows only.  This backward kernel takes the group's whole
+inner width at once, its sums as double-buffered output blocks, and
+with them three weights twice over: 2 x 3 x D x F x (2 + 4) bytes in
+bfloat16, 54 MiB at 2048 x 768, where ``_VMEM_FOR_A_GROUP`` leaves the
+tiles room.  Wider gated experts take the blocked kernel below (2304 x
+1024 would want 81 MiB here and 54 there).
 
 *The ungated kernels* (``W_down relu(W_up u) ** 2``) walk the same
 visits.  ``forward``: ``a = xs Wu``, ``h = relu(a) ** 2``, ``y = (h Wd)
-* wt``, two products a visit; ``backward``: ``a`` again, ``dh = gs
+* wt``, two products a visit, the inner width in blocks of
+``inner_block(F)`` columns; ``backward``: ``a`` again, ``dh = gs
 Wd^T``, ``dwt``, ``da = dh * wt * 2 relu(a)``, ``dx = da Wu^T``, ``dWu
-= xs^T da``, ``dWd = h^T (gs * wt)``, five.  Such experts are wide (2688
-x 1856 in the cell that has them: a weight 10 MB in bfloat16, an f32
-sum 20), so a group's weights *and* sums, each twice over, do not fit
-the 128 MiB of VMEM.  Nothing of the ungated form couples two columns
-of the inner width, so both kernels walk it in blocks of ``inner_block
-(F)`` columns (``h[:, blk]`` needs ``Wu[:, blk]`` alone, ``dWu[:, blk]``
-and ``dWd[blk, :]`` that block alone; ``y``, ``dx`` and ``dwt`` add up
-over the blocks in f32 values of one visit): the temporaries are a
-block's, the weights come whole, twice over, through their block
-specs, and the two f32 sums are **once** in VMEM, in the kernel's own
-scratch: a group's last visit sends each block of them off to HBM by a
-copy of its own as soon as the block is summed, and the next group's
-first visit waits for a block's copy just before it clears the block,
-so the copies run beside the products of both visits.  Every visit
-clears the rows that are not its group's and stores its own rows only,
-a whole tile like a shared one: one body, not the gated kernels' two
-(the selects are a hundredth of a visit; with a second path for whole
-tiles the backward kernel took 3.33 ms for 2.33 and was twice the
+= xs^T da``, ``dWd = h^T (gs * wt)``, five, always blocked.  Such
+experts are wide (2688 x 1856 in the cell that has them: a weight 10 MB
+in bfloat16, an f32 sum 20), so a group's weights *and* sums, each
+twice over, do not fit the 128 MiB of VMEM.
+
+*The blocked backward kernel*, of either form (the form is the number
+of weights it is handed).  Nothing of an expert couples two columns of
+the inner width, so it walks it in blocks of ``inner_block(F)``
+columns (``h[:, blk]`` needs ``Wg[:, blk]`` and ``Wu[:, blk]`` alone,
+``dWg[:, blk]``, ``dWu[:, blk]`` and ``dWd[blk, :]`` that block alone;
+``dx`` and ``dwt`` add up over the blocks in f32 values of one visit):
+the temporaries are a block's, the weights come whole, twice over,
+through their block specs, and the f32 sums are **once** in VMEM, in
+the kernel's own scratch: a group's last visit sends each block of them
+off to HBM by a copy of its own as soon as the block is summed, and the
+next group's first visit waits for a block's copy just before it
+clears the block, so the copies run beside the products of both
+visits.  That is weights x D x F x (2 x 2 + 4) bytes in bfloat16, 78.8
+MiB at 2688 x 1920 with two weights and 54 MiB at 2304 x 1024 with
+three, under ``_VMEM_FOR_BLOCKS``.  Every visit clears the rows that
+are not its group's and stores its own rows only, a whole tile like a
+shared one: one body, not the whole-width gated kernels' two (the
+selects are a hundredth of a visit; with a second path for whole tiles
+the ungated backward kernel took 3.33 ms for 2.33 and was twice the
 code), and the walk over the blocks is a loop the compiler keeps
 (``lax.fori_loop``, the blocks cut at lane offsets that are multiples
 of 128): unrolled, a kernel is three to five times the code for a
 tenth less time, and a step whose expert layers are programs of their
 own holds a copy of each kernel a layer, which its every start loads.
-``F`` is a whole number of 128-lane vectors: a caller fills an odd
-width with zero columns of ``w_up`` and zero rows of ``w_down``
-(``padded_width``), which is exact.  Timed alone on a v5e at that
-cell's shape (8 groups, 6,144 uneven live rows of 98,304; PERF.md,
-findings of PR 39): forward 1.08 ms, backward 2.55 (0.62 and 1.56 at
-the peak; 0.92 and 2.33 unrolled), where the backward pass as two
-kernels (rows with ``da`` and ``h`` to HBM, then the sums through
+The ungated ``F`` is a whole number of 128-lane vectors: a caller fills
+an odd width with zero columns of ``w_up`` and zero rows of ``w_down``
+(``padded_width``), which is exact.  Timed alone on a v5e at the
+ungated cell's shape (8 groups, 6,144 uneven live rows of 98,304;
+PERF.md, section 6): forward 1.08 ms, backward 2.55 (0.62 and
+1.56 at the peak; 0.92 and 2.33 unrolled), where the backward pass as
+two kernels (rows with ``da`` and ``h`` to HBM, then the sums through
 double-buffered output blocks) took 4.10 and ``lax.ragged_dot`` over
 the whole buffers 11.9 and 27.5.
 
@@ -108,18 +120,19 @@ from jax.experimental.pallas import tpu as pltpu
 _LANES = 128
 _NT = (((1,), (1,)), ((), ()))      # a @ b.T
 _TN = (((0,), (0,)), ((), ()))      # a.T @ b
-# the gated backward kernel holds a group's three f32 [D, F] sums twice
-# over (a block and the one being written back) and its three weights
-# twice (a block and the next group's on its way): 54 MiB of bfloat16 at
-# 2048 x 768, of the 128 a v5e core has, before a tile or a temporary
+# the gated backward kernel with a group's whole inner width holds the
+# group's three f32 [D, F] sums twice over (a block and the one being
+# written back) and its three weights twice (a block and the next
+# group's on its way): 54 MiB of bfloat16 at 2048 x 768, of the 128 a
+# v5e core has, before a tile or a temporary
 _VMEM_LIMIT = 100 * 1024 * 1024
 _VMEM_FOR_A_GROUP = 64 * 1024 * 1024
-# the ungated one holds its two weights twice and its two sums once,
-# 78.8 MiB of bfloat16 at 2688 x 1920, and its temporaries are a block's
-# of the inner width: at visits of 128 rows and blocks of 384 columns
-# Mosaic fits it in the 100 MiB, at 256 rows or 640 columns not (it
-# wants 120: PERF.md, findings of PR 39)
-_VMEM_FOR_TWO_WEIGHTS = 80 * 1024 * 1024
+# the blocked one holds the weights twice and their sums once, and its
+# temporaries are a block's of the inner width: 78.8 MiB of bfloat16 at
+# 2688 x 1920 with two weights, where visits of 128 rows and blocks of
+# 384 columns fit the 100 MiB and 256 rows or 640 columns do not (they
+# want 120: PERF.md, section 6); 54 MiB at 2304 x 1024 with three
+_VMEM_FOR_BLOCKS = 80 * 1024 * 1024
 _F_BLOCK = 512
 
 
@@ -128,21 +141,30 @@ def supports(dtype, d: int, f: int, rows: int, tile_rows: int,
     """Shapes the kernels take: operands the MXU takes, widths of whole
     128-lane vectors, a buffer of whole tiles, tiles of whole vector
     tiles (16 sublanes of bfloat16), and a group's weights and sums
-    that leave the tiles room in VMEM.  Of the ungated form (``weights``
-    2) ``f`` may be any: its caller fills it to ``padded_width(f)``."""
+    that leave the tiles room in VMEM, in one backward kernel or the
+    other (``_blocked``).  Of the ungated form (``weights`` 2) ``f`` may
+    be any: its caller fills it to ``padded_width(f)``."""
     if weights == 2:
         f = padded_width(f)
-        # both weights twice (a block and the next group's on its way)
-        # and both f32 sums once, in the kernel's own scratch
-        in_vmem = 2 * d * f * (2 * jnp.dtype(dtype).itemsize + 4)
-        fits = in_vmem <= _VMEM_FOR_TWO_WEIGHTS
-    else:
-        fits = (2 * 3 * d * f * (jnp.dtype(dtype).itemsize + 4)
-                <= _VMEM_FOR_A_GROUP)
     return (jnp.dtype(dtype) in (jnp.bfloat16, jnp.float32)
             and d % _LANES == 0 and f % _LANES == 0
             and tile_rows % 16 == 0 and rows >= tile_rows
-            and rows % tile_rows == 0 and fits)
+            and rows % tile_rows == 0
+            and _blocked(dtype, d, f, weights) is not None)
+
+
+def _blocked(dtype, d: int, f: int, weights: int):
+    """Whether the backward kernel walks the inner width in blocks: not
+    where a gated group's three weights and three f32 sums fit VMEM
+    twice over whole (``_backward_kernel``), else where all of a
+    group's weights twice and their sums once do
+    (``_blocked_backward_kernel``); ``None`` where neither fits."""
+    size = jnp.dtype(dtype).itemsize
+    if weights == 3 and 2 * 3 * d * f * (size + 4) <= _VMEM_FOR_A_GROUP:
+        return False
+    if weights * d * f * (2 * size + 4) <= _VMEM_FOR_BLOCKS:
+        return True
+    return None
 
 
 def padded_width(f: int) -> int:
@@ -153,9 +175,9 @@ def padded_width(f: int) -> int:
 
 
 def inner_block(f: int) -> int:
-    """The columns of an ungated expert's inner width a kernel takes at
-    once: the most whole vectors, up to ``_F_BLOCK`` lanes, that divide
-    ``f``."""
+    """The columns of an expert's inner width a kernel that walks it in
+    blocks takes at once: the most whole vectors, up to ``_F_BLOCK``
+    lanes, that divide ``f``."""
     return max(b for b in range(_LANES, _F_BLOCK + 1, _LANES) if f % b == 0)
 
 
@@ -296,9 +318,31 @@ def _relu2_forward_kernel(offsets, group, tile, x_ref, wt_ref, wu_ref, wd_ref,
     _store_rows(y_ref, y * wt_ref[:, :1], mine)
 
 
-def _relu2_backward_kernel(offsets, group, tile, x_ref, g_ref, wt_ref,
-                           wu_ref, wd_ref, dx_ref, dwt_ref, dwu_ref, dwd_ref,
-                           su_ref, sd_ref, sems, *, tile_rows):
+def _hidden(pre):
+    """An expert's hidden row from its first products ``pre`` (f32),
+    and what the row's weighted cotangent makes of each of them:
+    ``silu(a) * b`` of two (the gated form), ``relu(a) ** 2`` of one."""
+    if len(pre) == 2:
+        a, b = pre
+        sig = jax.nn.sigmoid(a)
+        s = a * sig
+        return s * b, lambda dh: (dh * b * sig * (1.0 + a * (1.0 - sig)),
+                                  dh * s)
+    r = jax.nn.relu(pre[0])
+    return r * r, lambda dh: (dh * (2.0 * r),)
+
+
+def _blocked_backward_kernel(offsets, group, tile, x_ref, g_ref, wt_ref,
+                             *refs, tile_rows):
+    """The backward kernel with the inner width walked in blocks, of
+    either form: ``refs`` are the weights (three or two, the last the
+    one back to ``D``), ``dx``, ``dwt``, the weights' gradients where
+    they lie in HBM, their sums in VMEM, a weight each, and the copies'
+    semaphores."""
+    n = (len(refs) - 3) // 3
+    *w_in, wd_ref = refs[:n]
+    dx_ref, dwt_ref = refs[n:n + 2]
+    grads, sums, sems = refs[n + 2:2 * n + 2], refs[2 * n + 2:-1], refs[-1]
     v = pl.program_id(0)
     mine, _, _ = _mine(offsets, group, tile, tile_rows)
     start, end = offsets[group[v]], offsets[group[v] + 1]
@@ -306,17 +350,16 @@ def _relu2_backward_kernel(offsets, group, tile, x_ref, g_ref, wt_ref,
     # without rows is visited once: both)
     first = start >= tile[v] * tile_rows
     last = end <= (tile[v] + 1) * tile_rows
-    blocks, _, f_block = su_ref.shape
+    blocks, _, f_block = sums[0].shape
 
     def leaving(j):
-        """Block ``j`` of the group's two sums on its way out."""
+        """Block ``j`` of the group's sums on its way out."""
         at = pl.multiple_of(j * f_block, _LANES)
-        return (pltpu.make_async_copy(
-                    su_ref.at[j], dwu_ref.at[group[v], :, pl.ds(at, f_block)],
-                    sems.at[0, j]),
-                pltpu.make_async_copy(
-                    sd_ref.at[j], dwd_ref.at[group[v], pl.ds(at, f_block), :],
-                    sems.at[1, j]))
+        cols, rows = (slice(None), pl.ds(at, f_block)), (pl.ds(at, f_block),)
+        return [pltpu.make_async_copy(
+                    total.at[j], grad.at[(group[v],) + where], sems.at[k, j])
+                for k, (total, grad, where) in enumerate(zip(
+                    sums, grads, [cols] * (n - 1) + [rows]))]
 
     def clear(j):
         """A group's first visit clears block ``j`` of the sums, once
@@ -327,8 +370,8 @@ def _relu2_backward_kernel(offsets, group, tile, x_ref, g_ref, wt_ref,
             def _():
                 for copy in leaving(j):
                     copy.wait()
-            su_ref[j] = jnp.zeros_like(su_ref[j])
-            sd_ref[j] = jnp.zeros_like(sd_ref[j])
+            for total in sums:
+                total[j] = jnp.zeros_like(total[j])
 
     def send(j):
         @pl.when(last)
@@ -337,26 +380,28 @@ def _relu2_backward_kernel(offsets, group, tile, x_ref, g_ref, wt_ref,
                 copy.start()
 
     def own(rows):
-        # the other rows as zeros give zeros: da, dy, dx, dwt, the sums
+        # the other rows as zeros give zeros: da, db, dy, dx, dwt, the sums
         return jnp.where(mine, rows.astype(jnp.float32), 0.0).astype(
             rows.dtype)
 
     x, g, wt = own(x_ref[...]), own(g_ref[...]), own(wt_ref[:, :1])
     dy = (g.astype(jnp.float32) * wt).astype(x.dtype)
 
-    def block(j, sums):
-        dx, dwt = sums
+    def block(j, carry):
+        dx, dwt = carry
         at = pl.multiple_of(j * f_block, _LANES)
-        wu, wd = wu_ref[:, pl.ds(at, f_block)], wd_ref[pl.ds(at, f_block), :]
-        r = jax.nn.relu(_dot(x, wu))
-        h = r * r
+        w_blk = [w[:, pl.ds(at, f_block)] for w in w_in]
+        wd = wd_ref[pl.ds(at, f_block), :]
+        h, slopes = _hidden([_dot(x, w) for w in w_blk])
         dh = _dot(g, wd, _NT)                       # before the weighting
         dwt += jnp.sum(dh * h, axis=-1, keepdims=True)
-        da = (dh * wt * (2.0 * r)).astype(x.dtype)
-        dx += _dot(da, wu, _NT)
+        d_pre = [d.astype(x.dtype) for d in slopes(dh * wt)]
+        for d, w in zip(d_pre, w_blk):
+            dx += _dot(d, w, _NT)
         clear(j)
-        su_ref[j] += _dot(x, da, _TN)
-        sd_ref[j] += _dot(h.astype(x.dtype), dy, _TN)
+        for total, d in zip(sums, d_pre):
+            total[j] += _dot(x, d, _TN)
+        sums[-1][j] += _dot(h.astype(x.dtype), dy, _TN)
         send(j)
         return dx, dwt
 
@@ -368,7 +413,7 @@ def _relu2_backward_kernel(offsets, group, tile, x_ref, g_ref, wt_ref,
     _store_rows(dx_ref, dx, mine)
     _store_rows(dwt_ref, dwt, mine)
 
-    @pl.when(last & (group[v] == dwu_ref.shape[0] - 1))
+    @pl.when(last & (group[v] == grads[0].shape[0] - 1))
     def _():
         for j in range(blocks):
             for copy in leaving(j):
@@ -435,17 +480,19 @@ def backward(xs, gs, wt, counts, *weights, tile_rows: int,
     rows = [(jax.ShapeDtypeStruct(xs.shape, xs.dtype), "rows"),
             (jax.ShapeDtypeStruct(wt.shape, jnp.float32), "rows")]
     sums = [jax.ShapeDtypeStruct(w.shape, jnp.float32) for w in weights]
-    if len(weights) == 3:
+    _, d, f = weights[0].shape
+    if not _blocked(xs.dtype, d, f, len(weights)):
         return _call(_backward_kernel, "hvtpu_grouped_ffn_bwd", walk,
                      tile_rows, ins, rows + [(a, "group") for a in sums],
                      interpret)
-    # the two sums in the kernel's own VMEM, a block of the inner width
+    # the sums in the kernel's own VMEM, a block of the inner width
     # after another, and a semaphore for each block's copy out
-    _, d, f = weights[0].shape
     f_block = inner_block(f)
+    blocks = f // f_block
     return _call(
-        _relu2_backward_kernel, "hvtpu_grouped_ffn_bwd", walk, tile_rows,
+        _blocked_backward_kernel, "hvtpu_grouped_ffn_bwd", walk, tile_rows,
         ins, rows + [(a, "hbm") for a in sums], interpret,
-        scratch=[pltpu.VMEM((f // f_block, d, f_block), jnp.float32),
-                 pltpu.VMEM((f // f_block, f_block, d), jnp.float32),
-                 pltpu.SemaphoreType.DMA((2, f // f_block))])
+        scratch=[pltpu.VMEM((blocks, d, f_block), jnp.float32)] * (
+            len(weights) - 1)
+        + [pltpu.VMEM((blocks, f_block, d), jnp.float32),
+           pltpu.SemaphoreType.DMA((len(weights), blocks))])

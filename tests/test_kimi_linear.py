@@ -562,6 +562,39 @@ def test_the_counters_tell_the_rule_the_form_and_the_widths():
                    path="xla") == before["attention"] + 1
 
 
+def test_the_published_expert_layer_takes_the_grouped_kernels(
+        config, interpreted):
+    """At the published widths, 2304 x 1024 over 16,384 tokens top-8 of
+    the 8 held, the gated experts' products are the grouped kernels
+    where kernels compile (here the interpreter), the backward one
+    walking the inner width in blocks (a group's three weights and
+    three f32 sums do not fit VMEM twice over whole):
+    ``hvtpu_moe_products_total{path="grouped"}`` counts the layer when
+    a step of it is traced, forward and backward."""
+    from benchmark.builders import kimi_linear_lm
+    from horovod_tpu.ops import grouped_ffn
+
+    cfg = kimi_linear_lm.model_config(config)
+    shapes = jax.eval_shape(
+        lambda key: kl.init_params(key, cfg), jax.random.PRNGKey(0))
+    p = jax.tree_util.tree_map(      # one layer of a stacked pair
+        lambda a: jax.ShapeDtypeStruct(a.shape[1:], a.dtype),
+        shapes["layers"][1])
+    x = jax.ShapeDtypeStruct((2, 8192, cfg.hidden_size),
+                             jnp.dtype(cfg.compute_dtype))
+    assert p["w_gate"].shape == (8, 2304, 1024)
+    assert grouped_ffn._blocked(cfg.compute_dtype, 2304, 1024, 3)
+    before = {path: counter("hvtpu_moe_products_total", path=path)
+              for path in ("grouped", "ragged_dot")}
+    grads = jax.eval_shape(jax.grad(lambda p, x: jnp.sum(
+        kl.expert_ffn(cfg, p, x)[0].astype(jnp.float32))), p, x)
+    assert grads["w_down"].shape == (8, 1024, 2304)
+    assert counter("hvtpu_moe_products_total",
+                   path="grouped") == before["grouped"] + 1
+    assert counter("hvtpu_moe_products_total",
+                   path="ragged_dot") == before["ragged_dot"]
+
+
 def test_the_routing_of_a_step_feeds_note_moe_routing():
     batch = batch_of([[20]], 64)
     (_, state), _ = system(TOY, params_of(TOY), batch)

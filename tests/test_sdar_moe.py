@@ -455,8 +455,8 @@ def test_a_walk_visits_every_tile_of_every_group_once(counts, empty_groups):
 @pytest.mark.parametrize("where, dtype, width, tokens, path", [
     ("tpu", jnp.bfloat16, 128, 1024, "grouped"),
     ("tpu", jnp.float32, 512, 32768, "grouped"),
-    # a group's f32 weights and sums, twice over, crowd the tiles out
-    ("tpu", jnp.float32, 2048, 32768, "ragged_dot"),
+    # a group's f32 weights twice over and sums once crowd the tiles out
+    ("tpu", jnp.float32, 4096, 32768, "ragged_dot"),
     ("tpu", jnp.bfloat16, 64, 1024, "ragged_dot"),      # the rehearsals
     ("tpu", jnp.float16, 128, 1024, "ragged_dot"),
     ("tpu", jnp.bfloat16, 128, 8, "ragged_dot"),        # no whole tile
@@ -473,6 +473,42 @@ def test_how_the_products_run_is_read_off_the_backend_and_the_shapes(
     monkeypatch.setattr(pallas_ops, "_on_tpu", lambda: where != "cpu")
     assert moe.products_path(dtype, width, 768, tokens, 8, 16) == path
     assert moe.products_path(dtype, 2048, width, tokens, 8, 16) == path
+
+
+@pytest.mark.parametrize("where, dtype, d, f, path, blocked", [
+    # the Kimi Linear cell's: three weights and three f32 sums twice over
+    # are 81 MiB, three weights twice and the sums once 54
+    ("interpret", jnp.bfloat16, 2304, 1024, "grouped", True),
+    ("tpu", jnp.bfloat16, 2304, 1024, "grouped", True),
+    # the transformer cell's: whole, 54 MiB, the kernel of before
+    ("tpu", jnp.bfloat16, 2048, 768, "grouped", False),
+    ("tpu", jnp.float32, 2048, 768, "grouped", True),
+    # beyond the blocked rule too: 108 and 81 MiB of its 80
+    ("tpu", jnp.bfloat16, 2304, 2048, "ragged_dot", None),
+    ("tpu", jnp.float32, 2304, 1024, "ragged_dot", None),
+    ("tpu_without_pallas", jnp.bfloat16, 2304, 1024, "ragged_dot", True),
+    ("cpu", jnp.bfloat16, 2304, 1024, "ragged_dot", True)])
+def test_which_backward_kernel_a_gated_shape_takes(
+        monkeypatch, where, dtype, d, f, path, blocked):
+    """``products_path`` of gated experts over the Kimi Linear cell's
+    16,384 tokens, top-8 of 8 held, and which backward kernel the
+    grouped path takes there (``grouped_ffn._blocked``): the group's
+    whole inner width where its weights and sums fit VMEM twice over,
+    else blocks of it where the weights twice and the sums once do;
+    ``lax.ragged_dot`` where neither, on the CPU and with
+    ``HVTPU_PALLAS=0``."""
+    from horovod_tpu.ops import grouped_ffn, pallas_ops
+
+    monkeypatch.delenv("HVTPU_PALLAS_INTERPRET", raising=False)
+    monkeypatch.delenv("HVTPU_PALLAS", raising=False)
+    if where == "interpret":
+        monkeypatch.setenv("HVTPU_PALLAS_INTERPRET", "1")
+    if where == "tpu_without_pallas":
+        monkeypatch.setenv("HVTPU_PALLAS", "0")
+    monkeypatch.setattr(pallas_ops, "_on_tpu", lambda: where.startswith(
+        "tpu"))
+    assert moe.products_path(dtype, d, f, 16384, 8, 8, "gated") == path
+    assert grouped_ffn._blocked(dtype, d, f, 3) is blocked
 
 
 # -- the other routing rule and the other form of expert ---------------------
@@ -647,16 +683,19 @@ def test_the_grouped_kernels_take_both_forms(monkeypatch):
                              "relu2") == "ragged_dot"
 
 
-# -- the ungated experts in the grouped kernels, interpreted ------------------
+# -- the blocked backward kernel, both forms, interpreted ----------------------
 
 @pytest.fixture
 def inner_blocks_of_128(monkeypatch):
     """An inner width of 256 is two blocks, one of 320 is filled to 384
-    and is three."""
+    and is three; and a gated group whose weights and sums fit VMEM
+    whole takes the blocked kernel all the same."""
     from horovod_tpu.ops import grouped_ffn
 
     monkeypatch.setattr(grouped_ffn, "_F_BLOCK", 128)
+    monkeypatch.setattr(grouped_ffn, "_VMEM_FOR_A_GROUP", 0)
     assert grouped_ffn.inner_block(grouped_ffn.padded_width(320)) == 128
+    assert grouped_ffn._blocked(jnp.bfloat16, 128, 256, 3)
 
 
 def _dense_relu2(x, weight, hit, w_up, w_down):
@@ -668,27 +707,40 @@ def _dense_relu2(x, weight, hit, w_up, w_down):
     return y
 
 
-@pytest.mark.parametrize("f", [256, 320])
+# (form, inner width): an ungated case is named by its width alone
+FORMS = [pytest.param("relu2", 256, id="256"),
+         pytest.param("relu2", 320, id="320"),
+         pytest.param("gated", 256, id="gated-256")]
+
+
+@pytest.mark.parametrize("form, f", FORMS)
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
 @pytest.mark.parametrize("routing", [
     "even", "an_expert_without_rows", "every_token_here", "off_every_tile",
     "nobody_here"])
 def test_the_ungated_kernels_equal_every_expert_over_every_token(
-        monkeypatch, grouped, inner_blocks_of_128, routing, dtype, f):
-    """``_grouped_ffn`` through the two-weight kernels against the
+        monkeypatch, grouped, inner_blocks_of_128, routing, dtype, form, f):
+    """``_grouped_ffn`` through the blocked backward kernel against the
     dense sum: the result and the gradients of ``x``, ``weight`` and
-    both weights, the inner width walked in two blocks (256) or filled
-    from 320 to 384 and walked in three, where the filled columns'
-    gradients are exact zeros; bfloat16 rows against the dense sum in
-    float32.  Then again with every row of the buffers that no
-    assignment owns NaN before the products: everything comes out
-    finite and as from buffers of zeros, bit for bit."""
+    every weight, the inner width walked in two blocks (256) or, of two
+    weights, filled from 320 to 384 and walked in three, where the
+    filled columns' gradients are exact zeros; three weights (the gated
+    form) or two; bfloat16 rows against the dense sum in float32.  Then
+    again with every row of the buffers that no assignment owns NaN
+    before the products: everything comes out finite and as from
+    buffers of zeros, bit for bit."""
     x, _, experts = _weights(21, 4, n=64, d=128, f=f)
     hit = _hits(routing)
     weight = jax.random.uniform(jax.random.PRNGKey(2), hit.shape)
     target = jax.random.normal(jax.random.PRNGKey(9), x.shape)
-    w_up, w_down = moe._in_whole_vectors(experts["w_up"], experts["w_down"])
-    assert w_up.shape[2] == w_down.shape[1] == {256: 256, 320: 384}[f]
+    if form == "gated":
+        weights, dense = tuple(experts[k] for k in (
+            "w_gate", "w_up", "w_down")), _dense
+    else:
+        weights = moe._in_whole_vectors(experts["w_up"], experts["w_down"])
+        dense = _dense_relu2
+        assert weights[0].shape[2] == weights[1].shape[1] == {
+            256: 256, 320: 384}[f]
 
     def ours(x, weight, *w):
         plan, sizes = moe._plan(jnp.asarray(hit), 3)
@@ -698,13 +750,13 @@ def test_the_ungated_kernels_equal_every_expert_over_every_token(
     def run(f):
         return jax.jit(jax.value_and_grad(
             lambda *a: jnp.sum(f(*a).astype(jnp.float32) * target),
-            (0, 1, 2, 3)))(x, weight, w_up, w_down)
+            tuple(range(2 + len(weights)))))(x, weight, *weights)
 
-    assert moe.products_path(dtype, 128, f, 64, 3, 4, "relu2") == "grouped"
+    assert moe.products_path(dtype, 128, f, 64, 3, 4, form) == "grouped"
     got = jax.tree_util.tree_leaves(run(ours))
     want = jax.tree_util.tree_leaves(run(
-        lambda x, weight, *w: _dense_relu2(x, weight, jnp.asarray(hit), *w)))
-    assert len(got) == 5
+        lambda x, weight, *w: dense(x, weight, jnp.asarray(hit), *w)))
+    assert len(got) == 3 + len(weights)
     for g, w in zip(got, want):
         assert np.isfinite(np.asarray(g)).all()
         if routing == "nobody_here":
@@ -712,8 +764,8 @@ def test_the_ungated_kernels_equal_every_expert_over_every_token(
         else:               # the loss is a sum that cancels
             _close(g, w, rtol=(1e-5 if dtype == jnp.float32 else 0.05)
                    * (1 if np.ndim(g) else 10))
-    assert not np.asarray(got[3])[:, :, f:].any()
-    assert not np.asarray(got[4])[:, f:, :].any()
+    assert not np.asarray(got[-2])[:, :, f:].any()
+    assert not np.asarray(got[-1])[:, f:, :].any()
     monkeypatch.setattr(
         moe, "_row_buffer", lambda rows, width, dtype, near: jnp.full(
             (rows, width), jnp.nan, dtype))
@@ -721,33 +773,41 @@ def test_the_ungated_kernels_equal_every_expert_over_every_token(
         np.testing.assert_array_equal(np.asarray(g), np.asarray(clean))
 
 
-@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+@pytest.mark.parametrize("dtype, form", [
+    pytest.param(dtype, form, id=jnp.dtype(dtype).name + (
+        "-gated" if form == "gated" else ""))
+    for form in ("relu2", "gated") for dtype in (jnp.float32, jnp.bfloat16)])
 @pytest.mark.parametrize("routing", ["uneven", "one_expert", "all_here",
                                      "none_here"])
 def test_through_the_kernels_the_ungated_layer_equals_the_plain_loop(
-        monkeypatch, grouped, inner_blocks_of_128, routing, dtype):
-    """``dropless_topk_moe`` with two-weight experts 320 wide, its
-    products in the kernels (the layer fills the width to 384 and the
-    gradients come back 320 wide), rigged routers and all, against the
-    plain loop: the result, the rows each expert got and the gradients
-    of the tokens, the router and both weights; and against the same
-    layer on the ``ragged_dot`` body, whose arithmetic the kernels
-    keep (in bfloat16 a router rigged to one expert has a gradient of
-    differences that cancel: both bodies are a fifth off the float32
-    loop there, and a part in a hundred thousand off each other)."""
+        monkeypatch, grouped, inner_blocks_of_128, routing, dtype, form):
+    """``dropless_topk_moe`` with the blocked backward kernel, rigged
+    routers and all, against the plain loop: the result, the rows each
+    expert got and the gradients of the tokens, the router and every
+    weight; and against the same layer on the ``ragged_dot`` body,
+    whose arithmetic the kernels keep (in bfloat16 a router rigged to
+    one expert has a gradient of differences that cancel: both bodies
+    are a fifth off the float32 loop there, and a part in a hundred
+    thousand off each other).  Two-weight experts 320 wide (the layer
+    fills the width to 384 and the gradients come back 320 wide), or
+    gated ones 256 wide."""
     first_expert, held, top_k = 8, 4, 3
+    f = 320 if form == "relu2" else 256
     counters = {
         name: moe.metrics.REGISTRY.counter(name).value(**label)
         for name, label in (("hvtpu_moe_products_total", {"path": "grouped"}),
-                            ("hvtpu_moe_experts_form_total",
-                             {"form": "relu2"}))}
+                            ("hvtpu_moe_experts_form_total", {"form": form}))}
     x, gate_w, experts = _rigged(routing, held, first_expert, dtype,
-                                 n=64, d=128, f=320)
-    experts = {k: experts[k] for k in ("w_up", "w_down")}
+                                 n=64, d=128, f=f)
+    if form == "relu2":
+        experts = {k: experts[k] for k in ("w_up", "w_down")}
+        plain_loop = functools.partial(_plain_relu2, top_k=top_k,
+                                       first_expert=first_expert)
+    else:
+        plain_loop = functools.partial(plain, top_k=top_k,
+                                       first_expert=first_expert)
     kwargs = dict(top_k=top_k, first_expert=first_expert)
     target = jax.random.normal(jax.random.PRNGKey(9), x.shape)
-
-    plain_relu2 = functools.partial(_plain_relu2, **kwargs)
 
     def run(f, x):
         return jax.jit(jax.value_and_grad(
@@ -757,23 +817,24 @@ def test_through_the_kernels_the_ungated_layer_equals_the_plain_loop(
     got = jax.tree_util.tree_leaves(
         run(lambda *a: layer(*a, **kwargs)[0], x))
     want = jax.tree_util.tree_leaves(
-        run(plain_relu2, x.astype(jnp.float32)))
+        run(plain_loop, x.astype(jnp.float32)))
     assert moe.metrics.REGISTRY.counter("hvtpu_moe_products_total").value(
         path="grouped") == counters["hvtpu_moe_products_total"] + 1
     assert moe.metrics.REGISTRY.counter(
         "hvtpu_moe_experts_form_total").value(
-            form="relu2") == counters["hvtpu_moe_experts_form_total"] + 1
+            form=form) == counters["hvtpu_moe_experts_form_total"] + 1
     rows = np.asarray(layer(x, gate_w, experts, **kwargs)[1][
         "rows_per_expert"])
     assert {"uneven": 0 < rows.sum() < 64 * top_k and rows[-1] == 0,
             "one_expert": rows[0] == 64,
             "all_here": rows.sum() == 64 * top_k,
             "none_here": rows.sum() == 0}[routing]
-    assert [g.shape for g in got[3:]] == [(held, 320, 128), (held, 128, 320)]
-    assert len(got) == 5
+    assert [g.shape for g in got[3:]] == [
+        w.shape for w in jax.tree_util.tree_leaves(experts)]
+    assert len(got) == 3 + len(experts)
     monkeypatch.setenv("HVTPU_PALLAS", "0")
-    assert moe.products_path(dtype, 128, 320, 64, top_k, held,
-                             "relu2") == "ragged_dot"
+    assert moe.products_path(dtype, 128, f, 64, top_k, held,
+                             form) == "ragged_dot"
     ragged = jax.tree_util.tree_leaves(
         run(lambda *a: layer(*a, **kwargs)[0], x))
     for i, (g, w, r) in enumerate(zip(got, want, ragged)):

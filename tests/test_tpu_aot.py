@@ -553,9 +553,12 @@ def test_the_kimi_linear_cells_step_fits_and_says_which_paths_it_took(
     (``lse`` and ``delta`` ``[2, 4, 8192, 8]``): ``q``, ``k``, ``dq``
     and ``dk`` are ``32 x 192 = 6144`` wide, ``v``, the result and
     ``dv`` ``32 x 128 = 4096``, and nothing is filled to 256.  The gated
-    experts' products at 2304 x 1024 do not fit the grouped kernels'
-    VMEM rule, so they are XLA's own ``ragged-dot`` kernels (the reader
-    of ``gated_experts_ms_per_step`` finds them by name); the delta rule
+    experts' products at 2304 x 1024 are the two grouped kernels of
+    ``ops/grouped_ffn.py``, the backward one walking the inner width in
+    blocks (a group's weights and sums do not fit VMEM twice over
+    whole), a forward and a backward one for each of the three runs of
+    expert layers, under ``hvtpu:moe.experts``, and no ``ragged-dot``
+    kernel of XLA's is left; the delta rule
     runs in the two kernels of ``ops/delta_rule.py`` under
     ``hvtpu:kda.delta``, the forward one twice a run of KDA layers
     (forward, and recomputed for the backward one), and no f32 array of
@@ -597,16 +600,18 @@ def test_the_kimi_linear_cells_step_fits_and_says_which_paths_it_took(
         line, = (line for line in attention if kernel in line)
         assert line.split(" = ")[1].startswith(result), line
     tokens = cell.traffic["batch_per_chip"] * cell.traffic["sequence_length"]
-    assert not grouped_ffn.supports(jnp.bfloat16, 2304, 1024, moe.buffer_rows(
+    assert grouped_ffn.supports(jnp.bfloat16, 2304, 1024, moe.buffer_rows(
         tokens, 8, 8), 128)
+    assert grouped_ffn._blocked(jnp.bfloat16, 2304, 1024, 3)
     assert moe.products_path(
-        jnp.bfloat16, 2304, 1024, tokens, 8, 8, "gated") == "ragged_dot"
-    assert re.findall(r"^\s*%(ragged-dot\S*) = ", text, re.MULTILINE)
-    assert not re.findall(r"%hvtpu_grouped_ffn", text)
+        jnp.bfloat16, 2304, 1024, tokens, 8, 8, "gated") == "grouped"
+    assert not re.findall(r"^\s*%(ragged-dot\S*) = ", text, re.MULTILINE)
+    layer_groups = kl.layer_groups(*kimi_linear_lm.layer_kinds(cell.config))
+    _the_experts_products_are_the_grouped_kernels(text, layers=sum(
+        ffn == "experts" for _, ffn, _ in layer_groups))
     delta = [re.search(r"%(hvtpu_delta_rule_\w+?)(?:\.\d+)? =", line)[1]
              for line in kernels if "%hvtpu_delta_rule" in line]
-    groups = sum(mixer == "kda" for mixer, _, _ in kl.layer_groups(
-        *kimi_linear_lm.layer_kinds(cell.config)))
+    groups = sum(mixer == "kda" for mixer, _, _ in layer_groups)
     assert sorted(delta) == (["hvtpu_delta_rule_bwd"] * groups
                              + ["hvtpu_delta_rule_fwd"] * 2 * groups)
     by_instruction = scopes.scope_by_instruction(text)
