@@ -7,10 +7,10 @@ Pure functions over a parameter tree.  ``HybridMoEConfig.pattern`` has a
 letter a layer, ``M``, ``*`` or ``E`` as the family's
 ``hybrid_override_pattern`` writes them; neighbours of one kind are
 stacked and run under one ``lax.scan`` (``layer_groups``), every layer
-recomputed in the backward pass.  The mechanisms are the other models':
-``hybrid_ssm.mamba_mixer`` (the chunked scan, the convolution cut at
-document starts, the group-wise gated norm),
-``hybrid_ssm.causal_document_attention`` (the Pallas kernels of
+recomputed in the backward pass.  The mechanisms are called, not
+copied: ``hybrid_ssm.mamba_mixer`` (the chunked scan, the convolution
+cut at document starts, the group-wise gated norm),
+``attention.causal_document_attention`` (the Pallas kernels of
 ``ops/flash_attention.py`` where they run) and
 ``parallel.moe.dropless_topk_moe``; this module is the stack, the
 shared expert, the plain embedding and the untied head.
@@ -64,7 +64,8 @@ from jax import lax
 
 from ..parallel.moe import dropless_topk_moe
 from . import hybrid_ssm
-from .block_diffusion import rms_norm
+from .attention import causal_document_attention
+from .layers import rms_norm, weighted_next_token_cross_entropy
 
 Params = Dict[str, Any]
 
@@ -201,9 +202,8 @@ def attention_layer(cfg: HybridMoEConfig, p: Params, x, segment):
         q, k, v = (heads(p["wq"], cfg.num_heads),
                    heads(p["wk"], cfg.num_kv_heads),
                    heads(p["wv"], cfg.num_kv_heads))
-    o = hybrid_ssm.causal_document_attention(
-        q, k, v, segment, scale=cfg.head_dim ** -0.5,
-        tile=hybrid_ssm._ATTENTION_TILE)
+    o = causal_document_attention(q, k, v, segment,
+                                  scale=cfg.head_dim ** -0.5)
     with jax.named_scope("hvtpu:attn.proj"):
         return x + o.reshape(b, t, -1) @ p["wo"].astype(dtype)
 
@@ -274,6 +274,6 @@ def next_token_loss(params: Params, batch, cfg: HybridMoEConfig):
     ``segment``, ``w``).  Returns the loss and the routing's counts
     ``{"moe_rows_per_expert": int32 [expert layers, experts_held]}``."""
     hidden, rows = hidden_states(params, batch["x"], cfg, batch["segment"])
-    return (hybrid_ssm.weighted_next_token_cross_entropy(
+    return (weighted_next_token_cross_entropy(
         logits_of(params, hidden, cfg), batch),
         {"moe_rows_per_expert": rows})

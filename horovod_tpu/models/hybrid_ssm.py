@@ -33,9 +33,9 @@ from the state carried in; the chunks are walked in time by a
 
 *The attention layer*: q, k, v, o without bias, no positional encoding,
 scores times ``attention_multiplier`` (not ``1/sqrt(head_dim)``),
-causal.  On a TPU its tile pairs run in the Pallas kernels of
-``ops/flash_attention.py``, which are handed the document ids; elsewhere
-in XLA, a tile of queries at a time (``causal_document_attention``).
+causal inside a document (``attention.causal_document_attention``: on
+a TPU the Pallas kernels of ``ops/flash_attention.py``, handed the
+document ids; elsewhere XLA, a tile of queries at a time).
 
 *Documents.*  A row is several documents back to back; ``segment``
 gives the document's index at every position.  At a document's first
@@ -62,30 +62,18 @@ of the heads is not a part of a sum that can be left out.
 from __future__ import annotations
 
 import dataclasses
-import functools
 import itertools
 from typing import Any, Dict, List, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
-import numpy as np
 from jax import lax
 
 from ..obs import metrics
-from ..ops import flash_attention, pallas_ops
-from .block_diffusion import _MASKED, rms_norm
+from .attention import causal_document_attention
+from .layers import causal_conv, rms_norm, weighted_next_token_cross_entropy
 
 Params = Dict[str, Any]
-
-# queries of a tile of the attention layer: the f32 scores of a tile
-# against every earlier key, 2 rows x 32 heads x 256 x 8,192, are 0.5 GB
-_ATTENTION_TILE = 256
-
-# queries and keys of a block of the Pallas kernels
-# (``ops/flash_attention.py``): the transformer's, PERF.md, findings of
-# PRs 28 and 33
-_FLASH_BLOCK_Q = 512
-_FLASH_BLOCK_KV = 512
 
 
 @dataclasses.dataclass(frozen=True)
@@ -204,26 +192,6 @@ def init_params(key, cfg: HybridSSMConfig) -> Params:
 # ---------------------------------------------------------------------------
 # the Mamba-2 mixer
 # ---------------------------------------------------------------------------
-
-def causal_conv(x, w, b, segment):
-    """Depthwise convolution over time of ``x`` ``[B, T, C]`` with taps
-    ``w`` ``[K, C]`` (the last tap at the position itself) and bias
-    ``b``, in f32.  A tap that reaches before the row's or the
-    document's start reads zero.  ``x`` and ``segment`` are padded once
-    at the front and every tap is a window of the same length into
-    them (a pad a tap made four passes of one; PERF.md, findings of PR
-    32)."""
-    taps, t = w.shape[0], x.shape[1]
-    padded = jnp.pad(x, ((0, 0), (taps - 1, 0), (0, 0)))
-    before = jnp.pad(segment, ((0, 0), (taps - 1, 0)), constant_values=-1)
-    y = jnp.broadcast_to(b, x.shape).astype(jnp.float32)
-    for tap in range(taps):
-        same = before[:, tap:tap + t] == segment
-        y = y + jnp.where(
-            same[..., None], padded[:, tap:tap + t].astype(jnp.float32),
-            0.0) * w[tap]
-    return y
-
 
 def _chunk(carry, inputs, a_head, d_skip, causal):
     """One chunk of ``ssd_scan`` for every row: ``carry`` is the state
@@ -366,150 +334,6 @@ def mamba_mixer(cfg: HybridSSMConfig, p: Params, u, segment):
 # the attention layer
 # ---------------------------------------------------------------------------
 
-def _seen(q_pos, k_pos, q_doc, k_doc):
-    """The mask: a key at or before the query, in its document."""
-    return (k_pos <= q_pos) & (q_doc == k_doc)
-
-
-@functools.lru_cache(maxsize=None)
-def _flash_schedule(positions: int, block_q: int,
-                    block_kv: int) -> flash_attention.PairSchedule:
-    """The kernels' walk: the causal triangle of block pairs, made from
-    positions alone.  Which of them hold a pair of one document is data
-    (``live_pairs``), so none is known to be full."""
-    first_key = np.arange(0, positions, block_kv)
-    last_query = np.arange(0, positions, block_q) + block_q - 1
-    return flash_attention.pair_schedule(
-        flash_attention.PARTIAL * (first_key[None, :] <= last_query[:, None]),
-        block_q, block_kv)
-
-
-def live_pairs(segment, block_q: int, block_kv: int):
-    """int32 ``[B, query blocks, key blocks]``: 0 where no query of the
-    one block and no key of the other can be of one document, because
-    the ranges of their ids do not meet.  ``segment`` numpy or jax."""
-    b = segment.shape[0]
-
-    def ranges(block):
-        ids = segment.reshape(b, -1, block)
-        return ids.min(axis=-1), ids.max(axis=-1)
-
-    (q_lo, q_hi), (k_lo, k_hi) = ranges(block_q), ranges(block_kv)
-    return ((k_lo[:, None, :] <= q_hi[:, :, None])
-            & (q_lo[:, :, None] <= k_hi[:, None, :])).astype(np.int32)
-
-
-def _flash_blocks(positions: int) -> Tuple[int, int]:
-    return min(_FLASH_BLOCK_Q, positions), min(_FLASH_BLOCK_KV, positions)
-
-
-def note_attention_pairs(segment) -> None:
-    """Count, from the host's loop like ``metrics.note_packed_batch``,
-    the block pairs of the causal triangle the kernels run and skip on a
-    batch of this ``segment`` (numpy ``[B, T]``, whole blocks)."""
-    blocks = _flash_blocks(segment.shape[1])
-    query_block, key_block = _flash_schedule(
-        segment.shape[1], *blocks).by_query[:2]
-    run = int(live_pairs(np.asarray(segment), *blocks)[
-        :, query_block, key_block].sum())
-    metrics.note_attention_pairs(
-        run, segment.shape[0] * len(query_block) - run)
-
-
-def _in_kernels(kernels, spec, segment, *operands):
-    """``flash_attention.forward`` or ``.backward`` on ``operands``
-    (``q`` first) under the document mask."""
-    scale, block_q, block_kv, interpret = spec
-    return kernels(
-        *operands, _flash_schedule(operands[0].shape[1], block_q, block_kv),
-        _seen, scale=scale, mask_value=_MASKED, interpret=interpret,
-        ids=segment, live=live_pairs(segment, block_q, block_kv))
-
-
-@functools.partial(jax.custom_vjp, nondiff_argnums=(4,))
-def _flash(q, k, v, segment, spec):
-    return _flash_fwd(q, k, v, segment, spec)[0]
-
-
-@jax.named_scope("hvtpu:attention")
-def _flash_fwd(q, k, v, segment, spec):
-    out, lse, exact = _in_kernels(
-        flash_attention.forward, spec, segment, q, k, v)
-    return out, (q, k, v, exact, lse, segment)
-
-
-@jax.named_scope("hvtpu:attention")
-def _flash_bwd(spec, res, d_out):
-    *res, segment = res
-    return (*_in_kernels(flash_attention.backward, spec, segment, *res,
-                         d_out), None)
-
-
-_flash.defvjp(_flash_fwd, _flash_bwd)
-
-
-def causal_document_attention(q, k, v, segment, *, scale: float, tile: int):
-    """Attention of ``q`` ``[B, T, H, hd]`` over ``k`` ``[B, T, G, hd]``
-    and ``v`` ``[B, T, G, hdv]``, every query head reading the key/value
-    head of its group: a query sees the keys at or before it in its own
-    document, and the result is ``[B, T, H, hdv]``.  A head has two
-    widths: ``hd`` of its queries and keys, ``hdv`` of its values and
-    results, which need not be equal (latent attention: keys of 128 +
-    64 against values of 128).  Scores and softmax in f32; the products
-    take ``q``'s type.  Everything in it runs under the scope
-    ``hvtpu:attention``.
-
-    Which implementation runs is observed, not set, as in
-    ``block_diffusion.tiled_attention``.  Where ``ops.pallas_ops``
-    compiles kernels and the shapes are ones they take, the causal
-    triangle of block pairs runs in the Pallas kernels of
-    ``ops/flash_attention.py`` with ``segment`` as the mask's data: a
-    pair's scores stay in VMEM, and a pair whose blocks share no
-    document is skipped when the step runs.  Elsewhere in XLA: a tile
-    of queries at a time against the keys up to the tile's end (the
-    tiles beyond are never computed), a plain softmax, each tile
-    recomputed in the backward pass, no pair left out.
-    ``hvtpu_attention_calls_total{path=}`` counts, when a program is
-    traced, which it was."""
-    b, t, heads, hd = q.shape
-    groups, hdv = k.shape[2], v.shape[3]
-    use, interpret = pallas_ops._pallas_mode()
-    blocks = _flash_blocks(t)
-    if use and q.dtype == k.dtype == v.dtype and flash_attention.supports(
-            hd, q.dtype, t, *blocks, groups, hdv):
-        metrics.note_attention_path("pallas")
-        return _flash(q, k, v, segment.astype(jnp.int32),
-                      (scale, *blocks, interpret))
-    tile = min(tile, t)
-    metrics.note_attention_path("xla")
-
-    @jax.checkpoint
-    def rows(q_rows, keys, values, seg_q, seg_k, first):
-        s = jnp.einsum("bqgrd,bkgd->bgrqk", q_rows, keys,
-                       preferred_element_type=jnp.float32) * scale
-        at = first + jnp.arange(q_rows.shape[1])
-        seen = ((jnp.arange(keys.shape[1])[None, :] <= at[:, None])[None]
-                & (seg_q[:, :, None] == seg_k[:, None, :]))
-        s = jnp.where(seen[:, None, None], s, _MASKED)
-        # the row's maximum is made once and kept: left to itself inside
-        # a whole step, the compiler recomputed it for every score as a
-        # window of 16,383 keys, 47 ms a tile (PERF.md, findings of PR 32)
-        top = lax.optimization_barrier(
-            lax.stop_gradient(jnp.max(s, axis=-1, keepdims=True)))
-        p = jnp.exp(s - top)
-        p = p / jnp.sum(p, axis=-1, keepdims=True)
-        return jnp.einsum("bgrqk,bkgd->bqgrd", p.astype(values.dtype), values,
-                          preferred_element_type=jnp.float32
-                          ).astype(q_rows.dtype)
-
-    with jax.named_scope("hvtpu:attention"):
-        q = q.reshape(b, t, groups, heads // groups, hd)
-        out = [rows(q[:, a:a + tile], k[:, :a + tile], v[:, :a + tile],
-                    segment[:, a:a + tile], segment[:, :a + tile], a)
-               for a in range(0, t, tile)]
-        return jnp.concatenate(out, axis=1).reshape(b, t, heads, hdv)
-
-
 def attention_mixer(cfg: HybridSSMConfig, p: Params, u, segment):
     b, t, _ = u.shape
     dtype = u.dtype
@@ -520,7 +344,7 @@ def attention_mixer(cfg: HybridSSMConfig, p: Params, u, segment):
     o = causal_document_attention(
         heads(p["wq"], cfg.num_heads), heads(p["wk"], cfg.num_kv_heads),
         heads(p["wv"], cfg.num_kv_heads), segment,
-        scale=cfg.attention_multiplier, tile=_ATTENTION_TILE)
+        scale=cfg.attention_multiplier)
     return o.reshape(b, t, -1) @ p["wo"].astype(dtype)
 
 
@@ -582,17 +406,6 @@ def logits_of(params: Params, hidden, cfg: HybridSSMConfig):
         return lax.dot_general(
             u, params["embed"].astype(u.dtype), (((2,), (1,)), ((), ())),
             preferred_element_type=jnp.float32) / cfg.logits_scaling
-
-
-def weighted_next_token_cross_entropy(logits, batch):
-    """The mean over the batch's weighted positions of ``w_t CE(logits_t,
-    x_{t+1})``, from f32 ``logits`` ``[B, T, V]``."""
-    with jax.named_scope("hvtpu:lm_head"):
-        label = jnp.roll(batch["x"], -1, axis=1)
-        ce = (jax.nn.logsumexp(logits, axis=-1) - jnp.take_along_axis(
-            logits, label[..., None], axis=-1)[..., 0])
-        w = batch["w"].astype(jnp.float32)
-        return jnp.sum(w * ce) / jnp.sum(w)
 
 
 def next_token_loss(params: Params, batch, cfg: HybridSSMConfig):

@@ -8,12 +8,12 @@ a shared expert, trained on packed documents: the ``kimi_linear`` family
 Pure functions over a parameter tree.  ``KimiLinearConfig.mixers`` and
 ``.ffns`` name each layer's two halves; neighbours that agree in both
 are stacked and run under one ``lax.scan`` (``layer_groups``), each
-half of a layer recomputed in the backward pass by itself.  What other
-models have is called, not copied: ``hybrid_ssm.causal_conv`` (cut at
-document starts), ``hybrid_ssm.causal_document_attention`` (the Pallas kernels of
-``ops/flash_attention.py`` where they run, a key width of their own),
-``parallel.moe.dropless_topk_moe`` and
-``hybrid_ssm.weighted_next_token_cross_entropy``.  This module is the
+half of a layer recomputed in the backward pass by itself.  What the
+other models have is called, not copied: ``layers.causal_conv`` (cut at
+document starts), ``attention.causal_document_attention`` (the Pallas
+kernels of ``ops/flash_attention.py`` where they run, a key width of
+their own), ``parallel.moe.dropless_topk_moe`` and
+``layers.weighted_next_token_cross_entropy``.  This module is the
 stack, the two mixers, the chunked delta rule and the dense and shared
 SwiGLU parts.
 
@@ -102,10 +102,10 @@ import jax.numpy as jnp
 from jax import lax
 
 from ..obs import metrics
-from ..ops import delta_rule, pallas_ops
+from ..ops import backend, delta_rule
 from ..parallel.moe import dropless_topk_moe
-from . import hybrid_ssm
-from .block_diffusion import rms_norm
+from .attention import causal_document_attention
+from .layers import causal_conv, rms_norm, weighted_next_token_cross_entropy
 
 Params = Dict[str, Any]
 
@@ -403,8 +403,8 @@ def chunked_delta_rule(q, k, v, g, beta, segment, chunk: int):
     module's docstring has the form and what is kept in f32.
 
     Which implementation runs is observed, not set, as in
-    ``hybrid_ssm.causal_document_attention``: the Pallas kernels of
-    ``ops/delta_rule.py`` where ``ops.pallas_ops`` compiles kernels and
+    ``attention.masked_attention``: the Pallas kernels of
+    ``ops/delta_rule.py`` where ``ops.backend`` compiles kernels and
     ``delta_rule.supports`` the widths, the chunk and the type; the XLA
     form below everywhere else."""
     b, t, h, dk = q.shape
@@ -419,7 +419,7 @@ def chunked_delta_rule(q, k, v, g, beta, segment, chunk: int):
         segment = jnp.pad(segment, ((0, 0), (0, pad)), constant_values=-1)
     n = (t + pad) // chunk
     metrics.note_kda_chunks(b * n, chunk)
-    use, interpret = pallas_ops._pallas_mode()
+    use, interpret = backend.pallas_mode()
     if use and q.dtype == k.dtype == v.dtype and delta_rule.supports(
             dk, v.shape[-1], chunk, q.dtype):
         metrics.note_kda_path("pallas")
@@ -497,7 +497,7 @@ def kda_operands(cfg: KimiLinearConfig, p: Params, u, segment):
         # rounded once, as the Mamba mixer's convolution is: the f32 sum
         # over the taps is 0.8 GB that nothing has to keep
         q, k, v = jnp.split(
-            jax.nn.silu(hybrid_ssm.causal_conv(
+            jax.nn.silu(causal_conv(
                 qkv, p["conv_w"], 0.0, segment)).astype(dtype).reshape(
                     b, t, 3 * heads, hd), 3, axis=2)
         q = (_unit_length(q) * hd ** -0.5).astype(dtype)
@@ -549,9 +549,8 @@ def mla_operands(cfg: KimiLinearConfig, p: Params, u):
 
 
 def mla_attention(cfg: KimiLinearConfig, q, k, v, segment):
-    return hybrid_ssm.causal_document_attention(
-        q, k, v, segment, scale=cfg.key_width ** -0.5,
-        tile=hybrid_ssm._ATTENTION_TILE)
+    return causal_document_attention(q, k, v, segment,
+                                     scale=cfg.key_width ** -0.5)
 
 
 def mla_mixer(cfg: KimiLinearConfig, p: Params, u, segment):
@@ -670,6 +669,6 @@ def next_token_loss(params: Params, batch, cfg: KimiLinearConfig):
     ``segment``, ``w``).  Returns the loss and the routing's counts
     ``{"moe_rows_per_expert": int32 [expert layers, experts_held]}``."""
     hidden, rows = hidden_states(params, batch["x"], cfg, batch["segment"])
-    return (hybrid_ssm.weighted_next_token_cross_entropy(
+    return (weighted_next_token_cross_entropy(
         logits_of(params, hidden, cfg), batch),
         {"moe_rows_per_expert": rows})

@@ -4,9 +4,9 @@ expected loss over the exits on packed documents: the ``ouro`` family
 (Ouro-2.6B, "Scaling Latent Reasoning via Looped Language Models",
 arXiv:2510.25741).
 
-Pure functions over a parameter tree, like ``block_diffusion`` and
-``hybrid_ssm``, whose ``rms_norm``, ``rope`` and
-``causal_document_attention`` it calls where they lie.
+Pure functions over a parameter tree, built from the shared parts of
+``models.layers`` (``rms_norm``, ``rope``) and
+``attention.causal_document_attention``.
 
 *The model* (``config.json`` of ``ByteDance/Ouro-2.6B`` and the
 modelling code it names).  With ``N*`` an RMSNorm with its own scale::
@@ -76,15 +76,10 @@ import jax.numpy as jnp
 from jax import lax
 
 from ..obs import metrics
-from .block_diffusion import rms_norm, rope
-from .hybrid_ssm import causal_document_attention
+from .attention import causal_document_attention
+from .layers import rms_norm, rope
 
 Params = Dict[str, Any]
-
-# queries of a tile of the attention where it runs in XLA: the f32
-# scores of a tile against every earlier key, 16 heads x 256 x 8,192,
-# are 134 MB a row
-_ATTENTION_TILE = 256
 
 
 @dataclasses.dataclass(frozen=True)
@@ -145,8 +140,8 @@ def layer(cfg: LoopedConfig, p: Params, h, segment, positions):
         q = rope(heads(p["wq"], cfg.num_heads), positions, cfg.rope_theta)
         k = rope(heads(p["wk"], cfg.num_kv_heads), positions, cfg.rope_theta)
         v = heads(p["wv"], cfg.num_kv_heads)
-    o = causal_document_attention(
-        q, k, v, segment, scale=cfg.head_dim ** -0.5, tile=_ATTENTION_TILE)
+    o = causal_document_attention(q, k, v, segment,
+                                  scale=cfg.head_dim ** -0.5)
     with jax.named_scope("hvtpu:loop.proj"):
         a = h + rms_norm(o.reshape(b, t, -1) @ p["wo"].astype(dtype),
                          p["norm2"], eps)

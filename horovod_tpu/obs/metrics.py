@@ -679,13 +679,12 @@ def aggregate(process_set=None, timeout_s: float = 60.0,
 # ---------------------------------------------------------------------------
 
 def note_attention_path(path: str) -> None:
-    """Count one call of ``models.block_diffusion.tiled_attention`` or
-    ``models.hybrid_ssm.causal_document_attention`` by the
-    implementation it took: ``"pallas"`` (the kernels of
-    ``ops/flash_attention.py``) or ``"xla"``.  Called while a program is
-    traced, once a call site and a trace, never from inside the step: a
-    step that scans its layers counts one call however many layers run
-    it."""
+    """Count one call of ``models.attention.masked_attention``, under
+    either mask, by the implementation it took: ``"pallas"`` (the
+    kernels of ``ops/flash_attention.py``) or ``"xla"``.  Called while a
+    program is traced, once a call site and a trace, never from inside
+    the step: a step that scans its layers counts one call however many
+    layers run it."""
     REGISTRY.counter(
         "hvtpu_attention_calls_total",
         "Calls of the models' attention in tiles, counted when a program "
@@ -726,7 +725,7 @@ def note_attention_pairs(run: int, skipped: int) -> None:
     """Count the block pairs the attention kernels ran and skipped on
     the batches noted: a pair whose blocks share no document is decided
     by the batch's data when the step runs, so the count comes from the
-    host's loop (``models.hybrid_ssm.note_attention_pairs(segment)``
+    host's loop (``models.attention.note_attention_pairs(segment)``
     makes it from the flags the step itself computes), never from inside
     the step."""
     pairs = REGISTRY.counter(

@@ -12,13 +12,14 @@ lists the (query tile, key tile) pairs that hold work and says of each
 whether every query sees every key, and ``seen(q_pos, k_pos)``, the
 mask itself, which a kernel evaluates on iotas in the pairs that are
 not full.  Nothing here knows which mask it is
-(``models/block_diffusion.py`` brings ``allowed``).  A mask may also
+(``models/attention.py`` brings the masks and the one ``custom_vjp``
+around these kernels).  A mask may also
 be *data*: with ``ids`` ``[B, P]`` (a document's index at every
 position, say) ``seen(q_pos, k_pos, q_id, k_id)`` gets the ids of the
 tile's queries and keys too, cut into blocks by the same index maps as
 ``q`` and ``k``, and ``live`` ``[B, query tiles, key tiles]``, computed
 in the step from the same data, says which pairs of the schedule a row
-need not run at all (``models/hybrid_ssm.py`` brings both).  Without
+need not run at all (the document mask brings both).  Without
 them the kernels are built as if neither existed.
 ``jax.experimental.pallas.ops.tpu.splash_attention`` is the model for
 the schedule and the oracle in the tests, and does not ship: it hands
@@ -61,9 +62,9 @@ tile's blocks and the results read the table itself.  What a skipped
 step still costs is its bookkeeping, about a microsecond for the eight
 blocks a step names.
 
-*The arithmetic* is ``models.block_diffusion._tiled``'s: f32 scores
-scaled after the product, f32 softmax, the products in the operands'
-type with f32 accumulation, a masked score ``mask_value``.
+*The arithmetic* is that of ``models/attention.py``'s XLA path: f32
+scores scaled after the product, f32 softmax, the products in the
+operands' type with f32 accumulation, a masked score ``mask_value``.
 """
 
 from __future__ import annotations

@@ -19,24 +19,26 @@ places where an explicit kernel still wins:
   the on-core PRNG (cuda_kernels.cu's scale kernels have no TPU analog
   in XLA's standard fusion set for the rounding path).
 
-Which implementation runs is decided by what the process can observe:
-on a TPU backend the Pallas kernel (compiled by Mosaic), on any other
-backend the numerically-identical XLA twin, so callers never need to
-branch.  ``HVTPU_PALLAS=0`` selects the twin on a TPU as well; float16
-buffers always take it (Mosaic has no f16 vector type on v5e).
+Which implementation runs is decided by what the process can observe
+(``ops.backend.pallas_mode``): on a TPU backend the Pallas kernel
+(compiled by Mosaic), on any other backend the numerically-identical XLA
+twin, so callers never need to branch.  ``HVTPU_PALLAS=0`` selects the
+twin on a TPU as well; float16 buffers always take it (Mosaic has no f16
+vector type on v5e).
 """
 
 from __future__ import annotations
 
 import functools
 import logging
-import os
 from typing import Tuple
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+
+from . import backend
 
 logger = logging.getLogger("horovod_tpu")
 
@@ -46,21 +48,6 @@ _LANES = 128
 # x 4 B = 128 KiB per operand block in VMEM — small enough to double
 # buffer, large enough to saturate HBM bandwidth.
 _TILE_ROWS = 256
-
-
-def _on_tpu() -> bool:
-    return jax.default_backend() == "tpu"
-
-
-def _pallas_mode() -> Tuple[bool, bool]:
-    """(use_pallas, interpret).  HVTPU_PALLAS=0 disables the kernels
-    entirely; HVTPU_PALLAS_INTERPRET=1 forces the Pallas path in
-    interpreter mode so CPU tests execute the real kernel bodies."""
-    if os.environ.get("HVTPU_PALLAS", "1") == "0":
-        return False, False
-    if os.environ.get("HVTPU_PALLAS_INTERPRET", "0") == "1":
-        return True, True
-    return _on_tpu(), False
 
 
 def _mosaic_dtype(dtype) -> bool:
@@ -126,7 +113,7 @@ def fused_scale_cast(flat, scale, out_dtype=None):
       out_dtype: output dtype (defaults to ``flat.dtype``).
     """
     out_dtype = jnp.dtype(out_dtype or flat.dtype)
-    use, interp = _pallas_mode()
+    use, interp = backend.pallas_mode()
     if (not use or flat.ndim != 1
             or not (_mosaic_dtype(flat.dtype) and _mosaic_dtype(out_dtype))):
         return _scale_cast_xla(jnp.asarray(flat), float(scale), out_dtype)
@@ -243,7 +230,7 @@ def quantize_int8_blocks(flat, *, stochastic: bool = False,
     EQuARX reduce-scatter phase — rounding bias accumulates over ranks).
     """
     flat = jnp.asarray(flat)
-    use, interp = _pallas_mode()
+    use, interp = backend.pallas_mode()
     if stochastic and (interp or not use):
         # the on-core PRNG exists only in the Mosaic-compiled kernel:
         # neither the test interpreter nor the XLA twin has one
@@ -311,7 +298,7 @@ def dequantize_int8_blocks(q, scale, n: int, dtype=jnp.float32):
     q = jnp.asarray(q)
     scale = jnp.asarray(scale)
     rows = q.shape[0]
-    use, interp = _pallas_mode()
+    use, interp = backend.pallas_mode()
     if not use or rows % _QROWS != 0:
         g = rows // _QROWS
         out = (q.astype(jnp.float32).reshape(g, QBLOCK) * scale)

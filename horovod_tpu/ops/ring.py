@@ -60,7 +60,8 @@ from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from .pallas_ops import _LANES, _pallas_mode, block_scale_inv
+from . import backend
+from .pallas_ops import _LANES, block_scale_inv
 
 # The quantized ring carries one (8, 128) f32 tile of scales per chunk:
 # a 1.6% sidecar on the int8 codes of a full 2048-row chunk.
@@ -80,7 +81,7 @@ def _interpret_arg(what: str):
     """``interpret=`` for the ring ``pallas_call``s: False (compiled) on
     a TPU, the TPU interpreter under ``HVTPU_PALLAS_INTERPRET=1``.
     Raises where the kernels cannot run."""
-    use, interp = _pallas_mode()
+    use, interp = backend.pallas_mode()
     if not use:
         raise RuntimeError(
             f"{what}: the Pallas ring kernels run on a TPU (or under "

@@ -26,7 +26,7 @@ from jax import lax
 from jax.experimental import pallas as pl
 
 from horovod_tpu.obs import metrics
-from horovod_tpu.ops import grouped_ffn, pallas_ops
+from horovod_tpu.ops import backend, grouped_ffn, pallas_ops
 
 
 def switch_route(
@@ -152,7 +152,7 @@ def _row_buffer(rows, width, dtype, near):
     does: the way back hands in the rows it will read, so that its
     output is not held while the experts' products run (135 MB of the
     step's peak in the benchmark's cell)."""
-    use, interpret = pallas_ops._pallas_mode()
+    use, interpret = backend.pallas_mode()
     if not use or interpret or not pallas_ops._mosaic_dtype(dtype):
         return lax.empty((rows, width), dtype)
     # no ``metadata=``: XLA prints it over three lines of the compiled
@@ -369,7 +369,7 @@ def products_path(dtype, d: int, f: int, tokens: int, top_k: int,
                   experts_held: int, form: str = "gated") -> str:
     """How the experts' products of a layer of these shapes run, from
     what the code can see: ``"grouped"``, in the Pallas kernels of
-    ``ops/grouped_ffn.py``, where ``ops.pallas_ops`` compiles kernels (a
+    ``ops/grouped_ffn.py``, where ``ops.backend`` compiles kernels (a
     TPU backend, or the interpreter in tests) and the shapes are ones
     the kernels of the experts' ``form`` take (``"gated"``, three
     weights: ``d`` and ``f`` whole 128-lane vectors; ``"relu2"``, two:
@@ -377,7 +377,7 @@ def products_path(dtype, d: int, f: int, tokens: int, top_k: int,
     whole tiles, and a group's weights and sums in VMEM); elsewhere
     ``"ragged_dot"``, the same products over the same buffers as
     ``lax.ragged_dot``."""
-    use, _ = pallas_ops._pallas_mode()
+    use, _ = backend.pallas_mode()
     return ("grouped" if use and grouped_ffn.supports(
         dtype, d, f, buffer_rows(tokens, top_k, experts_held),
         _product_rows(min(_TILE_ROWS, tokens)),
@@ -475,7 +475,7 @@ def _kernels(path, tile_rows):
     if path != "grouped":
         return _ragged_forward, _ragged_backward
     how = dict(tile_rows=_product_rows(tile_rows),
-               interpret=pallas_ops._pallas_mode()[1])
+               interpret=backend.pallas_mode()[1])
     return (functools.partial(grouped_ffn.forward, **how),
             functools.partial(grouped_ffn.backward, **how))
 

@@ -1,5 +1,5 @@
-"""The Pallas kernels behind ``models.block_diffusion.tiled_attention``
-and ``models.hybrid_ssm.causal_document_attention``
+"""The Pallas kernels behind ``models.attention.tiled_attention`` and
+``models.attention.causal_document_attention``
 (``ops/flash_attention.py``) on the CPU, under the Pallas interpreter:
 against the XLA tiles they replace, against a dense masked softmax and
 against the library's splash-attention kernel with the same computed
@@ -14,10 +14,9 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from horovod_tpu.models import block_diffusion as bd
-from horovod_tpu.models import hybrid_ssm as hs
+from horovod_tpu.models import attention as attn
 from horovod_tpu.obs import metrics
-from horovod_tpu.ops import flash_attention, pallas_ops
+from horovod_tpu.ops import backend, flash_attention
 
 HD = 128
 KERNELS = ("hvtpu_flash_attention_fwd", "hvtpu_flash_attention_dq",
@@ -41,8 +40,8 @@ def interpreted(monkeypatch):
 
 
 def blocks(monkeypatch, q, kv):
-    monkeypatch.setattr(bd, "_FLASH_BLOCK_Q", q)
-    monkeypatch.setattr(bd, "_FLASH_BLOCK_KV", kv)
+    monkeypatch.setattr(attn, "_FLASH_BLOCK_Q", q)
+    monkeypatch.setattr(attn, "_FLASH_BLOCK_KV", kv)
 
 
 def operands(seq_len, heads, kv_heads, dtype=jnp.float32, seed=0):
@@ -68,7 +67,7 @@ def with_gradients(attention, q, k, v, target):
 def dense_attention(q, k, v, seq_len, block_length):
     pos = np.arange(2 * seq_len)
     seen = jnp.asarray(
-        bd.allowed(pos[:, None], pos[None, :], seq_len, block_length))
+        attn.allowed(pos[:, None], pos[None, :], seq_len, block_length))
     k, v = (jnp.repeat(a, q.shape[2] // a.shape[2], axis=2) for a in (k, v))
     s = jnp.einsum("bqhd,bkhd->bhqk", q, k) / np.sqrt(q.shape[-1])
     p = jax.nn.softmax(jnp.where(seen, s, -jnp.inf), axis=-1)
@@ -85,7 +84,7 @@ def splash_attention(q, k, v, seq_len, block_length, block):
         def __init__(self):
             super().__init__(
                 (2 * seq_len, 2 * seq_len),
-                lambda q_ids, kv_ids: bd.allowed(
+                lambda q_ids, kv_ids: attn.allowed(
                     q_ids, kv_ids, seq_len, block_length))
 
         def __eq__(self, other):
@@ -126,8 +125,8 @@ def test_kernels_equal_the_xla_tiles_and_the_dense_mask(
     q, k, v, target = operands(seq_len, 2 * kv_heads, kv_heads)
 
     def tiled(q, k, v):
-        return bd.tiled_attention(q, k, v, block_length=block_length,
-                                  tile=128)
+        return attn.tiled_attention(q, k, v, block_length=block_length,
+                                    tile=128)
 
     before = calls("pallas"), calls("xla")
     got = with_gradients(tiled, q, k, v, target)
@@ -150,8 +149,8 @@ def test_kernels_equal_the_librarys_splash_attention(
     blocks(monkeypatch, 128, 128)
     q, k, v, target = operands(seq_len, 2 * kv_heads, kv_heads, seed=3)
     got = with_gradients(
-        lambda *a: bd.tiled_attention(*a, block_length=block_length,
-                                      tile=128), q, k, v, target)
+        lambda *a: attn.tiled_attention(*a, block_length=block_length,
+                                        tile=128), q, k, v, target)
     want = with_gradients(
         lambda *a: splash_attention(*a, seq_len, block_length, 128),
         q, k, v, target)
@@ -172,8 +171,8 @@ def test_bf16_kernels_differ_from_f32_by_the_rounding_of_their_products(
                                jnp.bfloat16, seed=5)
 
     def tiled(q, k, v):
-        return bd.tiled_attention(q, k, v, block_length=block_length,
-                                  tile=128)
+        return attn.tiled_attention(q, k, v, block_length=block_length,
+                                    tile=128)
 
     got = with_gradients(tiled, q, k, v, target)
     assert all(g.dtype == jnp.bfloat16 for g in got)
@@ -190,13 +189,13 @@ def test_the_forward_kernel_hands_the_backward_out_before_its_rounding(
     from the f32 ``out``, as XLA makes it for its own tiles."""
     seq_len = 128
     schedule = flash_attention.pair_schedule(
-        bd.tile_work(seq_len, 4, 128, 128), 128, 128)
+        attn.tile_work(seq_len, 4, 128, 128), 128, 128)
 
     def forward(dtype):
         q, k, v, _ = operands(seq_len, 2, 1, dtype, seed=7)
         return flash_attention.forward(
-            q, k, v, schedule, lambda a, b: bd.allowed(a, b, seq_len, 4),
-            scale=HD ** -0.5, mask_value=bd._MASKED, interpret=True)
+            q, k, v, schedule, lambda a, b: attn.allowed(a, b, seq_len, 4),
+            scale=HD ** -0.5, mask_value=attn._MASKED, interpret=True)
 
     out, _, exact = forward(jnp.bfloat16)
     assert (out.dtype, exact.dtype) == (jnp.bfloat16, jnp.float32)
@@ -222,11 +221,11 @@ def test_the_tables_are_allowed_block_by_block(seq_len, block_length,
     want = np.zeros((len(q_pos), len(k_pos)), np.int8)
     for i, qp in enumerate(q_pos):
         for j, kp in enumerate(k_pos):
-            seen = (bd.allowed(qp[:, None], kp[None, :], seq_len,
-                               block_length)
+            seen = (attn.allowed(qp[:, None], kp[None, :], seq_len,
+                                 block_length)
                     & (qp[:, None] >= 0) & (kp[None, :] >= 0))
             want[i, j] = 2 if seen.all() else 1 if seen.any() else 0
-    got = bd.tile_work(seq_len, block_length, tile_q, tile_k)
+    got = attn.tile_work(seq_len, block_length, tile_q, tile_k)
     assert np.array_equal(got, want)
     if seq_len % tile_q == 0 and seq_len % tile_k == 0:
         schedule = flash_attention.pair_schedule(got, tile_q, tile_k)
@@ -240,7 +239,7 @@ def test_the_cells_schedule():
     """8,192 tokens, block length 4, blocks of 512: 288 of 1,024 pairs,
     the 16 diagonal ones of each of three quadrants partial, at most 17
     key blocks a query block and 32 query blocks a key block."""
-    work = bd.tile_work(8192, 4, 512, 512)
+    work = attn.tile_work(8192, 4, 512, 512)
     assert work.shape == (32, 32)
     assert np.count_nonzero(work) == 288
     assert np.count_nonzero(work == flash_attention.PARTIAL) == 48
@@ -250,11 +249,40 @@ def test_the_cells_schedule():
     assert not work[16:, :16].any()
     partial = np.argwhere(work == flash_attention.PARTIAL)
     assert np.array_equal(partial[:, 0] % 16, partial[:, 1] % 16)
-    schedule = bd._flash_schedule(8192, 4, 512, 512)
+    cell = attn.BlockDiffusionMask(8192, 4)
+    schedule = attn._flash_schedule(cell, 512, 512)
     assert schedule.pairs == 288
-    assert bd._flash_schedule(8192, 4, 512, 512) is schedule   # memoised
+    assert attn._flash_schedule(cell, 512, 512) is schedule   # memoised
     # keys fetched 1,024 at a time: every pair on the diagonal partial
-    assert np.count_nonzero(bd.tile_work(8192, 4, 512, 1024)) == 160
+    assert np.count_nonzero(attn.tile_work(8192, 4, 512, 1024)) == 160
+
+
+@pytest.mark.parametrize("seq_len, block_length, tile", [
+    (24, 1, 16), (24, 4, 16), (24, 24, 16), (30, 5, 8), (64, 4, 16),
+    (8192, 4, 512)])
+def test_the_xla_rows_read_the_key_tiles_that_hold_work(
+        seq_len, block_length, tile):
+    """``key_ranges``, the XLA path's walk: under the block-diffusion
+    mask a tile of queries reads the keys of exactly the key tiles where
+    ``tile_work`` is not 0, in order and none twice (a half's last tile
+    is shorter, nothing is padded); under the document mask of as many
+    positions, the tile at ``a`` reads the keys ``[0, a + tile)``."""
+    n = -(-seq_len // tile)
+    first = np.concatenate([np.arange(n) * tile,
+                            seq_len + np.arange(n) * tile])
+    end = np.minimum(first + tile, np.repeat([seq_len, 2 * seq_len], n))
+    work = attn.tile_work(seq_len, block_length, tile, tile) > 0
+    walk = attn.key_ranges(attn.BlockDiffusionMask(seq_len, block_length),
+                           tile)
+    assert [queries for queries, _ in walk] == list(zip(first, end))
+    for row, (_, ranges) in zip(work, walk):
+        read = np.concatenate([np.arange(lo, hi) for lo, hi in ranges])
+        assert np.array_equal(read, np.concatenate([
+            np.arange(lo, hi) for lo, hi in zip(first[row], end[row])]))
+    positions = 2 * seq_len
+    assert attn.key_ranges(attn.DocumentMask(positions), tile) == tuple(
+        ((a, min(a + tile, positions)), ((0, min(a + tile, positions)),))
+        for a in range(0, positions, tile))
 
 
 @pytest.mark.parametrize("seq_len, head_dim, why", [
@@ -269,7 +297,7 @@ def test_other_shapes_take_the_xla_path(interpreted, monkeypatch, seq_len,
     k = jax.random.normal(ks[1], (1, 2 * seq_len, 1, head_dim))
     v = jax.random.normal(ks[2], (1, 2 * seq_len, 1, head_dim))
     before = calls("pallas"), calls("xla")
-    out = jax.jit(lambda *a: bd.tiled_attention(
+    out = jax.jit(lambda *a: attn.tiled_attention(
         *a, block_length=4, tile=128))(q, k, v)
     assert (calls("pallas"), calls("xla")) == (before[0], before[1] + 1), why
     assert distance(out, dense_attention(q, k, v, seq_len, 4)) < 1e-5
@@ -286,7 +314,7 @@ def test_heads_of_64_take_the_kernels_two_to_a_block(interpreted,
                  for k in ks[:2])
     k, v = (jax.random.normal(k_, (2, 2 * seq_len, 2, 64)) for k_ in ks[2:])
     before = calls("pallas"), calls("xla")
-    got = with_gradients(lambda *a: bd.tiled_attention(
+    got = with_gradients(lambda *a: attn.tiled_attention(
         *a, block_length=4, tile=128), q, k, v, target)
     assert (calls("pallas"), calls("xla")) == (before[0] + 1, before[1])
     dense = with_gradients(
@@ -299,7 +327,7 @@ def test_heads_of_64_take_the_kernels_two_to_a_block(interpreted,
     assert flash_attention.supports(128, jnp.float32, 256, 128, 128, 3)
 
 
-# -- a mask that is data: models.hybrid_ssm.causal_document_attention -------
+# -- a mask that is data: models.attention.causal_document_attention -------
 
 # where the documents after the first start, a row each; blocks of 128
 PACKINGS = {
@@ -333,18 +361,18 @@ def document_operands(head_dim, group, positions=512, kv_heads=2, seed=11,
 
 def kernels_against_xla_tiles(monkeypatch, head_dim, group, packing,
                               skipping, kv_heads=2, value_dim=None):
-    monkeypatch.setattr(hs, "_FLASH_BLOCK_Q", 128)
-    monkeypatch.setattr(hs, "_FLASH_BLOCK_KV", 128)
+    monkeypatch.setattr(attn, "_FLASH_BLOCK_Q", 128)
+    monkeypatch.setattr(attn, "_FLASH_BLOCK_KV", 128)
     if not skipping:
-        live = hs.live_pairs
+        live = attn.live_pairs
         monkeypatch.setattr(
-            hs, "live_pairs", lambda *a: jnp.ones_like(live(*a)))
+            attn, "live_pairs", lambda *a: jnp.ones_like(live(*a)))
     segment = jnp.asarray(segments(PACKINGS[packing], 512))
     q, k, v, target = document_operands(head_dim, group, kv_heads=kv_heads,
                                         value_dim=value_dim)
 
     def layer(q, k, v):
-        return hs.causal_document_attention(
+        return attn.causal_document_attention(
             q, k, v, segment, scale=0.125, tile=128)
 
     before = calls("pallas"), calls("xla")
@@ -396,7 +424,7 @@ def test_sixteen_key_value_heads_of_one_query_head_each(
     @jax.jit
     def layer(q, k, v, target):
         def loss(q, k, v):
-            out = hs.causal_document_attention(
+            out = attn.causal_document_attention(
                 q, k, v, segment, scale=0.125, tile=128)
             return jnp.sum(out * target), out
 
@@ -494,12 +522,12 @@ def test_a_blocks_heads_are_chosen_from_the_shapes(heads, kv_heads, hd,
     query, key_value = block
     assert (key_value * hd) % 128 == 0 and kv_heads % key_value == 0
     assert query == key_value * (heads // kv_heads)
-    schedule = hs._flash_schedule(256, 128, 128)
+    schedule = attn._flash_schedule(attn.DocumentMask(256), 128, 128)
     q = jax.ShapeDtypeStruct((2, 256, heads, hd), jnp.float32)
     kv = jax.ShapeDtypeStruct((2, 256, kv_heads, hd), jnp.float32)
     out, lse, exact = jax.eval_shape(
         lambda q, k, v: flash_attention.forward(
-            q, k, v, schedule, hs._seen, scale=1.0, mask_value=-1e30,
+            q, k, v, schedule, attn._seen, scale=1.0, mask_value=-1e30,
             interpret=True, ids=jnp.zeros((2, 256), jnp.int32)), q, kv, kv)
     assert out.shape == q.shape
     assert lse.shape == (2, heads // query, 256, query)
@@ -508,11 +536,11 @@ def test_a_blocks_heads_are_chosen_from_the_shapes(heads, kv_heads, hd,
 def walks(packing, first_skipped=False):
     """For both walks over a packing's rows: the table, the rows' flags
     in its order, and the pairs held."""
-    live = np.array(hs.live_pairs(segments(PACKINGS[packing], 512), 128,
-                                  128))
+    live = np.array(attn.live_pairs(segments(PACKINGS[packing], 512),
+                                    128, 128))
     if first_skipped:          # no walk of a causal mask: a caller's own
         live[:, 0, 0] = 0
-    schedule = hs._flash_schedule(512, 128, 128)
+    schedule = attn._flash_schedule(attn.DocumentMask(512), 128, 128)
     for own, table in enumerate((schedule.by_query, schedule.by_key)):
         flags = live[:, table[0], table[1]]
         yield own, table, flags, np.asarray(
@@ -538,7 +566,8 @@ def test_a_skipped_pair_holds_the_last_live_pairs_blocks(packing):
             assert 1 + np.count_nonzero(np.diff(row_held)) == row_flags.sum()
         q = jnp.zeros((2, 512, 4, 128))
         ks = flash_attention._Kernels.of(
-            q, q, q, hs._flash_schedule(512, 128, 128), hs._seen, 1.0, -1e30,
+            q, q, q, attn._flash_schedule(attn.DocumentMask(512), 128, 128),
+            attn._seen, 1.0, -1e30,
             True, jnp.zeros((2, 512), jnp.int32), jnp.asarray(flags))
         through_held = {
             0: {"narrow", "key_ids", "key_ids_row"},
@@ -575,14 +604,14 @@ def test_in_bf16_the_document_kernels_differ_by_their_products_rounding(
         interpreted, monkeypatch):
     """As for the block-diffusion mask: heads of 64 in bf16 against the
     f32 XLA tiles on the same operands, widened."""
-    monkeypatch.setattr(hs, "_FLASH_BLOCK_Q", 128)
-    monkeypatch.setattr(hs, "_FLASH_BLOCK_KV", 128)
+    monkeypatch.setattr(attn, "_FLASH_BLOCK_Q", 128)
+    monkeypatch.setattr(attn, "_FLASH_BLOCK_KV", 128)
     segment = jnp.asarray(segments(PACKINGS["boundaries inside blocks"], 512))
     q, k, v, target = document_operands(64, 4, seed=13)
     q, k, v = (a.astype(jnp.bfloat16) for a in (q, k, v))
 
     def layer(q, k, v):
-        return hs.causal_document_attention(
+        return attn.causal_document_attention(
             q, k, v, segment, scale=0.125, tile=128)
 
     got = with_gradients(layer, q, k, v, target)
@@ -601,13 +630,13 @@ def test_a_pair_is_skipped_only_where_no_query_sees_a_key(packing):
     knows the ranges of the ids, not the ids), never drop one that
     holds something; with documents in order it is exact."""
     segment = segments(PACKINGS[packing], 512)
-    live = hs.live_pairs(segment, 128, 128)
+    live = attn.live_pairs(segment, 128, 128)
     assert live.shape == (2, 4, 4) and live.dtype == np.int32
     pos = np.arange(512)
-    seen = np.asarray(hs._seen(pos[None, :, None], pos[None, None, :],
+    seen = np.asarray(attn._seen(pos[None, :, None], pos[None, None, :],
                                segment[:, :, None], segment[:, None, :]))
     holds = seen.reshape(2, 4, 128, 4, 128).any(axis=(2, 4))
-    schedule = hs._flash_schedule(512, 128, 128)
+    schedule = attn._flash_schedule(attn.DocumentMask(512), 128, 128)
     assert schedule.pairs == 10 and np.all(
         schedule.by_query[2] == flash_attention.PARTIAL)
     qi, kj = schedule.by_query[:2]
@@ -616,18 +645,18 @@ def test_a_pair_is_skipped_only_where_no_query_sees_a_key(packing):
                      np.triu_indices(4, 1)[1]].any()
     # ids in no order: a range that straddles another keeps the pair
     shuffled = np.where(segment == 0, 5, segment)
-    kept = hs.live_pairs(shuffled, 128, 128)
+    kept = attn.live_pairs(shuffled, 128, 128)
     same = (shuffled[:, :, None] == shuffled[:, None, :]).reshape(
         2, 4, 128, 4, 128).any(axis=(2, 4))
     assert np.all(kept[same] == 1)
 
 
 def test_the_hosts_loop_counts_the_pairs_run_and_skipped(monkeypatch):
-    monkeypatch.setattr(hs, "_FLASH_BLOCK_Q", 128)
-    monkeypatch.setattr(hs, "_FLASH_BLOCK_KV", 128)
+    monkeypatch.setattr(attn, "_FLASH_BLOCK_Q", 128)
+    monkeypatch.setattr(attn, "_FLASH_BLOCK_KV", 128)
     pairs = metrics.REGISTRY.counter("hvtpu_attention_pairs_total")
     before = pairs.value(kind="run"), pairs.value(kind="skipped")
-    hs.note_attention_pairs(segments([[128, 384], []], 512))
+    attn.note_attention_pairs(segments([[128, 384], []], 512))
     # row 0: documents of blocks {0}, {1, 2}, {3}: 1 + 3 + 1 pairs;
     # row 1: one document, the whole triangle of 10
     assert pairs.value(kind="run") - before[0] == 5 + 10
@@ -642,14 +671,14 @@ def test_without_ids_the_kernels_are_built_as_if_there_were_none(
     prefetched, and nothing else."""
     seq_len = 128
     schedule = flash_attention.pair_schedule(
-        bd.tile_work(seq_len, 4, 128, 128), 128, 128)
+        attn.tile_work(seq_len, 4, 128, 128), 128, 128)
     q, k, v, _ = operands(seq_len, 2, 1)
 
     def call_of(**data):
         jaxpr = jax.make_jaxpr(lambda q, k, v: flash_attention.forward(
             q, k, v, schedule,
-            (lambda a, b, *ids: bd.allowed(a, b, seq_len, 4)),
-            scale=HD ** -0.5, mask_value=bd._MASKED, interpret=True,
+            (lambda a, b, *ids: attn.allowed(a, b, seq_len, 4)),
+            scale=HD ** -0.5, mask_value=attn._MASKED, interpret=True,
             **data))(q, k, v)
         call, = (e for e in jaxpr.eqns if e.primitive.name == "pallas_call")
         return (call.params["grid_mapping"].num_index_operands,
@@ -666,29 +695,21 @@ def test_without_pallas_the_xla_path_runs(monkeypatch):
     monkeypatch.delenv("HVTPU_PALLAS_INTERPRET", raising=False)
     q, k, v, _ = operands(128, 2, 1)
     before = calls("pallas"), calls("xla")
-    jax.jit(lambda *a: bd.tiled_attention(
+    jax.jit(lambda *a: attn.tiled_attention(
         *a, block_length=4, tile=128)).lower(q, k, v)
     assert (calls("pallas"), calls("xla")) == (before[0], before[1] + 1)
 
 
-def test_lowered_for_a_tpu_the_kernels_are_three_plain_custom_calls(
-        monkeypatch):
-    """The join the benchmark's roofline depends on (``benchmark/scopes
-    .py`` reads an instruction as one line): each kernel a
-    ``tpu_custom_call`` under the scope, named for what it is, and none
-    with kernel metadata, which XLA would print over several lines."""
+def lowered_for_a_tpu(monkeypatch, loss, *shapes):
+    """``jax.grad(loss)`` lowered for a TPU, the kernels compiled."""
     monkeypatch.delenv("HVTPU_PALLAS_INTERPRET", raising=False)
     monkeypatch.delenv("HVTPU_PALLAS", raising=False)
-    monkeypatch.setattr(pallas_ops, "_on_tpu", lambda: True)
-    q, k, v, target = (jax.ShapeDtypeStruct(a.shape, jnp.bfloat16)
-                       for a in operands(1024, 4, 1))
-
-    def loss(q, k, v, target):
-        out = bd.tiled_attention(q, k, v, block_length=4, tile=512)
-        return jnp.sum(out.astype(jnp.float32) * target)
-
-    text = jax.jit(jax.grad(loss, (0, 1, 2))).trace(q, k, v, target).lower(
+    monkeypatch.setattr(backend, "on_tpu", lambda: True)
+    return jax.jit(jax.grad(loss, (0, 1, 2))).trace(*shapes).lower(
         lowering_platforms=("tpu",)).as_text(debug_info=True)
+
+
+def three_plain_custom_calls(text):
     found = re.findall(r"stablehlo\.custom_call @tpu_custom_call.*", text)
     assert len(found) == 3
     for name, line in zip(KERNELS, sorted(
@@ -698,3 +719,40 @@ def test_lowered_for_a_tpu_the_kernels_are_three_plain_custom_calls(
         assert "kernel_metadata" not in line.replace(
             'kernel_metadata = "{}"', "")
     assert text.count("hvtpu:attention") >= 3
+
+
+def test_lowered_for_a_tpu_the_kernels_are_three_plain_custom_calls(
+        monkeypatch):
+    """The join the benchmark's roofline depends on (``benchmark/scopes
+    .py`` reads an instruction as one line): each kernel a
+    ``tpu_custom_call`` under the scope, named for what it is, and none
+    with kernel metadata, which XLA would print over several lines."""
+    q, k, v, target = (jax.ShapeDtypeStruct(a.shape, jnp.bfloat16)
+                       for a in operands(1024, 4, 1))
+
+    def loss(q, k, v, target):
+        out = attn.tiled_attention(q, k, v, block_length=4, tile=512)
+        return jnp.sum(out.astype(jnp.float32) * target)
+
+    three_plain_custom_calls(lowered_for_a_tpu(monkeypatch, loss, q, k, v,
+                                               target))
+
+
+def test_under_the_document_mask_the_same_three_calls(monkeypatch):
+    """The document mask through the same wrapper, at latent attention's
+    heads (keys of 192 on values of 128, a key/value head a query head),
+    the ids its data: the same three kernels, each once."""
+    q, k = (jax.ShapeDtypeStruct((2, 1024, 8, 192), jnp.bfloat16)
+            for _ in range(2))
+    v, target = (jax.ShapeDtypeStruct((2, 1024, 8, 128), jnp.bfloat16)
+                 for _ in range(2))
+    segment = jnp.asarray(segments(PACKINGS["boundaries inside blocks"],
+                                   1024))
+
+    def loss(q, k, v, target):
+        out = attn.causal_document_attention(q, k, v, segment,
+                                             scale=192 ** -0.5)
+        return jnp.sum(out.astype(jnp.float32) * target)
+
+    three_plain_custom_calls(lowered_for_a_tpu(monkeypatch, loss, q, k, v,
+                                               target))

@@ -18,7 +18,9 @@ import jax.numpy as jnp
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
 from benchmark.reference import granite_hybrid as ref  # noqa: E402
+from horovod_tpu.models import attention as attn  # noqa: E402
 from horovod_tpu.models import hybrid_ssm as hs  # noqa: E402
+from horovod_tpu.models import layers  # noqa: E402
 from horovod_tpu.obs import metrics  # noqa: E402
 
 # relative L2 distance of a loss or a gradient leaf, f32 against f32
@@ -357,8 +359,8 @@ def test_the_convolution_reads_zeros_before_a_documents_start(starts):
         for back in range(4):
             if t - back >= 0 and segment[0, t - back] == segment[0, t]:
                 want[0, t] += w[3 - back] * x[0, t - back]
-    got = hs.causal_conv(jnp.asarray(x), jnp.asarray(w), jnp.asarray(b),
-                         jnp.asarray(segment))
+    got = layers.causal_conv(jnp.asarray(x), jnp.asarray(w), jnp.asarray(b),
+                             jnp.asarray(segment))
     np.testing.assert_allclose(np.asarray(got), want + b, atol=1e-6)
 
 
@@ -370,7 +372,7 @@ def test_the_attention_in_tiles_is_the_dense_masked_softmax(tile):
     v = jnp.asarray(rng.standard_normal((2, 64, 2, 8)), jnp.float32)
     segment = jnp.asarray(batch_of([[20, 50], [33]], 64)["segment"])
     with jax.default_matmul_precision("highest"):
-        got = hs.causal_document_attention(
+        got = attn.causal_document_attention(
             q, k, v, segment, scale=0.125, tile=tile)
         for row in range(2):
             mask = ref.dense_mask(segment[row])
@@ -437,7 +439,7 @@ def test_on_the_cpu_the_attention_is_pr_32s_function_bit_for_bit(
 
     counter = metrics.REGISTRY.counter("hvtpu_attention_calls_total")
     before = counter.value(path="xla"), counter.value(path="pallas")
-    got = results(hs.causal_document_attention)
+    got = results(attn.causal_document_attention)
     assert (counter.value(path="xla"), counter.value(path="pallas")) == (
         before[0] + 1, before[1])
     for g, w in zip(got, results(_tiles_of_pr_32)):

@@ -22,7 +22,7 @@ import jax.numpy as jnp
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
 from benchmark.reference import kimi_linear as ref  # noqa: E402
-from horovod_tpu.models import hybrid_ssm as hs  # noqa: E402
+from horovod_tpu.models import attention as attn  # noqa: E402
 from horovod_tpu.models import kimi_linear as kl  # noqa: E402
 from horovod_tpu.obs import metrics  # noqa: E402
 from horovod_tpu.parallel import moe  # noqa: E402
@@ -423,8 +423,8 @@ def test_keys_of_192_against_values_of_128_on_both_paths(
     the result and the three gradients."""
     if path == "xla":
         monkeypatch.setenv("HVTPU_PALLAS", "0")
-    monkeypatch.setattr(hs, "_FLASH_BLOCK_Q", 128)
-    monkeypatch.setattr(hs, "_FLASH_BLOCK_KV", 128)
+    monkeypatch.setattr(attn, "_FLASH_BLOCK_Q", 128)
+    monkeypatch.setattr(attn, "_FLASH_BLOCK_KV", 128)
     ks = jax.random.split(jax.random.PRNGKey(5), 4)
     q, k = (jax.random.normal(key, (2, 256, 4, 192)) for key in ks[:2])
     v, target = (jax.random.normal(key, (2, 256, 4, 128)) for key in ks[2:])
@@ -437,7 +437,7 @@ def test_keys_of_192_against_values_of_128_on_both_paths(
         return jnp.einsum("bhqk,bkhd->bqhd", jax.nn.softmax(s, axis=-1), v)
 
     def program(q, k, v):
-        return hs.causal_document_attention(
+        return attn.causal_document_attention(
             q, k, v, segment, scale=192 ** -0.5, tile=64)
 
     calls = counter("hvtpu_attention_calls_total", path=path)

@@ -26,7 +26,7 @@ sys.path.insert(0, ROOT)
 from benchmark import flops_looped_lm as flops  # noqa: E402
 from benchmark.reference import ouro_looped as ref  # noqa: E402
 from horovod_tpu.models import looped  # noqa: E402
-from horovod_tpu.models.block_diffusion import rms_norm  # noqa: E402
+from horovod_tpu.models.layers import rms_norm  # noqa: E402
 from horovod_tpu.obs import metrics  # noqa: E402
 
 # relative L2 distance of a loss or a gradient leaf, f32 against f32

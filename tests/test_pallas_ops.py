@@ -21,7 +21,7 @@ from horovod_tpu.ops import (
     fused_scale_cast,
     quantize_int8_blocks,
 )
-from horovod_tpu.ops import pallas_ops
+from horovod_tpu.ops import backend, pallas_ops
 
 
 @pytest.fixture
@@ -109,7 +109,7 @@ class TestLowersForTpu:
     @pytest.fixture(autouse=True)
     def compiled_mode(self, monkeypatch):
         monkeypatch.delenv("HVTPU_PALLAS_INTERPRET", raising=False)
-        monkeypatch.setattr(pallas_ops, "_on_tpu", lambda: True)
+        monkeypatch.setattr(backend, "on_tpu", lambda: True)
 
     def _lower(self, f, *shapes):
         return jax.jit(f).trace(*shapes).lower(

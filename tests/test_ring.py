@@ -215,10 +215,10 @@ class TestLowersForTpu:
 
     @pytest.fixture(autouse=True)
     def compiled_mode(self, monkeypatch):
-        from horovod_tpu.ops import pallas_ops
+        from horovod_tpu.ops import backend
 
         monkeypatch.delenv("HVTPU_PALLAS_INTERPRET")
-        monkeypatch.setattr(pallas_ops, "_on_tpu", lambda: True)
+        monkeypatch.setattr(backend, "on_tpu", lambda: True)
 
     def _lower(self, body, x, out_specs):
         text = jax.jit(

@@ -16,6 +16,7 @@ sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
 from benchmark.reference import data_parallel_sgd  # noqa: E402
 from benchmark.reference import sdar_block_diffusion as ref  # noqa: E402
+from horovod_tpu.models import attention as attn  # noqa: E402
 from horovod_tpu.models import block_diffusion as bd  # noqa: E402
 from horovod_tpu.parallel import moe  # noqa: E402
 
@@ -99,7 +100,7 @@ def test_the_reference_in_query_blocks_is_the_reference():
 
 def dense_attention(q, k, v, seq_len, block_length):
     pos = np.arange(2 * seq_len)
-    seen = bd.allowed(pos[:, None], pos[None, :], seq_len, block_length)
+    seen = attn.allowed(pos[:, None], pos[None, :], seq_len, block_length)
     rep = q.shape[2] // k.shape[2]
     k, v = jnp.repeat(k, rep, axis=2), jnp.repeat(v, rep, axis=2)
     s = jnp.einsum("bqhd,bkhd->bhqk", q, k) / np.sqrt(q.shape[-1])
@@ -117,8 +118,8 @@ def test_tiled_attention_equals_the_dense_mask(seq_len, block_length, tile):
     target = jax.random.normal(ks[3], q.shape)
 
     def tiled(q, k, v):
-        return bd.tiled_attention(q, k, v, block_length=block_length,
-                                  tile=tile)
+        return attn.tiled_attention(q, k, v, block_length=block_length,
+                                    tile=tile)
 
     def dense(q, k, v):
         return dense_attention(q, k, v, seq_len, block_length)
@@ -136,45 +137,14 @@ def test_the_programs_mask_is_the_references():
     for seq_len, block_length in [(24, 1), (24, 4), (24, 24), (30, 5)]:
         pos = np.arange(2 * seq_len)
         assert np.array_equal(
-            bd.allowed(pos[:, None], pos[None, :], seq_len, block_length),
+            attn.allowed(pos[:, None], pos[None, :], seq_len, block_length),
             np.asarray(ref.dense_mask(seq_len, block_length)))
     # block 1: causal over the clean half, each noised token alone
-    seen = bd.allowed(np.arange(8)[:, None], np.arange(8)[None, :], 4, 1)
+    seen = attn.allowed(np.arange(8)[:, None], np.arange(8)[None, :], 4, 1)
     assert np.array_equal(seen[4:, 4:], np.tril(np.ones((4, 4), bool)))
     assert np.array_equal(seen[:4, :4], np.eye(4, dtype=bool))
     assert np.array_equal(seen[:4, 4:], np.tril(np.ones((4, 4), bool), -1))
     assert not seen[4:, :4].any()
-
-
-@pytest.mark.parametrize("seq_len, block_length, tile", [
-    (24, 1, 16), (24, 4, 16), (24, 24, 16), (30, 5, 8), (64, 4, 16)])
-def test_the_runs_are_the_tile_pairs_that_hold_work(seq_len, block_length,
-                                                    tile):
-    n = -(-seq_len // tile)
-    pos = np.full((2, n * tile), 10 ** 6)            # padding sees nothing
-    pos[0, :seq_len] = np.arange(seq_len)
-    pos[1, :seq_len] = seq_len + np.arange(seq_len)
-    pos = pos.reshape(2 * n, tile)
-    work = {
-        (qi, kj) for qi in range(2 * n) for kj in range(2 * n)
-        if (bd.allowed(pos[qi][:, None], pos[kj][None, :], seq_len,
-                       block_length)
-            & (pos[qi][:, None] < 10 ** 6) & (pos[kj][None, :] < 10 ** 6)
-            ).any()}
-    pairs = [(qi, k_lo + qi - q_lo)
-             for q_lo, q_hi, k_lo in bd.tile_runs(seq_len, block_length, tile)
-             for qi in range(q_lo, q_hi)]
-    assert len(pairs) == len(set(pairs))
-    assert set(pairs) == work
-
-
-def test_the_cells_tiles():
-    """8,192 tokens, block 4, tile 512: 288 of 1,024 tile pairs hold
-    work (28 %), in 32 runs, and nothing of the fourth quadrant."""
-    runs = bd.tile_runs(8192, 4, 512)
-    assert sum(q_hi - q_lo for q_lo, q_hi, _ in runs) == 288
-    assert len(runs) == 32
-    assert not any(q_lo >= 16 and k_lo < 16 for q_lo, _, k_lo in runs)
 
 
 # -- the tie of the share to the model --------------------------------------

@@ -185,13 +185,13 @@ def test_lowered_for_a_tpu_the_row_buffers_are_plain_custom_calls(
     the products here are ``lax.ragged_dot``'s."""
     import re
 
-    from horovod_tpu.ops import pallas_ops
+    from horovod_tpu.ops import backend
 
     monkeypatch.delenv("HVTPU_PALLAS_INTERPRET", raising=False)
     monkeypatch.delenv("HVTPU_PALLAS", raising=False)
     if where == "tpu_without_pallas":
         monkeypatch.setenv("HVTPU_PALLAS", "0")
-    monkeypatch.setattr(pallas_ops, "_on_tpu", lambda: where != "cpu")
+    monkeypatch.setattr(backend, "on_tpu", lambda: where != "cpu")
     dtype = jnp.float16 if where == "tpu_float16" else jnp.bfloat16
     x, gate_w, experts = _weights(5, 4)
 
@@ -464,13 +464,13 @@ def test_a_walk_visits_every_tile_of_every_group_once(counts, empty_groups):
     ("cpu", jnp.bfloat16, 128, 1024, "ragged_dot")])
 def test_how_the_products_run_is_read_off_the_backend_and_the_shapes(
         monkeypatch, where, dtype, width, tokens, path):
-    from horovod_tpu.ops import pallas_ops
+    from horovod_tpu.ops import backend
 
     monkeypatch.delenv("HVTPU_PALLAS_INTERPRET", raising=False)
     monkeypatch.delenv("HVTPU_PALLAS", raising=False)
     if where == "tpu_without_pallas":
         monkeypatch.setenv("HVTPU_PALLAS", "0")
-    monkeypatch.setattr(pallas_ops, "_on_tpu", lambda: where != "cpu")
+    monkeypatch.setattr(backend, "on_tpu", lambda: where != "cpu")
     assert moe.products_path(dtype, width, 768, tokens, 8, 16) == path
     assert moe.products_path(dtype, 2048, width, tokens, 8, 16) == path
 
@@ -497,7 +497,7 @@ def test_which_backward_kernel_a_gated_shape_takes(
     else blocks of it where the weights twice and the sums once do;
     ``lax.ragged_dot`` where neither, on the CPU and with
     ``HVTPU_PALLAS=0``."""
-    from horovod_tpu.ops import grouped_ffn, pallas_ops
+    from horovod_tpu.ops import backend, grouped_ffn
 
     monkeypatch.delenv("HVTPU_PALLAS_INTERPRET", raising=False)
     monkeypatch.delenv("HVTPU_PALLAS", raising=False)
@@ -505,7 +505,7 @@ def test_which_backward_kernel_a_gated_shape_takes(
         monkeypatch.setenv("HVTPU_PALLAS_INTERPRET", "1")
     if where == "tpu_without_pallas":
         monkeypatch.setenv("HVTPU_PALLAS", "0")
-    monkeypatch.setattr(pallas_ops, "_on_tpu", lambda: where.startswith(
+    monkeypatch.setattr(backend, "on_tpu", lambda: where.startswith(
         "tpu"))
     assert moe.products_path(dtype, d, f, 16384, 8, 8, "gated") == path
     assert grouped_ffn._blocked(dtype, d, f, 3) is blocked
@@ -669,9 +669,9 @@ def test_the_grouped_kernels_take_both_forms(monkeypatch):
     """... and of the ungated one an inner width that is no whole
     number of vectors too (the layer fills it), where the gated
     kernels want whole vectors."""
-    from horovod_tpu.ops import pallas_ops
+    from horovod_tpu.ops import backend
 
-    monkeypatch.setattr(pallas_ops, "_pallas_mode", lambda: (True, True))
+    monkeypatch.setattr(backend, "pallas_mode", lambda: (True, True))
     for form in ("gated", "relu2"):
         assert moe.products_path(jnp.bfloat16, 128, 256, 64, 3, 4,
                                  form) == "grouped"
@@ -864,11 +864,11 @@ def test_through_the_kernels_the_ungated_layer_equals_the_plain_loop(
     ("cpu", jnp.bfloat16, 2688, 1856, "ragged_dot")])
 def test_how_the_ungated_products_run_is_read_off_the_backend_and_the_shapes(
         monkeypatch, where, dtype, d, f, path):
-    from horovod_tpu.ops import pallas_ops
+    from horovod_tpu.ops import backend
 
     monkeypatch.delenv("HVTPU_PALLAS_INTERPRET", raising=False)
     monkeypatch.delenv("HVTPU_PALLAS", raising=False)
     if where == "tpu_without_pallas":
         monkeypatch.setenv("HVTPU_PALLAS", "0")
-    monkeypatch.setattr(pallas_ops, "_on_tpu", lambda: where != "cpu")
+    monkeypatch.setattr(backend, "on_tpu", lambda: where != "cpu")
     assert moe.products_path(dtype, d, f, 16384, 6, 8, "relu2") == path

@@ -25,7 +25,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
-from horovod_tpu.ops import pallas_ops, ring  # noqa: E402
+from horovod_tpu.ops import backend, pallas_ops, ring  # noqa: E402
 
 pytestmark = pytest.mark.slow
 
@@ -40,7 +40,7 @@ def v5e_2x2():
 @pytest.fixture(autouse=True)
 def compiled_mode(monkeypatch):
     monkeypatch.delenv("HVTPU_PALLAS_INTERPRET", raising=False)
-    monkeypatch.setattr(pallas_ops, "_on_tpu", lambda: True)
+    monkeypatch.setattr(backend, "on_tpu", lambda: True)
 
 
 def _compile(f, *args):
@@ -87,7 +87,7 @@ def test_ring_kernels_compile(v5e_2x2, n_dev, per_rank):
         xs, axis_name="world")), block)
 
 
-# -- the block-diffusion attention's kernels (ops/flash_attention.py) -------
+# -- the attention's kernels (ops/flash_attention.py) ------------------------
 
 ATTENTION_KERNELS = ("hvtpu_flash_attention_fwd", "hvtpu_flash_attention_dq",
                      "hvtpu_flash_attention_dkv")
@@ -107,23 +107,52 @@ def _attention_kernels_by_scope(text):
 
 def test_attention_kernels_compile_at_the_cells_shape_and_keep_their_scope(
         v5e_2x2):
-    """``sdar-30b-a3b-1of8-t8k-b2``'s attention: 2 sequences of 2 x
-    8,192 positions, 4 query heads over 1 key/value head of 128, bf16,
-    forward and gradient, at the block sizes the model states."""
+    """Both masks through the one wrapper of ``models/attention.py``,
+    forward and gradient in bf16 at the block sizes it states:
+    ``sdar-30b-a3b-1of8-t8k-b2``'s block-diffusion attention (2
+    sequences of 2 x 8,192 positions, 4 query heads over 1 key/value head
+    of 128) and ``kimi-linear-48b-a3b-5of27-t8k-b2``'s latent attention
+    under the document mask (2 rows of 8,192 positions, 32 heads with
+    keys of 192 on values of 128, the document ids its data).  Each
+    compiles to the three kernels, once each, as plain custom calls
+    under ``hvtpu:attention``."""
+    import re
+
+    from horovod_tpu.models import attention as attn
     from horovod_tpu.models import block_diffusion as bd
 
     sh = NamedSharding(Mesh(np.array(v5e_2x2[:1]), ("world",)), P())
-    q = jax.ShapeDtypeStruct((2, 16384, 4, 128), jnp.bfloat16, sharding=sh)
-    kv = jax.ShapeDtypeStruct((2, 16384, 1, 128), jnp.bfloat16, sharding=sh)
 
-    def loss(q, k, v, target):
-        out = bd.tiled_attention(q, k, v, block_length=4,
-                                 tile=bd._ATTENTION_TILE)
+    def shaped(*shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=sh)
+
+    def block_diffusion(q, k, v, target):
+        out = attn.tiled_attention(q, k, v, block_length=4,
+                                   tile=bd._ATTENTION_TILE)
         return jnp.sum((out * target).astype(jnp.float32))
 
-    compiled = _compile(jax.grad(loss, (0, 1, 2)), q, kv, kv, q)
-    assert _attention_kernels_by_scope(compiled.as_text()) == {
-        kernel: {"hvtpu:attention"} for kernel in ATTENTION_KERNELS}
+    def documents(q, k, v, target, segment):
+        out = attn.causal_document_attention(q, k, v, segment,
+                                             scale=192 ** -0.5)
+        return jnp.sum((out * target).astype(jnp.float32))
+
+    for loss, shapes in (
+            (block_diffusion, (shaped(2, 16384, 4, 128),
+                               shaped(2, 16384, 1, 128),
+                               shaped(2, 16384, 1, 128),
+                               shaped(2, 16384, 4, 128))),
+            (documents, (shaped(2, 8192, 32, 192), shaped(2, 8192, 32, 192),
+                         shaped(2, 8192, 32, 128), shaped(2, 8192, 32, 128),
+                         shaped(2, 8192, dtype=jnp.int32)))):
+        text = _compile(jax.grad(loss, (0, 1, 2)), *shapes).as_text()
+        assert _attention_kernels_by_scope(text) == {
+            kernel: {"hvtpu:attention"} for kernel in ATTENTION_KERNELS}
+        kernels = re.findall(
+            r"^\s*(?:ROOT\s+)?%hvtpu_flash_attention_\w+(?:\.\d+)? = .*$",
+            text, re.MULTILINE)
+        assert len(kernels) == 3, loss.__name__
+        assert not any("kernel_metadata" in line.replace(
+            "kernel_metadata={}", "") for line in kernels)
 
 
 def _compiled_step(cell, v5e_2x2, config=None):
