@@ -37,4 +37,5 @@ __all__ = [
     "looped",
     "hybrid_moe",
     "kimi_linear",
+    "gated_conv",
 ]

@@ -105,7 +105,8 @@ from ..obs import metrics
 from ..ops import backend, delta_rule
 from ..parallel.moe import dropless_topk_moe
 from .attention import causal_document_attention
-from .layers import causal_conv, rms_norm, weighted_next_token_cross_entropy
+from .layers import (causal_conv, rms_norm, swiglu,
+                     weighted_next_token_cross_entropy)
 
 Params = Dict[str, Any]
 
@@ -568,16 +569,6 @@ _MIXER = {"kda": kda_mixer, "mla": mla_mixer}
 # ---------------------------------------------------------------------------
 # the two feed-forward parts
 # ---------------------------------------------------------------------------
-
-def swiglu(u, w_gate, w_up, w_down):
-    """``W_down (silu(W_gate u) * W_up u)``."""
-    dtype = u.dtype
-    hidden = (jax.nn.silu(jnp.dot(u, w_gate.astype(dtype),
-                                  preferred_element_type=jnp.float32))
-              * jnp.dot(u, w_up.astype(dtype),
-                        preferred_element_type=jnp.float32)).astype(dtype)
-    return hidden @ w_down.astype(dtype)
-
 
 def dense_ffn(cfg: KimiLinearConfig, p: Params, x):
     with jax.named_scope("hvtpu:mlp"):

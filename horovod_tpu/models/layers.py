@@ -1,6 +1,6 @@
 """Parts the decoders of ``models/`` share: the norm, the rotary
-embedding, the mask value, the causal convolution cut at document starts
-and the weighted next-token loss.  Pure functions; nothing here knows
+embedding, the mask value, the causal convolution cut at document starts,
+the SwiGLU feed-forward part and the weighted next-token loss.  Pure functions; nothing here knows
 which model calls it."""
 
 from __future__ import annotations
@@ -53,6 +53,17 @@ def causal_conv(x, w, b, segment):
             same[..., None], padded[:, tap:tap + t].astype(jnp.float32),
             0.0) * w[tap]
     return y
+
+
+def swiglu(u, w_gate, w_up, w_down):
+    """``W_down (silu(W_gate u) * W_up u)``, the two first products
+    summed and gated in f32, the gated rows back in ``u``'s type."""
+    dtype = u.dtype
+    hidden = (jax.nn.silu(jnp.dot(u, w_gate.astype(dtype),
+                                  preferred_element_type=jnp.float32))
+              * jnp.dot(u, w_up.astype(dtype),
+                        preferred_element_type=jnp.float32)).astype(dtype)
+    return hidden @ w_down.astype(dtype)
 
 
 def weighted_next_token_cross_entropy(logits, batch):

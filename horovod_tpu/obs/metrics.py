@@ -866,6 +866,24 @@ def note_kda_path(path: str) -> None:
         "kernels or XLA.").inc(path=path)
 
 
+def note_short_conv(path: str, taps: int) -> None:
+    """Count one call of ``models.gated_conv.short_conv`` (the gated
+    short convolution's taps and gates) by the implementation it took
+    (``"xla"``: no kernel computes it yet), and record its taps.  Called
+    while a program is traced, once a call site and a trace, like
+    ``note_kda_path``: a step that scans its layers counts one call
+    however many layers run it."""
+    REGISTRY.counter(
+        "hvtpu_short_conv_calls_total",
+        "Calls of the gated short convolution, counted when a program is "
+        "traced, by the implementation that was built in.").inc(path=path)
+    REGISTRY.gauge(
+        "hvtpu_short_conv_taps",
+        "Taps of the last gated short convolution built: the positions, "
+        "its own and those before it in its document, that a position "
+        "reads.").set(float(taps))
+
+
 def note_packed_batch(segment) -> None:
     """Record what a packed batch holds: ``segment`` is the int ``[rows,
     T]`` array of the document's index at every position, as the batch

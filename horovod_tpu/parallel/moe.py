@@ -545,6 +545,7 @@ def dropless_topk_moe(
     renormalise: bool,
     selection_bias: Optional[jax.Array] = None,
     scale: float = 1.0,
+    renormalise_eps: float = 0.0,
 ) -> Tuple[jax.Array, dict]:
     """The part of a top-k expert layer that the experts held here give.
 
@@ -564,7 +565,9 @@ def dropless_topk_moe(
     ``top_k`` experts are *chosen* by score plus bias and *weighted* by
     the score without it; the bias is a buffer that steers the load and
     gets no gradient.  Either way the kept weights are divided by their
-    sum if ``renormalise`` and multiplied by ``scale``.
+    sum if ``renormalise`` (by their sum plus ``renormalise_eps`` where
+    that is not 0: the LFM2 family's ``sum + 1e-6``) and multiplied by
+    ``scale``.
 
     The assignments whose expert is held here are
     sorted by expert, gathered, multiplied a group at a time (in the
@@ -612,7 +615,9 @@ def dropless_topk_moe(
                 top_i[:, :, None] == jnp.arange(num_experts),
                 scores[:, None, :], 0.0), axis=-1)
         if renormalise:
-            top_p = top_p / top_p.sum(axis=-1, keepdims=True)
+            total = top_p.sum(axis=-1, keepdims=True)
+            top_p = top_p / (total + renormalise_eps if renormalise_eps
+                             else total)
         if scale != 1.0:
             top_p = scale * top_p
         held = first_expert + jnp.arange(e_held)

@@ -13,7 +13,8 @@ import pytest
 PACKAGE = pathlib.Path(__file__).resolve().parent.parent / "horovod_tpu"
 MODELS = PACKAGE / "models"
 # decoders no module may take a name from
-DECODERS = ("block_diffusion", "looped", "kimi_linear", "hybrid_moe")
+DECODERS = ("block_diffusion", "looped", "kimi_linear", "hybrid_moe",
+            "gated_conv")
 # what ``hybrid_moe`` may take from ``hybrid_ssm``: the mixer
 MIXER = {"mamba_mixer", "mixer_start"}
 

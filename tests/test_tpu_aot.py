@@ -656,3 +656,74 @@ def test_the_kimi_linear_cells_step_fits_and_says_which_paths_it_took(
             "hvtpu:moe.experts", "hvtpu:moe.combine", "hvtpu:moe.shared",
             "hvtpu:lm_head"} <= set(
                 scopes.scope_by_instruction(text).values())
+
+
+LFM2_CELL = "lfm2-8b-a1b-6of24-t8k-b2"
+
+
+def test_the_lfm2_cells_step_fits_and_says_which_paths_it_took(v5e_2x2):
+    """The whole step of ``lfm2-8b-a1b-6of24-t8k-b2`` as ``benchmark/
+    job.py`` builds it, 602 M parameters trained at 12 bytes each and
+    16,384 tokens a step, for one described chip: it fits the 15.75 GiB
+    XLA:TPU has (12.7 GiB by the figure the other cells' cases use; 17.2
+    without the layers' recomputation, which it refuses: PERF.md,
+    the findings on this cell).  Its attention, 32 query heads on 8 key/value
+    heads of 64 with document ids, runs in the three kernels of
+    ``ops/flash_attention.py`` under ``hvtpu:attention``, once each (the
+    attention mixer is not recomputed), none with kernel metadata, two
+    key/value heads of 64
+    paired into one 128-lane block with their eight query heads (``lse``
+    and ``delta`` ``[2, 4, 8192, 8]``): ``q`` is ``32 x 64 = 2048``
+    wide, ``k`` and ``v`` ``8 x 64 = 512``, nothing filled to 128 a
+    head.  The gated experts' products at 2048 x 1792 do not fit the
+    grouped kernels' VMEM rule (a group's three weights twice and their
+    f32 sums once are 84 MiB, against 80), so they are XLA's own
+    ``ragged-dot`` kernels; the gated convolution is plain XLA under
+    ``hvtpu:shortconv.conv``; and the scopes the cell's readers join are
+    there."""
+    import re
+
+    from benchmark import cells, scopes
+
+    from horovod_tpu.ops import grouped_ffn
+    from horovod_tpu.parallel import moe
+
+    cell = cells.load_cell(LFM2_CELL)
+    compiled = _compiled_step(cell, v5e_2x2)
+    m = compiled.memory_analysis()
+    assert 4.8e9 <= m.argument_size_in_bytes <= 4.83e9
+    assert m.alias_size_in_bytes >= 4.8e9
+    assert (m.argument_size_in_bytes + m.output_size_in_bytes
+            + m.temp_size_in_bytes - m.alias_size_in_bytes) <= 14.0e9
+    text = compiled.as_text()
+    assert _attention_kernels_by_scope(text) == {
+        kernel: {"hvtpu:attention"} for kernel in ATTENTION_KERNELS}
+    kernels = re.findall(
+        r"^\s*(?:ROOT\s+)?%hvtpu_\w+(?:\.\d+)? = .*$", text, re.MULTILINE)
+    attention = [line for line in kernels if "%hvtpu_flash_attention" in line]
+    assert len(attention) == 3          # forward, dq, dk/dv
+    assert not any("kernel_metadata" in line.replace(
+        "kernel_metadata={}", "") for line in kernels)
+    for line in attention:
+        assert set(re.findall(r"bf16\[2,8192,(\d+)\]", line)) == {
+            "512", "2048"}, line
+        assert not re.search(r"bf16\[2,8192,4096\]|bf16\[2,8192,1024\]",
+                             line)                    # 128 a head
+        shapes = set(re.findall(r"f32\[2,(\d+),(\d+),(\d+)\]", line))
+        assert shapes == ({("4", "8", "8192")} if "_dkv" in line
+                          else {("4", "8192", "8")}), line
+    for kernel, result in (("_dq", "bf16[2,8192,2048]"),
+                           ("_dkv", "(bf16[2,8192,512]")):
+        line, = (line for line in attention if kernel in line)
+        assert line.split(" = ")[1].startswith(result), line
+    tokens = cell.traffic["batch_per_chip"] * cell.traffic["sequence_length"]
+    assert grouped_ffn._blocked(jnp.bfloat16, 2048, 1792, 3) is None
+    assert moe.products_path(
+        jnp.bfloat16, 2048, 1792, tokens, 4, 8, "gated") == "ragged_dot"
+    assert re.findall(r"^\s*%(ragged-dot\S*) = ", text, re.MULTILINE)
+    assert not re.findall(r"%hvtpu_grouped_ffn", text)
+    assert {"hvtpu:shortconv.proj", "hvtpu:shortconv.conv",
+            "hvtpu:attn.proj", "hvtpu:attention", "hvtpu:mlp",
+            "hvtpu:moe.route", "hvtpu:moe.dispatch", "hvtpu:moe.experts",
+            "hvtpu:moe.combine", "hvtpu:lm_head"} <= set(
+                scopes.scope_by_instruction(text).values())
