@@ -753,6 +753,20 @@ def note_moe_products(path: str) -> None:
         "Pallas kernels or lax.ragged_dot.").inc(path=path)
 
 
+def note_moe_backward_kernel(kernel: str) -> None:
+    """Count one backward pass of the grouped expert kernels
+    (``ops.grouped_ffn.backward``) by the kernel it was built with:
+    ``"whole"`` (a group's whole inner width at once, its weights and
+    f32 sums twice over in VMEM) or ``"blocked"`` (the inner width in
+    blocks, the weights twice over, the sums once).  Called while a
+    program is traced, like ``note_moe_products``."""
+    REGISTRY.counter(
+        "hvtpu_moe_backward_kernel_total",
+        "Backward passes of the grouped expert kernels, counted when a "
+        "program is traced, by whether the kernel takes a group's whole "
+        "inner width at once or walks it in blocks.").inc(kernel=kernel)
+
+
 def note_moe_layer(rule: str, form: str) -> None:
     """Count one call of ``parallel.moe.dropless_topk_moe`` by its
     routing rule (``"softmax"``, or ``"sigmoid_bias"``: sigmoid scores,

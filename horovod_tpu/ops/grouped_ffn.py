@@ -50,7 +50,8 @@ inner width at once, its sums as double-buffered output blocks, and
 with them three weights twice over: 2 x 3 x D x F x (2 + 4) bytes in
 bfloat16, 54 MiB at 2048 x 768, where ``_VMEM_FOR_A_GROUP`` leaves the
 tiles room.  Wider gated experts take the blocked kernel below (2304 x
-1024 would want 81 MiB here and 54 there).
+1024 would want 81 MiB here and 54 there, 2048 x 1792 126 here and 84
+there).
 
 *The ungated kernels* (``W_down relu(W_up u) ** 2``) walk the same
 visits.  ``forward``: ``a = xs Wu``, ``h = relu(a) ** 2``, ``y = (h Wd)
@@ -117,6 +118,8 @@ from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from ..obs import metrics
+
 _LANES = 128
 _NT = (((1,), (1,)), ((), ()))      # a @ b.T
 _TN = (((0,), (0,)), ((), ()))      # a.T @ b
@@ -131,8 +134,11 @@ _VMEM_FOR_A_GROUP = 64 * 1024 * 1024
 # temporaries are a block's of the inner width: 78.8 MiB of bfloat16 at
 # 2688 x 1920 with two weights, where visits of 128 rows and blocks of
 # 384 columns fit the 100 MiB and 256 rows or 640 columns do not (they
-# want 120: PERF.md, section 6); 54 MiB at 2304 x 1024 with three
-_VMEM_FOR_BLOCKS = 80 * 1024 * 1024
+# want 120: PERF.md, section 6); 54 MiB at 2304 x 1024 with three, and
+# 84 at 2048 x 1792 (the LFM2 cell's), which fit with visits of 128 rows
+# and blocks of 256 columns where 94.5 at 2304 x 1792 do not.  The
+# tiles' room grows with D: 84 MiB at 4096 x 896 want 100.3 in all
+_VMEM_FOR_BLOCKS = 84 * 1024 * 1024
 _F_BLOCK = 512
 
 
@@ -481,7 +487,9 @@ def backward(xs, gs, wt, counts, *weights, tile_rows: int,
             (jax.ShapeDtypeStruct(wt.shape, jnp.float32), "rows")]
     sums = [jax.ShapeDtypeStruct(w.shape, jnp.float32) for w in weights]
     _, d, f = weights[0].shape
-    if not _blocked(xs.dtype, d, f, len(weights)):
+    blocked = _blocked(xs.dtype, d, f, len(weights))
+    metrics.note_moe_backward_kernel("blocked" if blocked else "whole")
+    if not blocked:
         return _call(_backward_kernel, "hvtpu_grouped_ffn_bwd", walk,
                      tile_rows, ins, rows + [(a, "group") for a in sums],
                      interpret)

@@ -528,6 +528,43 @@ def test_the_builder_counts_the_parameters_the_tree_holds(config, size):
         assert shapes["layers"][2]["w_up"].shape == (3, 8, 2048, 1792)
 
 
+def test_the_published_expert_layer_takes_the_grouped_kernels(
+        config, monkeypatch):
+    """At the published widths, 2048 x 1792 over 16,384 tokens top-4 of
+    the 8 held, the experts' products are the grouped kernels where
+    kernels compile (here the interpreter), the backward one walking the
+    inner width in blocks with a group's three weights twice over and
+    their f32 sums once in VMEM (84 MiB, the most the rule allows):
+    ``hvtpu_moe_products_total{path="grouped"}`` and
+    ``hvtpu_moe_backward_kernel_total{kernel="blocked"}`` count the
+    layer when a step of it is traced, forward and backward."""
+    from benchmark.builders import lfm2_moe_lm
+    from horovod_tpu.ops import grouped_ffn
+
+    monkeypatch.setenv("HVTPU_PALLAS_INTERPRET", "1")
+    monkeypatch.delenv("HVTPU_PALLAS", raising=False)
+    cfg = lfm2_moe_lm.model_config(config)
+    shapes = jax.eval_shape(
+        lambda key: gc.init_params(key, cfg), jax.random.PRNGKey(0))
+    p = jax.tree_util.tree_map(      # one layer of a stacked run
+        lambda a: jax.ShapeDtypeStruct(a.shape[1:], a.dtype),
+        shapes["layers"][2])
+    x = jax.ShapeDtypeStruct((2, 8192, cfg.hidden_size),
+                             jnp.dtype(cfg.compute_dtype))
+    assert p["w_gate"].shape == (8, 2048, 1792)
+    assert grouped_ffn._blocked(cfg.compute_dtype, 2048, 1792, 3)
+    before = {"grouped": counter("hvtpu_moe_products_total", path="grouped"),
+              "blocked": counter("hvtpu_moe_backward_kernel_total",
+                                 kernel="blocked")}
+    grads = jax.eval_shape(jax.grad(lambda p, x: jnp.sum(
+        gc.expert_ffn(cfg, p, x)[0].astype(jnp.float32))), p, x)
+    assert grads["w_down"].shape == (8, 1792, 2048)
+    assert counter("hvtpu_moe_products_total",
+                   path="grouped") == before["grouped"] + 1
+    assert counter("hvtpu_moe_backward_kernel_total",
+                   kernel="blocked") == before["blocked"] + 1
+
+
 @pytest.mark.parametrize("key, value", [
     ("conv_bias", True), ("use_expert_bias", False),
     ("norm_topk_prob", False), ("tie_word_embeddings", True),

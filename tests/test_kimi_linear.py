@@ -569,8 +569,9 @@ def test_the_published_expert_layer_takes_the_grouped_kernels(
     where kernels compile (here the interpreter), the backward one
     walking the inner width in blocks (a group's three weights and
     three f32 sums do not fit VMEM twice over whole):
-    ``hvtpu_moe_products_total{path="grouped"}`` counts the layer when
-    a step of it is traced, forward and backward."""
+    ``hvtpu_moe_products_total{path="grouped"}`` and
+    ``hvtpu_moe_backward_kernel_total{kernel="blocked"}`` count the
+    layer when a step of it is traced, forward and backward."""
     from benchmark.builders import kimi_linear_lm
     from horovod_tpu.ops import grouped_ffn
 
@@ -586,6 +587,7 @@ def test_the_published_expert_layer_takes_the_grouped_kernels(
     assert grouped_ffn._blocked(cfg.compute_dtype, 2304, 1024, 3)
     before = {path: counter("hvtpu_moe_products_total", path=path)
               for path in ("grouped", "ragged_dot")}
+    blocked = counter("hvtpu_moe_backward_kernel_total", kernel="blocked")
     grads = jax.eval_shape(jax.grad(lambda p, x: jnp.sum(
         kl.expert_ffn(cfg, p, x)[0].astype(jnp.float32))), p, x)
     assert grads["w_down"].shape == (8, 1024, 2304)
@@ -593,6 +595,8 @@ def test_the_published_expert_layer_takes_the_grouped_kernels(
                    path="grouped") == before["grouped"] + 1
     assert counter("hvtpu_moe_products_total",
                    path="ragged_dot") == before["ragged_dot"]
+    assert counter("hvtpu_moe_backward_kernel_total",
+                   kernel="blocked") == blocked + 1
 
 
 def test_the_routing_of_a_step_feeds_note_moe_routing():

@@ -483,9 +483,13 @@ def test_how_the_products_run_is_read_off_the_backend_and_the_shapes(
     # the transformer cell's: whole, 54 MiB, the kernel of before
     ("tpu", jnp.bfloat16, 2048, 768, "grouped", False),
     ("tpu", jnp.float32, 2048, 768, "grouped", True),
-    # beyond the blocked rule too: 108 and 81 MiB of its 80
+    # the LFM2 cell's: 126 MiB whole, 84 in blocks, the most the rule
+    # allows
+    ("tpu", jnp.bfloat16, 2048, 1792, "grouped", True),
+    ("tpu", jnp.float32, 2304, 1024, "grouped", True),      # 81 in blocks
+    # beyond the blocked rule too: 108 and 162 MiB of its 84
     ("tpu", jnp.bfloat16, 2304, 2048, "ragged_dot", None),
-    ("tpu", jnp.float32, 2304, 1024, "ragged_dot", None),
+    ("tpu", jnp.float32, 2304, 2048, "ragged_dot", None),
     ("tpu_without_pallas", jnp.bfloat16, 2304, 1024, "ragged_dot", True),
     ("cpu", jnp.bfloat16, 2304, 1024, "ragged_dot", True)])
 def test_which_backward_kernel_a_gated_shape_takes(
@@ -509,6 +513,39 @@ def test_which_backward_kernel_a_gated_shape_takes(
         "tpu"))
     assert moe.products_path(dtype, d, f, 16384, 8, 8, "gated") == path
     assert grouped_ffn._blocked(dtype, d, f, 3) is blocked
+
+
+@pytest.mark.parametrize("kernel", ["whole", "blocked"])
+def test_the_backward_kernel_a_layer_was_built_with_is_counted(
+        monkeypatch, grouped, kernel):
+    """``hvtpu_moe_backward_kernel_total{kernel=}``: one a trace of a
+    backward pass through the grouped kernels, under the kernel the
+    shapes take (128 x 256 in float32 is whole, and blocked with
+    ``_VMEM_FOR_A_GROUP`` at 0); none for a forward pass alone, nor on
+    the ``ragged_dot`` path."""
+    from horovod_tpu.ops import grouped_ffn
+
+    if kernel == "blocked":
+        monkeypatch.setattr(grouped_ffn, "_VMEM_FOR_A_GROUP", 0)
+    assert grouped_ffn._blocked(jnp.float32, 128, 256, 3) is (
+        kernel == "blocked")
+    counter = moe.metrics.REGISTRY.counter("hvtpu_moe_backward_kernel_total")
+    x, gate_w, experts = _weights(21, 4, **WIDE)
+
+    def traced(grad):
+        def f(x):
+            return jnp.sum(layer(x, gate_w, experts, top_k=3,
+                                 first_expert=8)[0])
+        before = {k: counter.value(kernel=k) for k in ("whole", "blocked")}
+        jax.jit(jax.grad(f) if grad else f).lower(x)
+        return {k: counter.value(kernel=k) - before[k]
+                for k in ("whole", "blocked")}
+
+    assert traced(grad=True) == {"whole": float(kernel == "whole"),
+                                 "blocked": float(kernel == "blocked")}
+    assert traced(grad=False) == {"whole": 0.0, "blocked": 0.0}
+    monkeypatch.setenv("HVTPU_PALLAS", "0")
+    assert traced(grad=True) == {"whole": 0.0, "blocked": 0.0}
 
 
 # -- the other routing rule and the other form of expert ---------------------

@@ -675,16 +675,21 @@ def test_the_lfm2_cells_step_fits_and_says_which_paths_it_took(v5e_2x2):
     paired into one 128-lane block with their eight query heads (``lse``
     and ``delta`` ``[2, 4, 8192, 8]``): ``q`` is ``32 x 64 = 2048``
     wide, ``k`` and ``v`` ``8 x 64 = 512``, nothing filled to 128 a
-    head.  The gated experts' products at 2048 x 1792 do not fit the
-    grouped kernels' VMEM rule (a group's three weights twice and their
-    f32 sums once are 84 MiB, against 80), so they are XLA's own
-    ``ragged-dot`` kernels; the gated convolution is plain XLA under
+    head.  The gated experts' products at 2048 x 1792 are the two
+    grouped kernels of ``ops/grouped_ffn.py``, a forward and a backward
+    one for each of the two runs of expert layers, under
+    ``hvtpu:moe.experts``, the backward one walking the inner width in
+    blocks (a group's three weights twice and their f32 sums once are
+    84 MiB, the most the rule allows), and no ``ragged-dot`` kernel of
+    XLA's is left; the gated convolution is plain XLA under
     ``hvtpu:shortconv.conv``; and the scopes the cell's readers join are
     there."""
     import re
 
     from benchmark import cells, scopes
 
+    from benchmark.builders import lfm2_moe_lm
+    from horovod_tpu.models import gated_conv as gc
     from horovod_tpu.ops import grouped_ffn
     from horovod_tpu.parallel import moe
 
@@ -717,11 +722,13 @@ def test_the_lfm2_cells_step_fits_and_says_which_paths_it_took(v5e_2x2):
         line, = (line for line in attention if kernel in line)
         assert line.split(" = ")[1].startswith(result), line
     tokens = cell.traffic["batch_per_chip"] * cell.traffic["sequence_length"]
-    assert grouped_ffn._blocked(jnp.bfloat16, 2048, 1792, 3) is None
+    assert grouped_ffn._blocked(jnp.bfloat16, 2048, 1792, 3) is True
     assert moe.products_path(
-        jnp.bfloat16, 2048, 1792, tokens, 4, 8, "gated") == "ragged_dot"
-    assert re.findall(r"^\s*%(ragged-dot\S*) = ", text, re.MULTILINE)
-    assert not re.findall(r"%hvtpu_grouped_ffn", text)
+        jnp.bfloat16, 2048, 1792, tokens, 4, 8, "gated") == "grouped"
+    assert not re.findall(r"^\s*%(ragged-dot\S*) = ", text, re.MULTILINE)
+    layer_groups = gc.layer_groups(*lfm2_moe_lm.layer_kinds(cell.config))
+    _the_experts_products_are_the_grouped_kernels(text, layers=sum(
+        ffn == "experts" for _, ffn, _ in layer_groups))
     assert {"hvtpu:shortconv.proj", "hvtpu:shortconv.conv",
             "hvtpu:attn.proj", "hvtpu:attention", "hvtpu:mlp",
             "hvtpu:moe.route", "hvtpu:moe.dispatch", "hvtpu:moe.experts",
